@@ -1392,6 +1392,9 @@ def main() -> None:
         "(implies ci scale)",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     quick = (
         args.smoke or args.stream or args.matmat or args.solve
         or args.decode or args.chaos

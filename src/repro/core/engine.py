@@ -173,9 +173,9 @@ def resolve_packed(packed: Union[bool, str], schedule: BlockSchedule) -> bool:
 
 def resolve_backend(backend: str) -> str:
     """Map "auto" to a concrete executor: the ``REPRO_BACKEND`` env var when
-    set (empty string = unset, mirroring ``REPRO_PALLAS_INTERPRET`` — one
-    variable flips every auto call site in a serve/benchmark process instead
-    of threading --backend through each CLI), otherwise pallas on TPU
+    set (empty string = unset — one variable flips every auto call site in
+    a serve/benchmark process instead of threading --backend through each
+    CLI), otherwise pallas on TPU
     (native compile) and the jnp reference elsewhere — interpret-mode pallas
     is for correctness checks, not serving. Explicit "reference"/"pallas"
     arguments always win over the environment."""
@@ -684,6 +684,10 @@ class SpMVEngine:
         self._schedule: Optional[BlockSchedule] = None
         self.plan_cached: Optional[bool] = None  # set when the plan is built
         self._device_plan = None  # kernels.sell_spmv.DevicePlan (pallas only)
+        # Device-resident plan arrays, passed to the jitted executables as
+        # arguments (never closed over, which would embed O(nnz) constants
+        # in every compiled program), one copy per device that runs them.
+        self._operands = {}
         self._matvec = None
         self._matmat = None
         self._matmat_vmapped = None
@@ -796,7 +800,7 @@ class SpMVEngine:
                 from repro.kernels.ops import resolve_interpret
                 from repro.kernels.sell_spmm import sell_spmm_pallas
                 from repro.kernels.sell_spmv import build_device_plan, \
-                    sell_spmv_pallas
+                    chunk_values, sell_spmv_pallas
 
                 interpret = resolve_interpret()
                 cpc = self.cols_per_chunk
@@ -814,11 +818,22 @@ class SpMVEngine:
                     slice_height=H, packed=self.packed,
                 )
                 self._device_plan = plan
+                # Values in the kernels' per-chunk row layout, so the reshape
+                # below and the kernel's own cancel and no call relayouts.
+                operands = (
+                    chunk_values(jnp.asarray(va_plan, vdt), cpc), plan
+                )
 
-                def _matvec(x: jnp.ndarray) -> jnp.ndarray:
+                def _values(va, dtype):
+                    return va.reshape(n_slices, W_plan, H).astype(
+                        vdt if vdt is not None else dtype
+                    )
+
+                def _matvec(ops, x: jnp.ndarray) -> jnp.ndarray:
+                    va, plan = ops
                     y = sell_spmv_pallas(
                         None,
-                        jnp.asarray(va_plan, vdt if vdt is not None else x.dtype),
+                        _values(va, x.dtype),
                         x,
                         cols_per_chunk=cpc,
                         block_rows=block_rows,
@@ -830,10 +845,11 @@ class SpMVEngine:
 
                 if self.matmat_mode_resolved == "fused":
 
-                    def _matmat_fused(X: jnp.ndarray) -> jnp.ndarray:
+                    def _matmat_fused(ops, X: jnp.ndarray) -> jnp.ndarray:
+                        va, plan = ops
                         Y = sell_spmm_pallas(
                             None,
-                            jnp.asarray(va_plan, vdt if vdt is not None else X.dtype),
+                            _values(va, X.dtype),
                             X,
                             cols_per_chunk=cpc,
                             block_rows=block_rows,
@@ -845,26 +861,27 @@ class SpMVEngine:
                         return Y[:n_rows]
 
             else:
+                operands = (sched, jnp.asarray(va_plan[:, :W], vdt))
 
-                def _matvec(x: jnp.ndarray) -> jnp.ndarray:
+                def _matvec(ops, x: jnp.ndarray) -> jnp.ndarray:
+                    sched, va = ops
                     gathered = schedule_gather_reference(
                         x[:, None], sched, n_out=n_out
                     )
                     g = gathered[:, 0].reshape(n_slices, W_plan, H)[:, :W]
-                    va = jnp.asarray(
-                        va_plan[:, :W], vdt if vdt is not None else x.dtype
-                    )
+                    va = va.astype(vdt if vdt is not None else x.dtype)
                     # Width reduction through the padding-invariant tree:
                     # shards padded to their own (smaller) max width stay
                     # bit-identical to the global-width single-device plan.
                     y = _width_tree_sum(va * g, _runtime_one(x))
                     return y.reshape(-1)[:n_rows]
 
-                def _matmat_ref(X: jnp.ndarray) -> jnp.ndarray:
+                def _matmat_ref(ops, X: jnp.ndarray) -> jnp.ndarray:
                     # Direct 2-D variant of _matvec: same gather, same
                     # product, same tree folds per column (the folds are
                     # exact, so per-column bit-identity to matvec is
                     # structural), with one shared gather pass per batch.
+                    sched, va = ops
                     k = X.shape[1]
                     if k == 0:  # reshape(-1, 0) below can't infer a size
                         return jnp.zeros((n_rows, 0), X.dtype)
@@ -872,17 +889,16 @@ class SpMVEngine:
                         X, sched, n_out=n_out
                     )
                     g = gathered.reshape(n_slices, W_plan, H, k)[:, :W]
-                    va = jnp.asarray(
-                        va_plan[:, :W], vdt if vdt is not None else X.dtype
-                    )
+                    va = va.astype(vdt if vdt is not None else X.dtype)
                     y = _width_tree_sum(va[..., None] * g, _runtime_one(X))
                     return y.reshape(-1, k)[:n_rows]
 
+            self._operands = {None: operands}
             self._matvec = jax.jit(_matvec)
             self._matmat_vmapped = (
                 jax.jit(_matmat_ref) if _matmat_fused is None
                 and _matmat_ref is not None
-                else jax.jit(jax.vmap(_matvec, in_axes=1, out_axes=1))
+                else jax.jit(jax.vmap(_matvec, in_axes=(None, 1), out_axes=1))
             )
             self._matmat = (
                 jax.jit(_matmat_fused) if _matmat_fused is not None
@@ -908,16 +924,34 @@ class SpMVEngine:
                 f"matvec expects x of shape ({self.sell.n_cols},), got {x.shape}"
             )
         mv, _ = self._ensure_compiled()
-        return mv(x)
+        return mv(self._operands_for(x), x)
+
+    def _operands_for(self, x: jnp.ndarray):
+        """The plan arrays on the device `x` lives on (placed there once), so
+        each executable runs where its input was staged."""
+        concrete = isinstance(x, jax.Array) and not isinstance(
+            x, jax.core.Tracer
+        )
+        devices = x.devices() if concrete else ()
+        dev = next(iter(devices)) if len(devices) == 1 else None
+        with self._plan_lock:
+            ops = self._operands.get(dev)
+            if ops is None:
+                ops = self._operands[dev] = jax.device_put(
+                    self._operands[None], dev
+                )
+            return ops
 
     def device_matvec(self):
-        """The jitted matvec itself (not its result) — traceable inside
-        `jax.lax.while_loop` bodies. The hoisted `DevicePlan`/schedule
-        arrays are closure constants of this function, so a solver loop
-        carries the plan as loop-invariant state with zero host round-trips
-        per iteration (core.solvers builds on this)."""
+        """``(apply, operands)`` with ``apply(operands, x) == matvec(x)``:
+        the jitted matvec and the device-resident plan arrays it takes as
+        arguments — traceable inside `jax.lax.while_loop` bodies. A solver
+        loop passes `operands` into its own jitted driver, so the plan
+        enters the loop as loop-invariant state with zero host round-trips
+        per iteration and no O(nnz) constant in the compiled loop
+        (core.solvers builds on this)."""
         mv, _ = self._ensure_compiled()
-        return mv
+        return mv, self._operands[None]
 
     def matmat(self, X: jnp.ndarray) -> jnp.ndarray:
         """Y = A @ X for X: (n_cols, k) — one schedule shared by all k.
@@ -936,7 +970,7 @@ class SpMVEngine:
                 f"matmat expects X of shape ({self.sell.n_cols}, k), got {X.shape}"
             )
         _, mm = self._ensure_compiled()
-        return mm(X)
+        return mm(self._operands_for(X), X)
 
     def matmat_vmapped(self, X: jnp.ndarray) -> jnp.ndarray:
         """The per-column baseline: `matvec` vmapped over RHS columns (one
@@ -950,7 +984,7 @@ class SpMVEngine:
                 f"matmat expects X of shape ({self.sell.n_cols}, k), got {X.shape}"
             )
         self._ensure_compiled()
-        return self._matmat_vmapped(X)
+        return self._matmat_vmapped(self._operands_for(X), X)
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         return self.matvec(x) if jnp.asarray(x).ndim == 1 else self.matmat(x)
@@ -960,9 +994,9 @@ class SpMVEngine:
     # data, dispatch launches compute, finalize is the only host sync.
 
     def stage(self, X: jnp.ndarray, *, donate: bool = False) -> jnp.ndarray:
-        """Place a RHS micro-batch on this engine's device (async transfer;
-        the compiled executables run on the default device, so that is the
-        staging target). Donation retires jax-array sources — see
+        """Place a RHS micro-batch on the default device (async transfer;
+        the compiled executables run wherever their input lives, with the
+        plan arrays placed there once). Donation retires jax-array sources — see
         `runtime.device_put_rhs` for when that is legal."""
         if X.ndim != 2 or X.shape[0] != self.sell.n_cols:
             raise ValueError(
@@ -975,7 +1009,7 @@ class SpMVEngine:
         """Launch the batched matmat on an already-staged micro-batch —
         async (JAX dispatch), no host synchronization."""
         _, mm = self._ensure_compiled()
-        return mm(staged)
+        return mm(self._operands_for(staged), staged)
 
     def finalize(self, pending: jnp.ndarray) -> jnp.ndarray:
         """Block until a dispatched micro-batch's result is materialized."""

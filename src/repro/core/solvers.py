@@ -4,8 +4,8 @@ The paper's plan-once/execute-many design pays off when one coalescing
 schedule is reused thousands of times; the classic consumers of SpMV are
 exactly that shape. Each solver here runs its whole iteration *inside*
 `jax.lax.while_loop`: the engine's hoisted `DevicePlan` (schedule tags /
-warp maps) enters the loop as closure constants of the jitted matvec —
-loop-invariant carry — so per iteration there are zero host round-trips,
+warp maps) enters the loop as arguments of the jitted driver —
+loop-invariant state — so per iteration there are zero host round-trips,
 zero re-plans, and the convergence check (`rr > tol2`, L1 delta, ...) is
 evaluated on device.
 
@@ -136,31 +136,44 @@ def _loop_runners(ex, key, cond, step):
     """Jitted while-runner + cond/step for the python oracle, cached per
     executor so repeat solves (same solver/maxiter/dtype) retrace nothing.
     The cache rides on the executor instance, which also owns the matvec
-    the closures capture — their lifetimes match by construction.
+    and its plan arrays — their lifetimes match by construction.
+
+    ``step(mv, s)`` receives the matvec bound to the plan arrays, which
+    enter the jitted runners as arguments (`SpMVEngine.device_matvec`), not
+    as compiled-in constants.
 
     Invariant: cond/step may only close over values that are constant for
-    the executor's lifetime (the matvec, maxiter, n). Anything that can
-    differ between calls sharing a cache key — b, tolerances, damping —
-    must flow through the loop state, or a warm solve replays the first
-    call's value as a baked-in jit constant."""
+    the executor's lifetime (maxiter, n). Anything that can differ between
+    calls sharing a cache key — b, tolerances, damping — must flow through
+    the loop state, or a warm solve replays the first call's value as a
+    baked-in jit constant."""
     cache = ex.__dict__.setdefault("_solver_loop_cache", {})
     entry = cache.get(key)
     if entry is None:
+        apply, ops = ex.device_matvec()
+
+        def bound_step(ops, s):
+            return step(lambda v: apply(ops, v), s)
+
         entry = {
-            "while": jax.jit(lambda s: jax.lax.while_loop(cond, step, s)),
+            "ops": ops,
+            "while": jax.jit(lambda ops, s: jax.lax.while_loop(
+                cond, lambda s: bound_step(ops, s), s
+            )),
             "cond": jax.jit(cond),
-            "step": jax.jit(step),
+            "step": jax.jit(bound_step),
         }
         cache[key] = entry
     return entry
 
 
 def _drive(entry, state, loop: str):
+    ops = entry["ops"]
     if loop == "while":
-        return entry["while"](state)
+        return entry["while"](ops, state)
     cond_j, step_j = entry["cond"], entry["step"]
     while bool(cond_j(state)):
-        state = step_j(state)
+        state = step_j(ops, state)
     return state
 
 
@@ -218,11 +231,10 @@ def cg(
 
 
 def _cg_device(ex, b, *, tol, maxiter, x0, trace, mode) -> SolveResult:
-    mv = ex.device_matvec()
     b = jnp.asarray(b)
     x = jnp.zeros_like(b) if x0 is None else jnp.asarray(x0, b.dtype)
     bb = jnp.vdot(b, b)
-    r = b - mv(x)
+    r = b - ex.matvec(x)
     rr = jnp.vdot(r, r)
     tol2 = jnp.asarray(tol, bb.dtype) ** 2 * bb
     tr = jnp.zeros((maxiter,), b.dtype)
@@ -232,7 +244,7 @@ def _cg_device(ex, b, *, tol, maxiter, x0, trace, mode) -> SolveResult:
         _x, _r, _p, rr, k, tol2, _tr = s
         return (k < maxiter) & (rr > tol2)
 
-    def step(s):
+    def step(mv, s):
         x, r, p, rr, k, tol2, tr = s
         Ap = mv(p)
         alpha = rr / jnp.vdot(p, Ap)
@@ -393,7 +405,6 @@ def jacobi(
 
 def _jacobi_device(ex, b, *, inv_d, tol, maxiter, x0, trace,
                    mode) -> SolveResult:
-    mv = ex.device_matvec()
     b = jnp.asarray(b)
     x = jnp.zeros_like(b) if x0 is None else jnp.asarray(x0, b.dtype)
     bb = jnp.vdot(b, b)
@@ -414,7 +425,7 @@ def _jacobi_device(ex, b, *, inv_d, tol, maxiter, x0, trace,
         _x, _b, rr, k, _inv_d, tol2, _tr = s
         return (k < maxiter) & (rr > tol2)
 
-    def step(s):
+    def step(mv, s):
         x, b, _rr, k, inv_d, tol2, tr = s
         r = b - mv(x)
         rr = jnp.vdot(r, r)
@@ -542,7 +553,6 @@ def pagerank(
 
 def _pagerank_device(ex, n, *, damping, tol, maxiter, x0, trace,
                      mode) -> SolveResult:
-    mv = ex.device_matvec()
     dtype = _default_dtype()  # f32, or f64 under jax_enable_x64
     x = (jnp.full((n,), 1.0 / n, dtype) if x0 is None
          else jnp.asarray(x0, dtype))
@@ -558,7 +568,7 @@ def _pagerank_device(ex, n, *, damping, tol, maxiter, x0, trace,
         _x, delta, k, _damp, tolc, _tr = s
         return (k < maxiter) & (delta > tolc)
 
-    def step(s):
+    def step(mv, s):
         x, _delta, k, damp, tolc, tr = s
         y = damp * mv(x)
         y = y + (1.0 - jnp.sum(y)) / n
@@ -648,7 +658,6 @@ def power_iteration(
 
 
 def _power_device(ex, n, *, tol, maxiter, x0, trace, mode) -> SolveResult:
-    mv = ex.device_matvec()
     dtype = _default_dtype()
     x = (jnp.full((n,), 1.0 / math.sqrt(n), dtype) if x0 is None
          else jnp.asarray(x0, dtype))
@@ -664,7 +673,7 @@ def _power_device(ex, n, *, tol, maxiter, x0, trace, mode) -> SolveResult:
         _x, _lam, delta, k, tolc, _tr = s
         return (k < maxiter) & (delta > tolc)
 
-    def step(s):
+    def step(mv, s):
         x, _lam, _delta, k, tolc, tr = s
         y = mv(x)
         lam = jnp.vdot(x, y)  # Rayleigh quotient (x is unit-norm)

@@ -7,16 +7,17 @@ Mechanism mapping (see DESIGN.md §2):
     CSHR policy's access count.
   * The CSHR *Hitmap* is the vectorized mask `elem_warp == t`; the *Offsets*
     are `elem_offset`. Extraction + response-splitting + element packing
-    (paper Fig. 2b return path) collapse into ONE one-hot matmul on the MXU:
-        out[window] += onehot(hitmap, offsets) @ table_block
+    (paper Fig. 2b return path) collapse into ONE one-hot matmul on the MXU,
+    with the one-hot built transposed from the (1, window) metadata row:
+        out[window] += onehot_t(hitmap, offsets)^T @ table_block
     which restores original request order for free.
   * The index-side "parallel indexing" is the vectorized schedule construction
     in core.coalescer.build_block_schedule (all N lanes at once).
 
 The table block is (block_rows, D): `block_rows * D * itemsize` plays the role
-of the 512 b DRAM access granularity; on TPU it should be a multiple of the
-(8, 128) VMEM tile. MXU-aligned choices (block_rows=128, D%128==0) make the
-extraction matmul full-throughput.
+of the 512 b DRAM access granularity. The table is viewed as
+(n_blocks, block_rows, D) so every block ends in full array dims, which the
+TPU's block-shape rule accepts for any block_rows (paged KV uses 1).
 
 Like the SELL kernels, this kernel consumes a `DevicePlan` — the gather
 geometry is the degenerate SELL one (`n_slices = n_windows`, one chunk of
@@ -39,7 +40,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.coalescer import BlockSchedule, resolve_schedule
 
-from .sell_spmv import DevicePlan, _decode_meta, build_device_plan
+from .sell_spmv import (
+    DevicePlan,
+    _decode_meta,
+    _meta_rows,
+    build_device_plan,
+    run_slice_groups,
+    slices_per_call,
+)
 
 
 def build_gather_plan(
@@ -121,9 +129,10 @@ def resolve_gather_plan(
 
 
 def _kernel(
-    tags_ref,  # scalar-prefetch: (n_windows, max_warps) int32 (sentinel->0)
-    elem_meta_ref,  # (1, 1, window) packed | (1, 1, 2, window) unpacked
-    table_block_ref,  # (block_rows, D) — the coalesced wide fetch
+    tags_ref,  # scalar-prefetch: (group, max_warps) int32 (sentinel->0)
+    base_ref,  # scalar-prefetch (1,): the group's first window
+    elem_meta_ref,  # (1, 1, 1 | 2, window)
+    table_block_ref,  # (1, block_rows, D) — the coalesced wide fetch
     out_ref,  # (window, D)
     *,
     block_rows: int,
@@ -136,22 +145,20 @@ def _kernel(
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    meta = elem_meta_ref[0, 0]  # (window,) packed | (2, window) unpacked
-    ew, eo = _decode_meta(meta, packed=packed)
-    # Hitmap x Offsets -> one-hot extraction matrix for this request warp.
-    hit = ew == t
-    rows = jax.lax.broadcasted_iota(jnp.int32, (window, block_rows), 1)
-    onehot = (hit[:, None] & (eo[:, None] == rows)).astype(table_block_ref.dtype)
-    out_ref[...] += jax.lax.dot(
-        onehot, table_block_ref[...], preferred_element_type=out_ref.dtype
-    )
-
-
-def _meta_block_spec(window: int, packed: bool) -> pl.BlockSpec:
-    """One chunk of plan metadata per grid step w (both encodings)."""
-    if packed:
-        return pl.BlockSpec((1, 1, window), lambda w, t, tags: (w, 0, 0))
-    return pl.BlockSpec((1, 1, 2, window), lambda w, t, tags: (w, 0, 0, 0))
+    ew, eo = _decode_meta(elem_meta_ref[0, 0], packed=packed)
+    # Hitmap x Offsets -> one-hot extraction matrix for this request warp,
+    # built transposed (block_rows, window) from the (1, window) metadata row
+    # and contracted over the block-row axis.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_rows, window), 0)
+    onehot_t = ((ew == t) & (eo == rows)).astype(jnp.float32)
+    # Extract in f32: each output row receives exactly one table row, so the
+    # cast back to the table dtype is exact.
+    out_ref[...] += jax.lax.dot_general(
+        onehot_t, table_block_ref[0].astype(jnp.float32),
+        (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -185,7 +192,9 @@ def coalesced_gather_pallas(
     A prebuilt `schedule` (core.engine.cached_block_schedule) skips per-call
     plan construction; a prebuilt `plan` (`build_gather_plan`) additionally
     skips the schedule->plan lowering, and then `indices` may be None —
-    `n_out` (default: the plan's padded length) trims the output."""
+    `n_out` (default: the plan's padded length) trims the output. Plans whose
+    tags exceed the SMEM budget run as one call per group of windows, like
+    the SELL kernels."""
     R, D = table.shape
     dplan = resolve_gather_plan(
         indices, window=window, block_rows=block_rows, max_warps=max_warps,
@@ -199,25 +208,41 @@ def coalesced_gather_pallas(
             f"n_out={n_out} does not fit the plan's {n_windows} windows of "
             f"{window} ({n_windows * window} padded elements)"
         )
-    # Pad table to whole blocks.
+    # Pad table to whole blocks, one (block_rows, D) block per leading index
+    # so each block ends in full array dims (any block_rows, 1 included).
     n_blocks = -(-R // block_rows)
-    table_p = jnp.pad(table, ((0, n_blocks * block_rows - R), (0, 0)))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_windows, dplan.max_warps),
-        in_specs=[
-            _meta_block_spec(window, dplan.packed),
-            pl.BlockSpec((block_rows, D), lambda w, t, tags: (tags[w, t], 0)),
-        ],
-        out_specs=pl.BlockSpec((window, D), lambda w, t, tags: (w, 0)),
+    table_p = jnp.pad(table, ((0, n_blocks * block_rows - R), (0, 0))).reshape(
+        n_blocks, block_rows, D
     )
-    out = pl.pallas_call(
+
+    group = slices_per_call(n_windows, 1, dplan.max_warps)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(group, dplan.max_warps),
+        in_specs=[
+            pl.BlockSpec(
+                (1, 1, _meta_rows(dplan.packed), window),
+                lambda w, t, tags, base: (base[0] + w, 0, 0, 0),
+            ),
+            pl.BlockSpec(
+                (1, block_rows, D),
+                lambda w, t, tags, base: (tags[w, t], 0, 0),
+            ),
+        ],
+        out_specs=pl.BlockSpec((window, D), lambda w, t, tags, base: (w, 0)),
+    )
+    call = pl.pallas_call(
         functools.partial(
             _kernel, block_rows=block_rows, window=window, packed=dplan.packed
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_windows * window, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((group * window, D), table.dtype),
         interpret=interpret,
-    )(dplan.tags, dplan.elem_meta, table_p)
-    return out[:n_out]
+    )
+    out = run_slice_groups(
+        lambda base, tags: call(tags, base, dplan.elem_meta, table_p).reshape(
+            group, window, D
+        ),
+        dplan.tags, n_slices=n_windows, n_chunks=1, group=group,
+    )
+    return out.reshape(n_windows * window, D)[:n_out]

@@ -4,14 +4,8 @@ On TPU the kernels compile natively; everywhere else they run in
 ``interpret=True`` mode (Python-evaluated kernel bodies) so the whole library
 is testable on CPU. ``backend="jnp"`` falls through to the oracle — used by
 the framework when a call site is too small to justify a kernel launch.
-
-``REPRO_PALLAS_INTERPRET=1|0`` overrides the platform default — CI's
-interpret-mode job pins it to 1 so the kernel bodies are exercised on every
-push regardless of where the runner lands.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,19 +17,12 @@ from .sell_spmv import DEFAULT_BUFFER_DEPTH, sell_spmv_pallas
 
 
 def resolve_interpret(interpret: bool | None = None) -> bool:
-    """Resolve the pallas `interpret` flag: an explicit argument wins, then
-    the ``REPRO_PALLAS_INTERPRET`` env var, then "interpret everywhere but
-    TPU" (the only platform these kernels compile natively for)."""
+    """Resolve the pallas `interpret` flag: an explicit argument wins, else
+    "interpret everywhere but TPU" (the only platform these kernels compile
+    natively for)."""
     if interpret is not None:
         return bool(interpret)
-    env = (os.environ.get("REPRO_PALLAS_INTERPRET") or "").strip().lower()
-    if env:  # empty/unset falls through to the platform default
-        return env not in ("0", "false")
     return jax.default_backend() != "tpu"
-
-
-def _interpret_default() -> bool:
-    return resolve_interpret()
 
 
 def coalesced_gather(

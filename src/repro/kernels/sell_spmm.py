@@ -6,10 +6,11 @@ one right-hand side; this kernel applies the same reuse argument *across* the
 RHS batch: instead of re-running the coalesced x-gather and re-streaming the
 schedule metadata and SELL values once per column (what vmapping the matvec
 kernel does), each warp's wide fetch grabs a ``(block_rows, k_tile)`` tile of
-the dense X and the one-hot extraction becomes a real MXU matmul
+the dense X and the extraction becomes a real MXU matmul: the one-hot is
+built transposed and weighted by the chunk's values, folded over the chunk's
+columns into ``m (block_rows, H)``, and
 
-    onehot (window, block_rows) @ X_block (block_rows, k_tile)
-        -> gathered (window, k_tile)
+    m^T (H, block_rows) @ X_block (block_rows, k_tile) -> (H, k_tile)
 
 so the metadata stream and the SELL values are read **once per k_tile
 columns** instead of once per column — HBM SpMV designs (Serpens) and the
@@ -45,23 +46,47 @@ from .sell_spmv import (
     DevicePlan,
     _decode_meta,
     _meta_block_spec,
+    _meta_rows,
+    _sum_chunk_columns,
     _validate_buffer_depth,
+    chunk_values,
     resolve_device_plan,
+    run_slice_groups,
+    slices_per_call,
 )
 
 
+def _accumulate(ew, eo, t, vals, x_block, out_ref, *, block_rows: int,
+                cols_per_chunk: int, slice_height: int):
+    """One warp's contribution to the (H, k_tile) output tile.
+
+    The one-hot is built transposed, ``(block_rows, window)``, from the
+    (1, window) metadata row and weighted by the chunk's values; folding the
+    chunk's columns gives ``m (block_rows, H)``, and one MXU matmul
+    contracting the block-row axis applies it to the whole RHS tile:
+    ``out (H, k_tile) += m^T @ X_block``, in at least f32 (the MXU
+    accumulates in 32 bits) whatever the storage dtypes."""
+    acc = jnp.promote_types(out_ref.dtype, jnp.float32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_rows, ew.shape[1]), 0)
+    w = jnp.where((ew == t) & (eo == rows), vals.astype(acc), 0)
+    m = _sum_chunk_columns(w, cols_per_chunk, slice_height)
+    out_ref[0, 0] += jax.lax.dot_general(
+        m, x_block.astype(acc), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=acc,
+    ).astype(out_ref.dtype)
+
+
 def _kernel(
-    tags_ref,  # scalar-prefetch (n_windows, max_warps)
-    elem_meta_ref,  # (1, 1, window) packed | (1, 1, 2, window) unpacked
-    values_ref,  # (1, 1, C, H)
-    x_block_ref,  # (1, block_rows, k_tile) — coalesced wide fetch of X
-    out_ref,  # (1, H, k_tile)
+    tags_ref,  # scalar-prefetch (group * n_chunks, max_warps)
+    base_ref,  # scalar-prefetch (1,): the group's first slice
+    elem_meta_ref,  # (1, 1, 1 | 2, window)
+    values_ref,  # (1, 1, 1, window)
+    x_block_ref,  # (1, 1, block_rows, k_tile) — coalesced wide fetch of X
+    out_ref,  # (1, 1, H, k_tile)
     *,
     block_rows: int,
-    window: int,
     cols_per_chunk: int,
     slice_height: int,
-    k_tile: int,
     packed: bool,
 ):
     c = pl.program_id(2)
@@ -72,35 +97,29 @@ def _kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
     ew, eo = _decode_meta(elem_meta_ref[0, 0], packed=packed)
-    hit = ew == t
-    rows = jax.lax.broadcasted_iota(jnp.int32, (window, block_rows), 1)
-    onehot = (hit[:, None] & (eo[:, None] == rows)).astype(x_block_ref.dtype)
-    # Extraction across the whole RHS tile: response-splitter + element-packer
-    # as one MXU matmul — the wide fetch is amortized over k_tile columns.
-    gathered = jax.lax.dot(
-        onehot, x_block_ref[0], preferred_element_type=out_ref.dtype
-    )  # (window, k_tile)
-    g = gathered.reshape(cols_per_chunk, slice_height, k_tile)
-    # VPC VMAC, broadcast over the RHS tile: multiply by nonzeros and reduce
-    # over the chunk's columns.
-    out_ref[0] += jnp.sum(values_ref[0, 0][:, :, None] * g, axis=0)
+    # Extraction across the whole RHS tile: the wide fetch is amortized over
+    # k_tile columns.
+    _accumulate(
+        ew, eo, t, values_ref[0, 0], x_block_ref[0, 0], out_ref,
+        block_rows=block_rows, cols_per_chunk=cols_per_chunk,
+        slice_height=slice_height,
+    )
 
 
 def _kernel_buffered(
-    tags_ref,  # scalar-prefetch (n_windows, max_warps)
+    tags_ref,  # scalar-prefetch (group * n_chunks, max_warps)
+    base_ref,  # scalar-prefetch (1,): the group's first slice
     elem_meta_hbm,  # full meta array, ANY memory space
-    values_hbm,  # full (n_slices, n_chunks, C, H) values, ANY memory space
-    x_block_ref,  # (1, block_rows, k_tile)
-    out_ref,  # (1, H, k_tile)
-    meta_vmem,  # (depth, window) | (depth, 2, window) scratch
-    vals_vmem,  # (depth, C, H) scratch
+    values_hbm,  # full (n_slices, n_chunks, 1, window) values, ANY space
+    x_block_ref,  # (1, 1, block_rows, k_tile)
+    out_ref,  # (1, 1, H, k_tile)
+    meta_vmem,  # (depth, 1 | 2, window) scratch
+    vals_vmem,  # (depth, 1, window) scratch
     sems,  # DMA semaphores (2, depth)
     *,
     block_rows: int,
-    window: int,
     cols_per_chunk: int,
     slice_height: int,
-    k_tile: int,
     packed: bool,
     n_chunks: int,
     n_ktiles: int,
@@ -120,7 +139,7 @@ def _kernel_buffered(
 
     def chunk_dma(gg, slot):
         c_g = gg % n_chunks
-        s_g = (gg // n_chunks) // n_ktiles
+        s_g = base_ref[0] + (gg // n_chunks) // n_ktiles
         return (
             pltpu.make_async_copy(
                 elem_meta_hbm.at[s_g, c_g], meta_vmem.at[slot],
@@ -156,14 +175,11 @@ def _kernel_buffered(
             cp.wait()
 
     ew, eo = _decode_meta(meta_vmem[slot], packed=packed)
-    hit = ew == t
-    rows = jax.lax.broadcasted_iota(jnp.int32, (window, block_rows), 1)
-    onehot = (hit[:, None] & (eo[:, None] == rows)).astype(x_block_ref.dtype)
-    gathered = jax.lax.dot(
-        onehot, x_block_ref[0], preferred_element_type=out_ref.dtype
-    )  # (window, k_tile)
-    g_vals = gathered.reshape(cols_per_chunk, slice_height, k_tile)
-    out_ref[0] += jnp.sum(vals_vmem[slot][:, :, None] * g_vals, axis=0)
+    _accumulate(
+        ew, eo, t, vals_vmem[slot], x_block_ref[0, 0], out_ref,
+        block_rows=block_rows, cols_per_chunk=cols_per_chunk,
+        slice_height=slice_height,
+    )
 
 
 @functools.partial(
@@ -197,8 +213,8 @@ def sell_spmm_pallas(
     prebuilt `schedule`/`plan` objects the matvec kernel takes are accepted —
     `core.engine.SpMVEngine` shares one `DevicePlan` between both kernels —
     and with either, `colidx` may be None (it never touches the dispatch
-    path). `packed` and `buffer_depth` behave exactly as in
-    `sell_spmv_pallas`."""
+    path). `packed`, `buffer_depth` and the split into calls over groups of
+    slices behave exactly as in `sell_spmv_pallas`."""
     n_slices, W, H = values.shape
     if X.ndim != 2:
         raise ValueError(f"sell_spmm expects X of shape (n_cols, k), got "
@@ -224,7 +240,7 @@ def sell_spmm_pallas(
         cols_per_chunk=cols_per_chunk, block_rows=block_rows,
         max_warps=max_warps, schedule=schedule, plan=plan, packed=packed,
     )
-    vals = values.reshape(n_slices, n_chunks, cols_per_chunk, H)
+    vals = chunk_values(values, cols_per_chunk)
 
     # Clamp the tile to k (a 1-column batch must not pay k_tile columns of
     # MXU work), then pad k up to a whole number of tiles with zero columns.
@@ -233,67 +249,69 @@ def sell_spmm_pallas(
     k_pad = n_ktiles * kt
     R = X.shape[0]
     n_blocks = -(-R // block_rows)
+    # One (block_rows, kt) tile per (wide block, k-tile): each block ends in
+    # full array dims, as TPU block shapes must.
     X_p = jnp.pad(
         X, ((0, n_blocks * block_rows - R), (0, k_pad - k))
-    ).reshape(n_blocks, block_rows, k_pad)
+    ).reshape(n_blocks, block_rows, n_ktiles, kt).transpose(0, 2, 1, 3)
 
-    def tag_of(s, q, c, t, tags):
-        return (tags[s * n_chunks + c, t], 0, q)
+    def tag_of(s, q, c, t, tags, base):
+        return (tags[s * n_chunks + c, t], q, 0, 0)
 
+    group = slices_per_call(n_slices, n_chunks, dplan.max_warps)
     # Accumulate in the promoted dtype (bf16 values x f32 RHS -> f32
     # accumulation), matching ref.sell_spmm_ref's natural promotion.
-    out_shape = jax.ShapeDtypeStruct((n_slices, H, k_pad), out_dtype)
-    out_spec = pl.BlockSpec((1, H, kt), lambda s, q, c, t, tags: (s, 0, q))
-    x_spec = pl.BlockSpec((1, block_rows, kt), tag_of)
+    out_shape = jax.ShapeDtypeStruct((group, n_ktiles, H, kt), out_dtype)
+    out_spec = pl.BlockSpec(
+        (1, 1, H, kt), lambda s, q, c, t, tags, base: (s, q, 0, 0)
+    )
+    x_spec = pl.BlockSpec((1, 1, block_rows, kt), tag_of)
     common = dict(
-        block_rows=block_rows, window=window, cols_per_chunk=cols_per_chunk,
-        slice_height=H, k_tile=kt, packed=dplan.packed,
+        block_rows=block_rows, cols_per_chunk=cols_per_chunk,
+        slice_height=H, packed=dplan.packed,
     )
     if depth == 1:
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_slices, n_ktiles, n_chunks, dplan.max_warps),
+            num_scalar_prefetch=2,
+            grid=(group, n_ktiles, n_chunks, dplan.max_warps),
             in_specs=[
                 _meta_block_spec(window, dplan.packed, rank=3),
                 pl.BlockSpec(
-                    (1, 1, cols_per_chunk, H),
-                    lambda s, q, c, t, tags: (s, c, 0, 0),
+                    (1, 1, 1, window),
+                    lambda s, q, c, t, tags, base: (base[0] + s, c, 0, 0),
                 ),
                 x_spec,
             ],
             out_specs=out_spec,
         )
-        out = pl.pallas_call(
-            functools.partial(_kernel, **common),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(dplan.tags, dplan.elem_meta, vals, X_p)
+        kernel = functools.partial(_kernel, **common)
     else:
-        meta_slot = (2, window) if not dplan.packed else (window,)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_slices, n_ktiles, n_chunks, dplan.max_warps),
+            num_scalar_prefetch=2,
+            grid=(group, n_ktiles, n_chunks, dplan.max_warps),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 x_spec,
             ],
             out_specs=out_spec,
             scratch_shapes=[
-                pltpu.VMEM((depth, *meta_slot), jnp.int32),
-                pltpu.VMEM((depth, cols_per_chunk, H), values.dtype),
+                pltpu.VMEM((depth, _meta_rows(dplan.packed), window),
+                           jnp.int32),
+                pltpu.VMEM((depth, 1, window), values.dtype),
                 pltpu.SemaphoreType.DMA((2, depth)),
             ],
         )
-        out = pl.pallas_call(
-            functools.partial(
-                _kernel_buffered, **common,
-                n_chunks=n_chunks, n_ktiles=n_ktiles,
-                total_chunks=n_slices * n_ktiles * n_chunks, depth=depth,
-            ),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(dplan.tags, dplan.elem_meta, vals, X_p)
-    return out.reshape(n_slices * H, k_pad)[:, :k]
+        kernel = functools.partial(
+            _kernel_buffered, **common,
+            n_chunks=n_chunks, n_ktiles=n_ktiles,
+            total_chunks=group * n_ktiles * n_chunks, depth=depth,
+        )
+    call = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+    )
+    out = run_slice_groups(
+        lambda base, tags: call(tags, base, dplan.elem_meta, vals, X_p),
+        dplan.tags, n_slices=n_slices, n_chunks=n_chunks, group=group,
+    )  # (n_slices, n_ktiles, H, kt)
+    return out.transpose(0, 2, 1, 3).reshape(n_slices * H, k_pad)[:, :k]

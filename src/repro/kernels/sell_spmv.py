@@ -9,7 +9,10 @@ indirect stream overlap exactly as prefetching overlaps compute in the paper.
 Layout: padded SELL (n_slices, W, H) with H = slice height (32), W padded to a
 multiple of `cols_per_chunk`. One *window* of the indirect stream = one
 (slice, chunk) = cols_per_chunk * H indices, matching the paper's windowed
-coalescing of the column-index stream.
+coalescing of the column-index stream. The kernels stream each chunk's
+values and metadata as one (1, window) row, and every block ends in full
+array dims (the TPU's (8, 128) block-shape rule); a chunk's window element
+``j * H + h`` folds into output row h.
 
 `DevicePlan` is the kernel-ready, device-resident form of a `BlockSchedule`:
 the SENTINEL-sanitized tag matrix plus the per-(slice, chunk) metadata words.
@@ -78,12 +81,14 @@ class DevicePlan:
                any `elem_warp`, so block 0 is a safe dummy fetch target and
                the scalar-prefetch index map needs no branch).
     elem_meta: the per-element indirect-stream words, already reshaped to the
-               (slice, chunk) grid the kernels iterate.
-               packed=True  -> (n_slices, n_chunks, window) int32, each word
-                               ``(elem_warp << 16) | elem_offset``
+               (slice, chunk) grid the kernels iterate, one row per word
+               kind so each chunk's block ends in full array dims (the TPU
+               block-shape rule) and lays out compactly in HBM.
+               packed=True  -> (n_slices, n_chunks, 1, window) int32, each
+                               word ``(elem_warp << 16) | elem_offset``
                                (4 metadata bytes/element);
-               packed=False -> (n_slices, n_chunks, 2, window) int32, lane 0
-                               elem_warp, lane 1 elem_offset (8 bytes/element,
+               packed=False -> (n_slices, n_chunks, 2, window) int32, row 0
+                               elem_warp, row 1 elem_offset (8 bytes/element,
                                the lossless fallback for geometries whose
                                warp ids or offsets overflow 16 bits).
 
@@ -113,14 +118,14 @@ class DevicePlan:
     def elem_warp(self) -> jnp.ndarray:
         """(n_slices, n_chunks, window) int32 warp ids, whatever the encoding."""
         if self.packed:
-            return jax.lax.shift_right_logical(self.elem_meta, 16)
+            return jax.lax.shift_right_logical(self.elem_meta[:, :, 0, :], 16)
         return self.elem_meta[:, :, 0, :]
 
     @property
     def elem_offset(self) -> jnp.ndarray:
         """(n_slices, n_chunks, window) int32 offsets, whatever the encoding."""
         if self.packed:
-            return jnp.bitwise_and(self.elem_meta, 0xFFFF)
+            return jnp.bitwise_and(self.elem_meta[:, :, 0, :], 0xFFFF)
         return self.elem_meta[:, :, 1, :]
 
     @property
@@ -198,7 +203,7 @@ def build_device_plan(
         # Both halves fit 16 bits; the shift may carry into the sign bit
         # (warp >= 2**15), which is why every decode site uses a *logical*
         # right shift.
-        elem_meta = jnp.bitwise_or(jnp.left_shift(ew, 16), eo)
+        elem_meta = jnp.bitwise_or(jnp.left_shift(ew, 16), eo)[:, :, None, :]
     else:
         elem_meta = jnp.stack([ew, eo], axis=2)
     return DevicePlan(
@@ -305,18 +310,25 @@ def resolve_device_plan(
 
 
 def _decode_meta(meta, *, packed: bool):
-    """Split one chunk's metadata into (elem_warp, elem_offset).
+    """Split one chunk's metadata into (elem_warp, elem_offset), each
+    (1, window) int32.
 
-    `meta` is (window,) int32 when packed, (2, window) int32 otherwise. The
+    `meta` is (1, window) int32 when packed, (2, window) int32 otherwise. The
     packed decode must be a *logical* shift: warp ids >= 2**15 set the int32
     sign bit and an arithmetic shift would smear it."""
     if packed:
         ew = jax.lax.shift_right_logical(meta, 16)
         eo = jnp.bitwise_and(meta, 0xFFFF)
     else:
-        ew = meta[0]
-        eo = meta[1]
+        ew = meta[0:1]
+        eo = meta[1:2]
     return ew, eo
+
+
+def _meta_rows(packed: bool) -> int:
+    """Rows per chunk of `DevicePlan.elem_meta`: one packed word, or the
+    (warp, offset) pair."""
+    return 1 if packed else 2
 
 
 def _validate_buffer_depth(buffer_depth: int) -> int:
@@ -330,15 +342,91 @@ def _validate_buffer_depth(buffer_depth: int) -> int:
     return depth
 
 
+#: Bytes of scalar-prefetched tag rows one kernel call may hold. The tags sit
+#: in SMEM (1 MiB per TPU v5e core) with every row padded to 128 lanes, so a
+#: plan larger than this runs as several calls over groups of slices.
+SMEM_TAG_BUDGET = 512 * 1024
+
+
+def slices_per_call(n_slices: int, n_chunks: int, max_warps: int) -> int:
+    """Slices one kernel call covers so that its tag rows fit
+    `SMEM_TAG_BUDGET`."""
+    row_bytes = 4 * 128 * -(-max(int(max_warps), 1) // 128)
+    fit = SMEM_TAG_BUDGET // (row_bytes * n_chunks)
+    return max(1, min(int(n_slices), fit))
+
+
+def run_slice_groups(call, tags, *, n_slices: int, n_chunks: int,
+                     group: int):
+    """Run ``call(base, tags_group)`` over consecutive groups of `group`
+    slices and stitch the per-slice outputs back together.
+
+    ``base`` is a (1,) int32 array holding the group's first slice, passed to
+    the kernel as a second scalar-prefetch operand; ``tags_group`` holds the
+    group's ``group * n_chunks`` tag rows. ``call`` returns an array whose
+    leading axis is the group's `group` slices. The last group is shifted back
+    to end at ``n_slices`` (it overlaps its predecessor rather than reading
+    past the plan), and only its new slices are kept."""
+    n_groups = -(-n_slices // group)
+
+    def one(g):
+        base = jnp.minimum(g * group, n_slices - group).astype(jnp.int32)
+        tags_g = jax.lax.dynamic_slice_in_dim(
+            tags, base * n_chunks, group * n_chunks
+        )
+        return call(base.reshape(1), tags_g)
+
+    if n_groups == 1:
+        return one(jnp.int32(0))
+    out = jax.lax.map(one, jnp.arange(n_groups, dtype=jnp.int32))
+    head = out[:-1].reshape(-1, *out.shape[2:])
+    return jnp.concatenate([head, out[-1][n_groups * group - n_slices:]])
+
+
+def _gather_x(ew, eo, t, x_row, block_rows: int):
+    """(1, window) x values for the elements warp `t` serves: element i takes
+    ``x_row[eo[i]]`` where ``ew[i] == t`` and 0 elsewhere. The
+    response-splitter + element-packer as `block_rows` selects, so the f32
+    values pass through unrounded."""
+    hit = ew == t
+    g = jnp.zeros(ew.shape, x_row.dtype)
+    for r in range(block_rows):
+        g = jnp.where(hit & (eo == r), x_row[:, r:r + 1], g)
+    return g
+
+
+def _sum_chunk_columns(prod, cols_per_chunk: int, slice_height: int):
+    """Reduce a (rows, window) chunk over its `cols_per_chunk` columns (the
+    VPC VMAC's reduction): window element ``j * H + h`` folds into lane h."""
+    acc = prod[:, :slice_height]
+    for j in range(1, cols_per_chunk):
+        acc = acc + prod[:, j * slice_height:(j + 1) * slice_height]
+    return acc
+
+
+def _chunk_contribution(ew, eo, t, vals, x_row, out_dtype, *,
+                        block_rows: int, cols_per_chunk: int,
+                        slice_height: int):
+    """(1, H) contribution of warp `t` to a slice's output: gather, multiply
+    by the nonzeros and reduce over the chunk's columns (the VPC VMAC), in
+    at least f32 whatever the storage dtypes."""
+    cdt = jnp.promote_types(out_dtype, jnp.float32)
+    g = _gather_x(ew, eo, t, x_row, block_rows)
+    prod = vals.astype(cdt) * g.astype(cdt)
+    return _sum_chunk_columns(prod, cols_per_chunk, slice_height).astype(
+        out_dtype
+    )
+
+
 def _kernel(
-    tags_ref,  # scalar-prefetch (n_windows, max_warps)
-    elem_meta_ref,  # (1, 1, window) packed | (1, 1, 2, window) unpacked
-    values_ref,  # (1, 1, C, H)
-    x_block_ref,  # (1, block_rows) — coalesced wide fetch of x
-    out_ref,  # (1, H)
+    tags_ref,  # scalar-prefetch (group * n_chunks, max_warps)
+    base_ref,  # scalar-prefetch (1,): the group's first slice
+    elem_meta_ref,  # (1, 1, 1 | 2, window)
+    values_ref,  # (1, 1, 1, window)
+    x_block_ref,  # (1, 1, block_rows) — coalesced wide fetch of x
+    out_ref,  # (1, 1, H)
     *,
     block_rows: int,
-    window: int,
     cols_per_chunk: int,
     slice_height: int,
     packed: bool,
@@ -351,30 +439,25 @@ def _kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
     ew, eo = _decode_meta(elem_meta_ref[0, 0], packed=packed)
-    hit = ew == t
-    rows = jax.lax.broadcasted_iota(jnp.int32, (window, block_rows), 1)
-    onehot = (hit[:, None] & (eo[:, None] == rows)).astype(x_block_ref.dtype)
-    # Extraction: response-splitter + element-packer as one matvec.
-    gathered = jax.lax.dot(
-        onehot, x_block_ref[0, :][:, None], preferred_element_type=out_ref.dtype
-    )[:, 0]
-    g = gathered.reshape(cols_per_chunk, slice_height)
-    # VPC VMAC: multiply by nonzeros and reduce over the chunk's columns.
-    out_ref[0, :] += jnp.sum(values_ref[0, 0] * g, axis=0)
+    out_ref[0] += _chunk_contribution(
+        ew, eo, t, values_ref[0, 0], x_block_ref[0], out_ref.dtype,
+        block_rows=block_rows, cols_per_chunk=cols_per_chunk,
+        slice_height=slice_height,
+    )
 
 
 def _kernel_buffered(
-    tags_ref,  # scalar-prefetch (n_windows, max_warps)
+    tags_ref,  # scalar-prefetch (group * n_chunks, max_warps)
+    base_ref,  # scalar-prefetch (1,): the group's first slice
     elem_meta_hbm,  # full meta array, ANY memory space
-    values_hbm,  # full (n_slices, n_chunks, C, H) values, ANY memory space
-    x_block_ref,  # (1, block_rows) — coalesced wide fetch of x
-    out_ref,  # (1, H)
-    meta_vmem,  # (depth, window) | (depth, 2, window) scratch
-    vals_vmem,  # (depth, C, H) scratch
+    values_hbm,  # full (n_slices, n_chunks, 1, window) values, ANY space
+    x_block_ref,  # (1, 1, block_rows) — coalesced wide fetch of x
+    out_ref,  # (1, 1, H)
+    meta_vmem,  # (depth, 1 | 2, window) scratch
+    vals_vmem,  # (depth, 1, window) scratch
     sems,  # DMA semaphores (2, depth)
     *,
     block_rows: int,
-    window: int,
     cols_per_chunk: int,
     slice_height: int,
     packed: bool,
@@ -391,10 +474,10 @@ def _kernel_buffered(
     s = pl.program_id(0)
     c = pl.program_id(1)
     t = pl.program_id(2)
-    g = s * n_chunks + c  # linearized chunk index across slices
+    g = s * n_chunks + c  # linearized chunk index across this call's slices
 
     def chunk_dma(gg, slot):
-        s_g = gg // n_chunks
+        s_g = base_ref[0] + gg // n_chunks
         c_g = gg % n_chunks
         return (
             pltpu.make_async_copy(
@@ -433,33 +516,34 @@ def _kernel_buffered(
             cp.wait()
 
     ew, eo = _decode_meta(meta_vmem[slot], packed=packed)
-    hit = ew == t
-    rows = jax.lax.broadcasted_iota(jnp.int32, (window, block_rows), 1)
-    onehot = (hit[:, None] & (eo[:, None] == rows)).astype(x_block_ref.dtype)
-    gathered = jax.lax.dot(
-        onehot, x_block_ref[0, :][:, None], preferred_element_type=out_ref.dtype
-    )[:, 0]
-    g_vals = gathered.reshape(cols_per_chunk, slice_height)
-    out_ref[0, :] += jnp.sum(vals_vmem[slot] * g_vals, axis=0)
+    out_ref[0] += _chunk_contribution(
+        ew, eo, t, vals_vmem[slot], x_block_ref[0], out_ref.dtype,
+        block_rows=block_rows, cols_per_chunk=cols_per_chunk,
+        slice_height=slice_height,
+    )
 
 
 def _meta_block_spec(window: int, packed: bool, rank: int):
     """BlockSpec for one chunk's metadata in the depth-1 path. `rank` is the
     number of leading grid axes in the index map signature (2 for spmv's
-    (s, c, t), 3 for spmm's (s, q, c, t))."""
+    (s, c, t), 3 for spmm's (s, q, c, t)); the map offsets the slice by the
+    call's group base."""
+    block = (1, 1, _meta_rows(packed), window)
     if rank == 2:
-        if packed:
-            return pl.BlockSpec((1, 1, window), lambda s, c, t, tags: (s, c, 0))
         return pl.BlockSpec(
-            (1, 1, 2, window), lambda s, c, t, tags: (s, c, 0, 0)
-        )
-    if packed:
-        return pl.BlockSpec(
-            (1, 1, window), lambda s, q, c, t, tags: (s, c, 0)
+            block, lambda s, c, t, tags, base: (base[0] + s, c, 0, 0)
         )
     return pl.BlockSpec(
-        (1, 1, 2, window), lambda s, q, c, t, tags: (s, c, 0, 0)
+        block, lambda s, q, c, t, tags, base: (base[0] + s, c, 0, 0)
     )
+
+
+def chunk_values(values: jnp.ndarray, cols_per_chunk: int) -> jnp.ndarray:
+    """(n_slices, W, H) SELL values as one (1, window) row per (slice, chunk)
+    — the layout both SELL kernels stream. Plan-owning callers store values
+    in this shape so no call pays a relayout."""
+    n_slices, W, H = values.shape
+    return values.reshape(n_slices, W // cols_per_chunk, 1, cols_per_chunk * H)
 
 
 @functools.partial(
@@ -495,7 +579,8 @@ def sell_spmv_pallas(
     (None == "auto": one int32 word per element whenever lossless);
     `buffer_depth >= 2` streams values + metadata through a rotating VMEM
     scratch with async copies (see `_kernel_buffered`), `buffer_depth=1`
-    keeps the classic BlockSpec pipeline."""
+    keeps the classic BlockSpec pipeline. Plans whose tags exceed
+    `SMEM_TAG_BUDGET` run as one kernel call per group of slices."""
     n_slices, W, H = values.shape
     if W % cols_per_chunk != 0:
         raise ValueError(
@@ -513,70 +598,70 @@ def sell_spmv_pallas(
         cols_per_chunk=cols_per_chunk, block_rows=block_rows,
         max_warps=max_warps, schedule=schedule, plan=plan, packed=packed,
     )
-    vals = values.reshape(n_slices, n_chunks, cols_per_chunk, H)
+    vals = chunk_values(values, cols_per_chunk)
 
     R = x.shape[0]
     n_blocks = -(-R // block_rows)
-    x_p = jnp.pad(x, (0, n_blocks * block_rows - R)).reshape(n_blocks, block_rows)
+    x_p = jnp.pad(x, (0, n_blocks * block_rows - R)).reshape(
+        n_blocks, 1, block_rows
+    )
 
-    def tag_of(s, c, t, tags):
-        return (tags[s * n_chunks + c, t], 0)
+    def tag_of(s, c, t, tags, base):
+        return (tags[s * n_chunks + c, t], 0, 0)
 
+    group = slices_per_call(n_slices, n_chunks, dplan.max_warps)
     out_shape = jax.ShapeDtypeStruct(
         # Accumulate in the promoted dtype (bf16 values x f32 input -> f32
         # accumulation), matching ref.sell_spmv_ref's natural promotion.
-        (n_slices, H), jnp.promote_types(values.dtype, x.dtype)
+        (group, 1, H), jnp.promote_types(values.dtype, x.dtype)
     )
-    out_spec = pl.BlockSpec((1, H), lambda s, c, t, tags: (s, 0))
-    x_spec = pl.BlockSpec((1, block_rows), tag_of)
+    out_spec = pl.BlockSpec((1, 1, H), lambda s, c, t, tags, base: (s, 0, 0))
+    x_spec = pl.BlockSpec((1, 1, block_rows), tag_of)
     common = dict(
-        block_rows=block_rows, window=window, cols_per_chunk=cols_per_chunk,
+        block_rows=block_rows, cols_per_chunk=cols_per_chunk,
         slice_height=H, packed=dplan.packed,
     )
     if depth == 1:
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_slices, n_chunks, dplan.max_warps),
+            num_scalar_prefetch=2,
+            grid=(group, n_chunks, dplan.max_warps),
             in_specs=[
                 _meta_block_spec(window, dplan.packed, rank=2),
                 pl.BlockSpec(
-                    (1, 1, cols_per_chunk, H), lambda s, c, t, tags: (s, c, 0, 0)
+                    (1, 1, 1, window),
+                    lambda s, c, t, tags, base: (base[0] + s, c, 0, 0),
                 ),
                 x_spec,
             ],
             out_specs=out_spec,
         )
-        out = pl.pallas_call(
-            functools.partial(_kernel, **common),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(dplan.tags, dplan.elem_meta, vals, x_p)
+        kernel = functools.partial(_kernel, **common)
     else:
-        meta_slot = (2, window) if not dplan.packed else (window,)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_slices, n_chunks, dplan.max_warps),
+            num_scalar_prefetch=2,
+            grid=(group, n_chunks, dplan.max_warps),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 x_spec,
             ],
             out_specs=out_spec,
             scratch_shapes=[
-                pltpu.VMEM((depth, *meta_slot), jnp.int32),
-                pltpu.VMEM((depth, cols_per_chunk, H), values.dtype),
+                pltpu.VMEM((depth, _meta_rows(dplan.packed), window),
+                           jnp.int32),
+                pltpu.VMEM((depth, 1, window), values.dtype),
                 pltpu.SemaphoreType.DMA((2, depth)),
             ],
         )
-        out = pl.pallas_call(
-            functools.partial(
-                _kernel_buffered, **common,
-                n_chunks=n_chunks, total_chunks=n_slices * n_chunks,
-                depth=depth,
-            ),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(dplan.tags, dplan.elem_meta, vals, x_p)
+        kernel = functools.partial(
+            _kernel_buffered, **common,
+            n_chunks=n_chunks, total_chunks=group * n_chunks, depth=depth,
+        )
+    call = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+    )
+    out = run_slice_groups(
+        lambda base, tags: call(tags, base, dplan.elem_meta, vals, x_p),
+        dplan.tags, n_slices=n_slices, n_chunks=n_chunks, group=group,
+    )
     return out.reshape(-1)

@@ -10,17 +10,26 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """A mesh whose axes are all `AxisType.Auto`: the stack's sharding rules
+    are partition specs that the compiler propagates (`sharding.rules`),
+    while `jax.make_mesh` defaults to explicit axes."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests / examples on CPU)."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _auto_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def auto_spmv_mesh() -> jax.sharding.Mesh:

@@ -37,6 +37,7 @@ import numpy as np
 from repro.configs import get_arch
 from repro.core import faults
 from repro.core import matrices as _matgen
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model, make_input_batch
 from repro.models.transformer import Runtime
 
@@ -663,6 +664,7 @@ def main() -> None:
         "fault recovered, and parity with the fault-free oracle held",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.solve and not args.spmv:
         ap.error("--solve requires --spmv to pick the matrix family")

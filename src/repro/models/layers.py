@@ -14,6 +14,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.indirect_stream import coalesced_gather
 
@@ -73,7 +74,14 @@ def embedding_apply(
 ) -> jnp.ndarray:
     """(B, S) int32 -> (B, S, D). backend: jnp | coalesced | pallas."""
     if backend == "jnp":
-        return p["embed"][token_ids]
+        ids = jax.typeof(token_ids).sharding
+        if ids.mesh.empty:
+            return p["embed"][token_ids]
+        # Under explicit mesh axes the gather's output sharding must be
+        # stated: rows follow the token ids' sharding, features replicate.
+        return p["embed"].at[token_ids].get(
+            out_sharding=NamedSharding(ids.mesh, P(*ids.spec, None))
+        )
     return coalesced_gather(
         p["embed"], token_ids, window=window, block_rows=block_rows,
         backend=backend,

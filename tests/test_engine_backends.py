@@ -161,8 +161,8 @@ def test_auto_backend_resolves_off_tpu():
 
 def test_repro_backend_env_var_steers_auto(monkeypatch):
     """$REPRO_BACKEND overrides the platform rule for backend="auto" only:
-    explicit backends win, empty string means unset (mirroring
-    REPRO_PALLAS_INTERPRET), garbage is rejected."""
+    explicit backends win, empty string means unset, garbage is
+    rejected."""
     platform_default = resolve_backend("auto")
     monkeypatch.setenv("REPRO_BACKEND", "pallas")
     assert resolve_backend("auto") == "pallas"

@@ -338,6 +338,25 @@ def test_shard_failure_recovers_bit_identical_degraded_mode():
     assert rep["injected"] == 1 and rep["unrecovered"] == 0
 
 
+def test_real_shard_error_propagates(monkeypatch):
+    """Only injected faults are recovered: a genuine shard failure (a
+    compile or device error) must surface, not be recomputed on the
+    reference backend behind the caller's back."""
+    csr = _csr(n=256)
+    X = RNG.standard_normal((csr.n_cols, 4)).astype(np.float32)
+    eng = ShardedSpMVEngine(
+        csr, mesh=parse_mesh_spec("1,1"), n_shards=2, backend="reference"
+    )
+
+    def broken(X):
+        raise RuntimeError("device kernel failed to compile")
+
+    monkeypatch.setattr(eng.engines[1], "matmat", broken)
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        eng.matmat(X)
+    assert eng.recovery_report()["recovered"] == 0
+
+
 # --------------------------------------------------------------------------
 # property: recovery is invisible in the numbers (reference backend)
 # --------------------------------------------------------------------------

@@ -153,21 +153,17 @@ def test_mismatched_prebuilt_schedule_rejected():
         )
 
 
-def test_resolve_interpret_env_override(monkeypatch):
-    """Explicit arg > REPRO_PALLAS_INTERPRET > platform default; an empty
-    env var means unset, not "force native compile"."""
-    default = jax.default_backend() != "tpu"
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert ops.resolve_interpret() is default
-    assert ops.resolve_interpret(True) is True
-    assert ops.resolve_interpret(False) is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert ops.resolve_interpret() is True
-    assert ops.resolve_interpret(False) is False  # arg wins
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+def test_resolve_interpret_follows_platform_and_argument(monkeypatch):
+    """Only the explicit argument and the platform decide interpret mode:
+    no environment variable can force interpretation on a TPU."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
     assert ops.resolve_interpret() is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "")
-    assert ops.resolve_interpret() is default
+    assert ops.resolve_interpret(True) is True
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")  # ignored
+    assert ops.resolve_interpret() is False
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "cpu")
+    assert ops.resolve_interpret() is True
+    assert ops.resolve_interpret(False) is False
 
 
 def test_max_warps_reduction_still_correct():
@@ -178,3 +174,39 @@ def test_max_warps_reduction_still_correct():
                                max_warps=8)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(table)[np.asarray(idx)])
+
+
+@pytest.mark.parametrize("kernel", ["spmv-d1", "spmv-d2", "spmm", "gather"])
+def test_kernels_split_into_slice_groups(monkeypatch, kernel):
+    """A plan whose tags exceed the SMEM budget runs as one kernel call per
+    group of slices (the last group shifted back to overlap its predecessor);
+    the stitched result must match the oracle exactly as a single call."""
+    from repro.kernels import sell_spmv
+
+    rng = np.random.default_rng(3)
+    n_slices, W, H, n_cols, cpc = 7, 16, 8, 400, 4
+    # 3 slices (6 windows of 128-lane tag rows) per call: groups 0-2, 3-5, 4-6
+    monkeypatch.setattr(sell_spmv, "SMEM_TAG_BUDGET", 3 * (W // cpc) * 512)
+    jax.clear_caches()
+    if kernel == "gather":
+        table = jnp.asarray(rng.standard_normal((n_cols, 16)), jnp.float32)
+        idx = jnp.asarray(rng.integers(0, n_cols, size=7 * 32), jnp.int32)
+        monkeypatch.setattr(sell_spmv, "SMEM_TAG_BUDGET", 3 * 512)
+        out = ops.coalesced_gather(table, idx, window=32, block_rows=8)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(table)[idx])
+        return
+    colidx = jnp.asarray(rng.integers(0, n_cols, size=(n_slices, W, H)),
+                         jnp.int32)
+    values = jnp.asarray(rng.standard_normal((n_slices, W, H)), jnp.float32)
+    if kernel == "spmm":
+        X = jnp.asarray(rng.standard_normal((n_cols, 5)), jnp.float32)
+        y = ops.sell_spmm(colidx, values, X, cols_per_chunk=cpc, k_tile=2)
+        ye = ref.sell_spmm_ref(colidx, values, X)
+    else:
+        x = jnp.asarray(rng.standard_normal(n_cols), jnp.float32)
+        y = ops.sell_spmv(colidx, values, x, cols_per_chunk=cpc,
+                          buffer_depth=int(kernel[-1]))
+        ye = ref.sell_spmv_ref(colidx, values, x)
+    assert sell_spmv.slices_per_call(n_slices, W // cpc, 1) == 3
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ye), rtol=1e-5,
+                               atol=1e-5)

@@ -102,7 +102,7 @@ def test_pack_decodes_high_warp_ids_with_logical_shift():
     meta = jnp.asarray((ew.astype(np.int64) << 16) | eo, jnp.int32)
     plan = DevicePlan(
         tags=jnp.zeros((4, 1), jnp.int32),
-        elem_meta=meta.reshape(1, 1, 4),
+        elem_meta=meta.reshape(1, 1, 1, 4),
         window=4, block_rows=PACK_LIMIT, cols_per_chunk=1, slice_height=4,
         n_slices=1, n_chunks=1, packed=True,
     )

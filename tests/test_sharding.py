@@ -105,8 +105,9 @@ MULTIDEV_SNIPPET = textwrap.dedent(
     from repro.sharding.rules import param_pspecs, batch_pspecs, to_shardings
     from repro.optim.optimizer import OptConfig, init_opt_state
     from repro.train.steps import make_train_step
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(model_axis=4)  # (data=2, model=4)
     cfg = ARCHS["tinyllama-1.1b"].reduced()
     model = build_model(cfg)
     rt = Runtime()
