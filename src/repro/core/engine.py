@@ -76,6 +76,7 @@ from .formats import CSRMatrix, SELLMatrix
 from .perfmodel import DEFAULT_HW, HWConfig, matmat_spmv_perf, spmv_perf, \
     streaming_spmv_perf
 from .runtime import device_put_rhs, normalize_to_sell, pad_width
+from .spans import span
 
 BACKENDS = ("reference", "pallas", "auto")
 BACKEND_ENV = "REPRO_BACKEND"
@@ -354,11 +355,12 @@ def _build_lock_for(key) -> threading.RLock:
 def stream_digest(indices: np.ndarray) -> str:
     """SHA-256 of an index stream's bytes (plus shape/dtype, so e.g. an int32
     and an int64 view of the same bytes don't collide)."""
-    arr = np.ascontiguousarray(np.asarray(indices))
-    h = hashlib.sha256()
-    h.update(str((arr.shape, arr.dtype.str)).encode())
-    h.update(arr.tobytes())
-    return h.hexdigest()
+    with span("planner.digest"):
+        arr = np.ascontiguousarray(np.asarray(indices))
+        h = hashlib.sha256()
+        h.update(str((arr.shape, arr.dtype.str)).encode())
+        h.update(arr.tobytes())
+        return h.hexdigest()
 
 
 def cached_block_schedule(
@@ -439,20 +441,21 @@ def cached_block_schedule(
                     schedule_store.quarantine(path)
                     rebuilding = True
 
-        sched = build_block_schedule(
-            jnp.asarray(np.asarray(indices, dtype=np.int32)),
-            window=window,
-            block_rows=block_rows,
-            max_warps=max_warps,
-        )
-        # Materialize now: the cache must hand out ready metadata, not lazy
-        # traces.
-        sched = jax.tree_util.tree_map(
-            lambda a: a.block_until_ready()
-            if hasattr(a, "block_until_ready") else a,
-            sched,
-        )
-        sched = trim_schedule_warps(sched)
+        with span("planner.schedule"):
+            sched = build_block_schedule(
+                jnp.asarray(np.asarray(indices, dtype=np.int32)),
+                window=window,
+                block_rows=block_rows,
+                max_warps=max_warps,
+            )
+            # Materialize now: the cache must hand out ready metadata, not
+            # lazy traces.
+            sched = jax.tree_util.tree_map(
+                lambda a: a.block_until_ready()
+                if hasattr(a, "block_until_ready") else a,
+                sched,
+            )
+            sched = trim_schedule_warps(sched)
         _bump("built")
         if rebuilding:
             _bump("rebuilds")
@@ -555,15 +558,17 @@ def _sell_content_digest(sell: SELLMatrix) -> str:
     cached = getattr(sell, "_content_digest", None)
     if cached is not None:
         return cached
-    h = hashlib.sha256()
-    h.update(
-        str((sell.n_rows, sell.n_cols, sell.slice_height)).encode()
-    )
-    for arr in (sell.slice_ptrs, sell.slice_widths, sell.colidx, sell.values):
-        a = np.ascontiguousarray(np.asarray(arr))
-        h.update(str((a.shape, a.dtype.str)).encode())
-        h.update(a.tobytes())
-    digest = h.hexdigest()
+    with span("planner.digest"):
+        h = hashlib.sha256()
+        h.update(
+            str((sell.n_rows, sell.n_cols, sell.slice_height)).encode()
+        )
+        for arr in (sell.slice_ptrs, sell.slice_widths, sell.colidx,
+                    sell.values):
+            a = np.ascontiguousarray(np.asarray(arr))
+            h.update(str((a.shape, a.dtype.str)).encode())
+            h.update(a.tobytes())
+        digest = h.hexdigest()
     sell._content_digest = digest
     return digest
 
@@ -627,9 +632,11 @@ class SpMVEngine:
         plan_width_multiple: Optional[int] = None,
         cache_dir: Optional[str] = None,
     ):
-        sell = normalize_to_sell(
-            matrix, slice_height=slice_height, width_multiple=width_multiple
-        )
+        with span("planner.convert"):
+            sell = normalize_to_sell(
+                matrix, slice_height=slice_height,
+                width_multiple=width_multiple,
+            )
         self.sell = sell
         self.backend = backend  # as requested ("auto" preserved for report)
         self.backend_resolved = resolve_backend(backend)
@@ -715,12 +722,13 @@ class SpMVEngine:
 
     def _ensure_plan_locked(self):
         if self._plan is None:
-            va, stream, W = self._ensure_padded()
-            ci_plan, va_plan, W_plan = pad_width(
-                self._ci3, va, multiple=self.plan_width_multiple
-            )
-            if W_plan != W:
-                stream = np.ascontiguousarray(ci_plan.reshape(-1))
+            with span("planner.convert"):
+                va, stream, W = self._ensure_padded()
+                ci_plan, va_plan, W_plan = pad_width(
+                    self._ci3, va, multiple=self.plan_width_multiple
+                )
+                if W_plan != W:
+                    stream = np.ascontiguousarray(ci_plan.reshape(-1))
             self._plan = (ci_plan, va_plan, stream, W, W_plan)
             # The base padded arrays are now redundant (the plan holds what
             # execution needs); drop them so a padded pallas engine doesn't
@@ -775,7 +783,10 @@ class SpMVEngine:
 
     def _ensure_compiled(self):
         with self._plan_lock:
-            return self._ensure_compiled_locked()
+            if self._matvec is None:
+                with span("planner"):
+                    return self._ensure_compiled_locked()
+            return self._matvec, self._matmat
 
     def _ensure_compiled_locked(self):
         if self._matvec is None:
@@ -784,8 +795,10 @@ class SpMVEngine:
             sell = self.sell
             n_slices, H = sell.n_slices, sell.slice_height
             n_rows, n_out = sell.n_rows, stream.shape[0]
-            _matmat_fused = None
-            _matmat_ref = None
+            # Named after what they run: a profile's module line reads
+            # jit_engine_matvec, jit_engine_matmat and so on.
+            engine_matmat = None
+            engine_matmat_ref = None
             # Narrow value storage: cast the hoisted value plan once per
             # trace; the multiply promotes back to the RHS dtype (f32
             # accumulation for bf16 values).
@@ -795,41 +808,46 @@ class SpMVEngine:
             )
 
             if self.backend_resolved == "pallas":
-                # Locals to the kernels package are lazy: core must stay
-                # importable before kernels (which itself imports core).
-                from repro.kernels.ops import resolve_interpret
-                from repro.kernels.sell_spmm import sell_spmm_pallas
-                from repro.kernels.sell_spmv import build_device_plan, \
-                    chunk_values, sell_spmv_pallas
-
-                interpret = resolve_interpret()
                 cpc = self.cols_per_chunk
                 block_rows = self.block_rows
                 kt = self.k_tile
                 depth = self.buffer_depth
-                # Lower the schedule to the kernel-ready device plan exactly
-                # once; the matvec and the fused matmat kernels share it. The
-                # schedule already encodes every gather, so the column-index
-                # array is never shipped into a kernel call (colidx=None).
-                # `packed` resolves here against the real schedule geometry
-                # (auto: one int32 word per element whenever lossless).
-                plan = build_device_plan(
-                    sched, n_slices=n_slices, cols_per_chunk=cpc,
-                    slice_height=H, packed=self.packed,
-                )
-                self._device_plan = plan
-                # Values in the kernels' per-chunk row layout, so the reshape
-                # below and the kernel's own cancel and no call relayouts.
-                operands = (
-                    chunk_values(jnp.asarray(va_plan, vdt), cpc), plan
-                )
+                with span("planner.lower") as counts:
+                    # Locals to the kernels package are lazy: core must stay
+                    # importable before kernels (which itself imports core).
+                    # The first engine of a process pays the import here.
+                    from repro.kernels.ops import resolve_interpret
+                    from repro.kernels.sell_spmm import sell_spmm_pallas
+                    from repro.kernels.sell_spmv import build_device_plan, \
+                        chunk_values, grid_steps, sell_spmv_pallas
+
+                    # Lower the schedule to the kernel-ready device plan
+                    # exactly once; the matvec and the fused matmat kernels
+                    # share it. The schedule already encodes every gather,
+                    # so the column-index array is never shipped into a
+                    # kernel call (colidx=None). `packed` resolves here
+                    # against the real schedule geometry (auto: one int32
+                    # word per element whenever lossless).
+                    plan = build_device_plan(
+                        sched, n_slices=n_slices, cols_per_chunk=cpc,
+                        slice_height=H, packed=self.packed,
+                    )
+                    self._device_plan = plan
+                    # Values in the kernels' per-chunk row layout, so the
+                    # reshape below and the kernel's own cancel and no call
+                    # relayouts.
+                    operands = jax.block_until_ready((
+                        chunk_values(jnp.asarray(va_plan, vdt), cpc), plan
+                    ))
+                    counts["grid_steps"] = grid_steps(plan)
+                interpret = resolve_interpret()
 
                 def _values(va, dtype):
                     return va.reshape(n_slices, W_plan, H).astype(
                         vdt if vdt is not None else dtype
                     )
 
-                def _matvec(ops, x: jnp.ndarray) -> jnp.ndarray:
+                def engine_matvec(ops, x: jnp.ndarray) -> jnp.ndarray:
                     va, plan = ops
                     y = sell_spmv_pallas(
                         None,
@@ -845,7 +863,7 @@ class SpMVEngine:
 
                 if self.matmat_mode_resolved == "fused":
 
-                    def _matmat_fused(ops, X: jnp.ndarray) -> jnp.ndarray:
+                    def engine_matmat(ops, X: jnp.ndarray) -> jnp.ndarray:
                         va, plan = ops
                         Y = sell_spmm_pallas(
                             None,
@@ -861,9 +879,12 @@ class SpMVEngine:
                         return Y[:n_rows]
 
             else:
-                operands = (sched, jnp.asarray(va_plan[:, :W], vdt))
+                with span("planner.lower"):
+                    operands = jax.block_until_ready(
+                        (sched, jnp.asarray(va_plan[:, :W], vdt))
+                    )
 
-                def _matvec(ops, x: jnp.ndarray) -> jnp.ndarray:
+                def engine_matvec(ops, x: jnp.ndarray) -> jnp.ndarray:
                     sched, va = ops
                     gathered = schedule_gather_reference(
                         x[:, None], sched, n_out=n_out
@@ -876,8 +897,8 @@ class SpMVEngine:
                     y = _width_tree_sum(va * g, _runtime_one(x))
                     return y.reshape(-1)[:n_rows]
 
-                def _matmat_ref(ops, X: jnp.ndarray) -> jnp.ndarray:
-                    # Direct 2-D variant of _matvec: same gather, same
+                def engine_matmat_ref(ops, X: jnp.ndarray) -> jnp.ndarray:
+                    # Direct 2-D variant of matvec: same gather, same
                     # product, same tree folds per column (the folds are
                     # exact, so per-column bit-identity to matvec is
                     # structural), with one shared gather pass per batch.
@@ -894,14 +915,16 @@ class SpMVEngine:
                     return y.reshape(-1, k)[:n_rows]
 
             self._operands = {None: operands}
-            self._matvec = jax.jit(_matvec)
+            self._matvec = jax.jit(engine_matvec)
             self._matmat_vmapped = (
-                jax.jit(_matmat_ref) if _matmat_fused is None
-                and _matmat_ref is not None
-                else jax.jit(jax.vmap(_matvec, in_axes=(None, 1), out_axes=1))
+                jax.jit(engine_matmat_ref) if engine_matmat is None
+                and engine_matmat_ref is not None
+                else jax.jit(
+                    jax.vmap(engine_matvec, in_axes=(None, 1), out_axes=1)
+                )
             )
             self._matmat = (
-                jax.jit(_matmat_fused) if _matmat_fused is not None
+                jax.jit(engine_matmat) if engine_matmat is not None
                 else self._matmat_vmapped
             )
         return self._matvec, self._matmat
@@ -924,7 +947,8 @@ class SpMVEngine:
                 f"matvec expects x of shape ({self.sell.n_cols},), got {x.shape}"
             )
         mv, _ = self._ensure_compiled()
-        return mv(self._operands_for(x), x)
+        with span("engine.matvec"):
+            return mv(self._operands_for(x), x)
 
     def _operands_for(self, x: jnp.ndarray):
         """The plan arrays on the device `x` lives on (placed there once), so
@@ -970,7 +994,8 @@ class SpMVEngine:
                 f"matmat expects X of shape ({self.sell.n_cols}, k), got {X.shape}"
             )
         _, mm = self._ensure_compiled()
-        return mm(self._operands_for(X), X)
+        with span("engine.matmat"):
+            return mm(self._operands_for(X), X)
 
     def matmat_vmapped(self, X: jnp.ndarray) -> jnp.ndarray:
         """The per-column baseline: `matvec` vmapped over RHS columns (one
@@ -1008,8 +1033,7 @@ class SpMVEngine:
     def dispatch(self, staged: jnp.ndarray) -> jnp.ndarray:
         """Launch the batched matmat on an already-staged micro-batch —
         async (JAX dispatch), no host synchronization."""
-        _, mm = self._ensure_compiled()
-        return mm(self._operands_for(staged), staged)
+        return self.matmat(staged)
 
     def finalize(self, pending: jnp.ndarray) -> jnp.ndarray:
         """Block until a dispatched micro-batch's result is materialized."""
@@ -1163,6 +1187,7 @@ class SpMVEngine:
         return report
 
 
+@span("planner")
 def get_engine(
     matrix: Union[CSRMatrix, SELLMatrix],
     *,
@@ -1195,10 +1220,11 @@ def get_engine(
     cache lookup. `cache_dir` is not part of the key — it changes where a
     plan is stored, never what it is. Thread-safe: concurrent callers with
     the same key get the same engine object."""
-    matrix = normalize_to_sell(
-        matrix, slice_height=slice_height, width_multiple=width_multiple,
-        validate=False,  # O(nnz) scan deferred to construction on a miss
-    )
+    with span("planner.convert"):
+        matrix = normalize_to_sell(
+            matrix, slice_height=slice_height, width_multiple=width_multiple,
+            validate=False,  # O(nnz) scan deferred to construction on a miss
+        )
     resolved = resolve_backend(backend)
     mode_resolved = resolve_matmat_mode(matmat_mode, resolved)
     if packed not in PACKED_CHOICES:

@@ -29,6 +29,11 @@ Every solve reports `schedule_builds` — the delta of the global
 plan-build counter across the solve — so callers (and the benchmark
 gate) can assert the schedule was built exactly once regardless of
 iteration count.
+
+Each call runs in a ``solver.<name>`` span (`core.spans`); on the device
+loops it holds ``solver.<name>.start`` (the eager prologue that builds the
+loop state), ``solver.<name>.loop`` (dispatch of the jitted runner) and
+``solver.<name>.result`` (the reads back to the host).
 """
 from __future__ import annotations
 
@@ -42,6 +47,7 @@ import numpy as np
 
 from .engine import get_engine, schedule_cache_stats
 from .formats import CSRMatrix, SELLMatrix, coo_to_csr
+from .spans import span
 
 __all__ = [
     "SolveResult",
@@ -155,11 +161,14 @@ def _loop_runners(ex, key, cond, step):
         def bound_step(ops, s):
             return step(lambda v: apply(ops, v), s)
 
+        def run_loop(ops, s):
+            return jax.lax.while_loop(cond, lambda s: bound_step(ops, s), s)
+
+        # A profile's module line then reads jit_cg_loop and so on.
+        run_loop.__name__ = f"{key[0]}_loop"
         entry = {
             "ops": ops,
-            "while": jax.jit(lambda ops, s: jax.lax.while_loop(
-                cond, lambda s: bound_step(ops, s), s
-            )),
+            "while": jax.jit(run_loop),
             "cond": jax.jit(cond),
             "step": jax.jit(bound_step),
         }
@@ -183,16 +192,36 @@ def _trace_out(tr, iterations: int, want: bool) -> Optional[np.ndarray]:
     return np.asarray(tr)[:iterations]
 
 
-class _BuildCounter:
-    """Delta of the global schedule-build counter across a solve."""
+def _device_solve(solver, ex, mode, maxiter, start, cond, step, finish):
+    """One solve on a device loop, in its three spans: ``start()`` builds
+    the loop state eagerly and returns it with what ``finish`` needs
+    besides; the jitted runner (cached per solver, `maxiter` and dtype of
+    the iterate, the state's first entry) runs `cond`/`step`; ``finish(state,
+    aux)`` reads the result back into a `SolveResult`."""
+    with span(f"solver.{solver}.start"):
+        state, aux = start()
+    with span(f"solver.{solver}.loop"):
+        key = (solver, maxiter, str(state[0].dtype))
+        state = _drive(_loop_runners(ex, key, cond, step), state, mode)
+    with span(f"solver.{solver}.result"):
+        return finish(state, aux)
+
+
+class _Solve:
+    """One public solver call: its ``solver.<name>`` span, and the delta of
+    the global schedule-build counter across it."""
+
+    def __init__(self, solver: str):
+        self._span = span(f"solver.{solver}")
 
     def __enter__(self):
+        self._span.__enter__()
         self._before = schedule_cache_stats()["built"]
         return self
 
     def __exit__(self, *exc):
         self.builds = schedule_cache_stats()["built"] - self._before
-        return False
+        return self._span.__exit__(*exc)
 
 
 # --------------------------------------------------------------------------
@@ -215,7 +244,7 @@ def cg(
     `core.matrices.make_spd` / `core.matrices.spd` produce valid inputs).
     Converges when ||r||_2 <= tol * ||b||_2, capped at ``maxiter``
     (default n) iterations."""
-    with _BuildCounter() as bc:
+    with _Solve("cg") as bc:
         ex = _resolve_operator(A, backend=backend, engine_kw=engine_kw)
         n = _require_square(ex, "cg")
         mode = _resolve_loop(loop, ex)
@@ -231,14 +260,15 @@ def cg(
 
 
 def _cg_device(ex, b, *, tol, maxiter, x0, trace, mode) -> SolveResult:
-    b = jnp.asarray(b)
-    x = jnp.zeros_like(b) if x0 is None else jnp.asarray(x0, b.dtype)
-    bb = jnp.vdot(b, b)
-    r = b - ex.matvec(x)
-    rr = jnp.vdot(r, r)
-    tol2 = jnp.asarray(tol, bb.dtype) ** 2 * bb
-    tr = jnp.zeros((maxiter,), b.dtype)
-    state = (x, r, r, rr, jnp.asarray(0, jnp.int32), tol2, tr)
+    def start():
+        b_ = jnp.asarray(b)
+        x = jnp.zeros_like(b_) if x0 is None else jnp.asarray(x0, b_.dtype)
+        bb = jnp.vdot(b_, b_)
+        r = b_ - ex.matvec(x)
+        rr = jnp.vdot(r, r)
+        tol2 = jnp.asarray(tol, bb.dtype) ** 2 * bb
+        tr = jnp.zeros((maxiter,), b_.dtype)
+        return (x, r, r, rr, jnp.asarray(0, jnp.int32), tol2, tr), bb
 
     def cond(s):
         _x, _r, _p, rr, k, tol2, _tr = s
@@ -255,21 +285,23 @@ def _cg_device(ex, b, *, tol, maxiter, x0, trace, mode) -> SolveResult:
         tr = tr.at[k].set(jnp.sqrt(rr_new))
         return (x, r, p, rr_new, k + 1, tol2, tr)
 
-    entry = _loop_runners(ex, ("cg", maxiter, str(b.dtype)), cond, step)
-    x, r, p, rr, k, tol2, tr = _drive(entry, state, mode)
-    iters = int(k)
-    bb_f = float(bb)
-    resid = math.sqrt(float(rr)) / math.sqrt(bb_f) if bb_f > 0 else 0.0
-    return SolveResult(
-        x=x,
-        iterations=iters,
-        residual=resid,
-        converged=bool(float(rr) <= float(tol2)),
-        solver="cg",
-        loop=mode,
-        schedule_builds=0,
-        residual_trace=_trace_out(tr, iters, trace),
-    )
+    def finish(state, bb):
+        x, r, p, rr, k, tol2, tr = state
+        iters = int(k)
+        bb_f = float(bb)
+        resid = math.sqrt(float(rr)) / math.sqrt(bb_f) if bb_f > 0 else 0.0
+        return SolveResult(
+            x=x,
+            iterations=iters,
+            residual=resid,
+            converged=bool(float(rr) <= float(tol2)),
+            solver="cg",
+            loop=mode,
+            schedule_builds=0,
+            residual_trace=_trace_out(tr, iters, trace),
+        )
+
+    return _device_solve("cg", ex, mode, maxiter, start, cond, step, finish)
 
 
 def _host_matvec_and_dot(ex) -> Callable[[np.ndarray], Tuple[np.ndarray, float]]:
@@ -379,7 +411,7 @@ def jacobi(
     the trace/result is that of the iterate *entering* each step (one
     extra half-step of progress is already applied when the loop exits —
     checking after the update would cost a second matvec per iteration)."""
-    with _BuildCounter() as bc:
+    with _Solve("jacobi") as bc:
         ex = _resolve_operator(A, backend=backend, engine_kw=engine_kw)
         n = _require_square(ex, "jacobi")
         mode = _resolve_loop(loop, ex)
@@ -405,21 +437,23 @@ def jacobi(
 
 def _jacobi_device(ex, b, *, inv_d, tol, maxiter, x0, trace,
                    mode) -> SolveResult:
-    b = jnp.asarray(b)
-    x = jnp.zeros_like(b) if x0 is None else jnp.asarray(x0, b.dtype)
-    bb = jnp.vdot(b, b)
-    tol2 = jnp.asarray(tol, bb.dtype) ** 2 * bb
-    inv_dj = jnp.asarray(inv_d, b.dtype)
-    tr = jnp.zeros((maxiter,), b.dtype)
-    # b rides in the loop state (not the closure): the jitted cond/step are
-    # cached per executor keyed only on (solver, maxiter, dtype), and a
-    # closure-captured b would be baked into the compiled step as a
-    # constant — a warm-engine solve with a different RHS would silently
-    # solve the *first* system.
-    state = (
-        x, b, jnp.asarray(jnp.inf, b.dtype), jnp.asarray(0, jnp.int32),
-        inv_dj, tol2, tr,
-    )
+    def start():
+        b_ = jnp.asarray(b)
+        x = jnp.zeros_like(b_) if x0 is None else jnp.asarray(x0, b_.dtype)
+        bb = jnp.vdot(b_, b_)
+        tol2 = jnp.asarray(tol, bb.dtype) ** 2 * bb
+        inv_dj = jnp.asarray(inv_d, b_.dtype)
+        tr = jnp.zeros((maxiter,), b_.dtype)
+        # b rides in the loop state (not the closure): the jitted cond/step
+        # are cached per executor keyed only on (solver, maxiter, dtype),
+        # and a closure-captured b would be baked into the compiled step as
+        # a constant — a warm-engine solve with a different RHS would
+        # silently solve the *first* system.
+        state = (
+            x, b_, jnp.asarray(jnp.inf, b_.dtype), jnp.asarray(0, jnp.int32),
+            inv_dj, tol2, tr,
+        )
+        return state, bb
 
     def cond(s):
         _x, _b, rr, k, _inv_d, tol2, _tr = s
@@ -433,21 +467,25 @@ def _jacobi_device(ex, b, *, inv_d, tol, maxiter, x0, trace,
         tr = tr.at[k].set(jnp.sqrt(rr))
         return (x, b, rr, k + 1, inv_d, tol2, tr)
 
-    entry = _loop_runners(ex, ("jacobi", maxiter, str(b.dtype)), cond, step)
-    x, _b, rr, k, _, tol2, tr = _drive(entry, state, mode)
-    iters = int(k)
-    bb_f = float(bb)
-    rr_f = float(rr) if np.isfinite(float(rr)) else float("inf")
-    resid = math.sqrt(rr_f) / math.sqrt(bb_f) if bb_f > 0 else 0.0
-    return SolveResult(
-        x=x,
-        iterations=iters,
-        residual=resid,
-        converged=bool(float(rr) <= float(tol2)),
-        solver="jacobi",
-        loop=mode,
-        schedule_builds=0,
-        residual_trace=_trace_out(tr, iters, trace),
+    def finish(state, bb):
+        x, _b, rr, k, _, tol2, tr = state
+        iters = int(k)
+        bb_f = float(bb)
+        rr_f = float(rr) if np.isfinite(float(rr)) else float("inf")
+        resid = math.sqrt(rr_f) / math.sqrt(bb_f) if bb_f > 0 else 0.0
+        return SolveResult(
+            x=x,
+            iterations=iters,
+            residual=resid,
+            converged=bool(float(rr) <= float(tol2)),
+            solver="jacobi",
+            loop=mode,
+            schedule_builds=0,
+            residual_trace=_trace_out(tr, iters, trace),
+        )
+
+    return _device_solve(
+        "jacobi", ex, mode, maxiter, start, cond, step, finish
     )
 
 
@@ -523,7 +561,7 @@ def pagerank(
     that folds teleport and dangling-node rank into one rank-1 correction,
     so sum(x) stays exactly 1 and the SpMV is the whole iteration.
     Converges when the L1 iterate delta drops below ``tol``."""
-    with _BuildCounter() as bc:
+    with _Solve("pagerank") as bc:
         if isinstance(A, CSRMatrix):
             ex = get_engine(
                 transition_matrix(A), backend=backend, **engine_kw
@@ -553,16 +591,18 @@ def pagerank(
 
 def _pagerank_device(ex, n, *, damping, tol, maxiter, x0, trace,
                      mode) -> SolveResult:
-    dtype = _default_dtype()  # f32, or f64 under jax_enable_x64
-    x = (jnp.full((n,), 1.0 / n, dtype) if x0 is None
-         else jnp.asarray(x0, dtype))
-    damp = jnp.asarray(damping, dtype)
-    tolc = jnp.asarray(tol, dtype)
-    tr = jnp.zeros((maxiter,), dtype)
-    state = (
-        x, jnp.asarray(jnp.inf, dtype), jnp.asarray(0, jnp.int32),
-        damp, tolc, tr,
-    )
+    def start():
+        dtype = _default_dtype()  # f32, or f64 under jax_enable_x64
+        x = (jnp.full((n,), 1.0 / n, dtype) if x0 is None
+             else jnp.asarray(x0, dtype))
+        damp = jnp.asarray(damping, dtype)
+        tolc = jnp.asarray(tol, dtype)
+        tr = jnp.zeros((maxiter,), dtype)
+        state = (
+            x, jnp.asarray(jnp.inf, dtype), jnp.asarray(0, jnp.int32),
+            damp, tolc, tr,
+        )
+        return state, None
 
     def cond(s):
         _x, delta, k, _damp, tolc, _tr = s
@@ -576,21 +616,23 @@ def _pagerank_device(ex, n, *, damping, tol, maxiter, x0, trace,
         tr = tr.at[k].set(delta)
         return (y, delta, k + 1, damp, tolc, tr)
 
-    entry = _loop_runners(
-        ex, ("pagerank", maxiter, str(dtype)), cond, step
-    )
-    x, delta, k, _, _, tr = _drive(entry, state, mode)
-    iters = int(k)
-    delta_f = float(delta)
-    return SolveResult(
-        x=x,
-        iterations=iters,
-        residual=delta_f if np.isfinite(delta_f) else float("inf"),
-        converged=bool(float(delta) <= tol),
-        solver="pagerank",
-        loop=mode,
-        schedule_builds=0,
-        residual_trace=_trace_out(tr, iters, trace),
+    def finish(state, _):
+        x, delta, k, _, _, tr = state
+        iters = int(k)
+        delta_f = float(delta)
+        return SolveResult(
+            x=x,
+            iterations=iters,
+            residual=delta_f if np.isfinite(delta_f) else float("inf"),
+            converged=bool(float(delta) <= tol),
+            solver="pagerank",
+            loop=mode,
+            schedule_builds=0,
+            residual_trace=_trace_out(tr, iters, trace),
+        )
+
+    return _device_solve(
+        "pagerank", ex, mode, maxiter, start, cond, step, finish
     )
 
 
@@ -640,7 +682,7 @@ def power_iteration(
     quotient; `SolveResult.eigenvalue` carries lam. Deterministic default
     start (normalized ones); pass ``x0`` if that is orthogonal to the
     dominant eigenvector."""
-    with _BuildCounter() as bc:
+    with _Solve("power_iteration") as bc:
         ex = _resolve_operator(A, backend=backend, engine_kw=engine_kw)
         n = _require_square(ex, "power_iteration")
         mode = _resolve_loop(loop, ex)
@@ -658,16 +700,18 @@ def power_iteration(
 
 
 def _power_device(ex, n, *, tol, maxiter, x0, trace, mode) -> SolveResult:
-    dtype = _default_dtype()
-    x = (jnp.full((n,), 1.0 / math.sqrt(n), dtype) if x0 is None
-         else jnp.asarray(x0, dtype))
-    x = x / jnp.sqrt(jnp.vdot(x, x))
-    tolc = jnp.asarray(tol, dtype)
-    tr = jnp.zeros((maxiter,), dtype)
-    state = (
-        x, jnp.asarray(0.0, dtype), jnp.asarray(jnp.inf, dtype),
-        jnp.asarray(0, jnp.int32), tolc, tr,
-    )
+    def start():
+        dtype = _default_dtype()
+        x = (jnp.full((n,), 1.0 / math.sqrt(n), dtype) if x0 is None
+             else jnp.asarray(x0, dtype))
+        x = x / jnp.sqrt(jnp.vdot(x, x))
+        tolc = jnp.asarray(tol, dtype)
+        tr = jnp.zeros((maxiter,), dtype)
+        state = (
+            x, jnp.asarray(0.0, dtype), jnp.asarray(jnp.inf, dtype),
+            jnp.asarray(0, jnp.int32), tolc, tr,
+        )
+        return state, None
 
     def cond(s):
         _x, _lam, delta, k, tolc, _tr = s
@@ -683,19 +727,23 @@ def _power_device(ex, n, *, tol, maxiter, x0, trace, mode) -> SolveResult:
         tr = tr.at[k].set(delta)
         return (x, lam, delta, k + 1, tolc, tr)
 
-    entry = _loop_runners(ex, ("power", maxiter, str(dtype)), cond, step)
-    x, lam, delta, k, _, tr = _drive(entry, state, mode)
-    iters = int(k)
-    return SolveResult(
-        x=x,
-        iterations=iters,
-        residual=float(delta),
-        converged=bool(float(delta) <= tol),
-        solver="power_iteration",
-        loop=mode,
-        schedule_builds=0,
-        residual_trace=_trace_out(tr, iters, trace),
-        eigenvalue=float(lam),
+    def finish(state, _):
+        x, lam, delta, k, _, tr = state
+        iters = int(k)
+        return SolveResult(
+            x=x,
+            iterations=iters,
+            residual=float(delta),
+            converged=bool(float(delta) <= tol),
+            solver="power_iteration",
+            loop=mode,
+            schedule_builds=0,
+            residual_trace=_trace_out(tr, iters, trace),
+            eigenvalue=float(lam),
+        )
+
+    return _device_solve(
+        "power_iteration", ex, mode, maxiter, start, cond, step, finish
     )
 
 

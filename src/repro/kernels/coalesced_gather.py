@@ -49,6 +49,10 @@ from .sell_spmv import (
     slices_per_call,
 )
 
+#: Name of the kernel's instruction on a device trace
+#: (``coalesced_gather.N``), as in `kernels.sell_spmv.KERNEL_NAME`.
+KERNEL_NAME = "coalesced_gather"
+
 
 def build_gather_plan(
     schedule: BlockSchedule, *, packed: bool | str = "auto"
@@ -238,11 +242,15 @@ def coalesced_gather_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((group * window, D), table.dtype),
         interpret=interpret,
+        name=KERNEL_NAME,
     )
+
+    def one_call(base, tags):
+        with jax.named_scope(KERNEL_NAME):
+            out = call(tags, base, dplan.elem_meta, table_p)
+        return out.reshape(group, window, D)
+
     out = run_slice_groups(
-        lambda base, tags: call(tags, base, dplan.elem_meta, table_p).reshape(
-            group, window, D
-        ),
-        dplan.tags, n_slices=n_windows, n_chunks=1, group=group,
+        one_call, dplan.tags, n_slices=n_windows, n_chunks=1, group=group,
     )
     return out.reshape(n_windows * window, D)[:n_out]

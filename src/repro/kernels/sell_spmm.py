@@ -55,6 +55,10 @@ from .sell_spmv import (
     slices_per_call,
 )
 
+#: Name of the kernel's instruction on a device trace (``sell_spmm.N``), as in
+#: `kernels.sell_spmv.KERNEL_NAME`.
+KERNEL_NAME = "sell_spmm"
+
 
 def _accumulate(ew, eo, t, vals, x_block, out_ref, *, block_rows: int,
                 cols_per_chunk: int, slice_height: int):
@@ -309,9 +313,15 @@ def sell_spmm_pallas(
         )
     call = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+        name=KERNEL_NAME,
     )
+
+    def one_call(base, tags):
+        with jax.named_scope(KERNEL_NAME):
+            return call(tags, base, dplan.elem_meta, vals, X_p)
+
     out = run_slice_groups(
-        lambda base, tags: call(tags, base, dplan.elem_meta, vals, X_p),
-        dplan.tags, n_slices=n_slices, n_chunks=n_chunks, group=group,
+        one_call, dplan.tags, n_slices=n_slices, n_chunks=n_chunks,
+        group=group,
     )  # (n_slices, n_ktiles, H, kt)
     return out.transpose(0, 2, 1, 3).reshape(n_slices * H, k_pad)[:, :k]

@@ -65,6 +65,10 @@ from repro.core.coalescer import (
     resolve_schedule,
 )
 
+#: Name of the kernel's instruction on a device trace (``sell_spmv.N``): the
+#: `pallas_call` name and the innermost `jax.named_scope` around its call.
+KERNEL_NAME = "sell_spmv"
+
 #: Default VMEM pipeline depth for both SELL kernels: double buffering.
 DEFAULT_BUFFER_DEPTH = 2
 
@@ -354,6 +358,15 @@ def slices_per_call(n_slices: int, n_chunks: int, max_warps: int) -> int:
     row_bytes = 4 * 128 * -(-max(int(max_warps), 1) // 128)
     fit = SMEM_TAG_BUDGET // (row_bytes * n_chunks)
     return max(1, min(int(n_slices), fit))
+
+
+def grid_steps(plan: DevicePlan) -> int:
+    """Kernel grid steps one `sell_spmv_pallas` product over `plan` runs:
+    every slice group's (group, n_chunks, max_warps) grid, the last group
+    counted whole although it overlaps its predecessor."""
+    group = slices_per_call(plan.n_slices, plan.n_chunks, plan.max_warps)
+    n_groups = -(-plan.n_slices // group)
+    return n_groups * group * plan.n_chunks * plan.max_warps
 
 
 def run_slice_groups(call, tags, *, n_slices: int, n_chunks: int,
@@ -659,9 +672,15 @@ def sell_spmv_pallas(
         )
     call = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+        name=KERNEL_NAME,
     )
+
+    def one_call(base, tags):
+        with jax.named_scope(KERNEL_NAME):
+            return call(tags, base, dplan.elem_meta, vals, x_p)
+
     out = run_slice_groups(
-        lambda base, tags: call(tags, base, dplan.elem_meta, vals, x_p),
-        dplan.tags, n_slices=n_slices, n_chunks=n_chunks, group=group,
+        one_call, dplan.tags, n_slices=n_slices, n_chunks=n_chunks,
+        group=group,
     )
     return out.reshape(-1)

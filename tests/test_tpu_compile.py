@@ -7,6 +7,7 @@ The topology is described inside a fixture, never at import time: only one
 process at a time may load the TPU compiler library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,45 +80,81 @@ def _assert_kernel(lowered):
 # HPCG 27-point 104^3 (1,124,864 rows) and webbase-1M (1,000,000 rows).
 HPCG = (35152, 4, 26)
 WEBBASE = (31250, 8, 120)
+# Few enough slices for one kernel call (no slice-group loop around it).
+ONE_GROUP = (64, 4, 26)
 
 
-@pytest.mark.parametrize("geometry", [HPCG, WEBBASE], ids=["hpcg", "webbase"])
-def test_sell_spmv_compiles_for_v5e(one_chip, geometry):
+def _lower_spmv(sharding, geometry):
     n_slices, n_chunks, max_warps = geometry
-    plan = _plan(one_chip, *geometry)
+    plan = _plan(sharding, *geometry)
     fn = jax.jit(lambda v, x, p: sell_spmv_pallas(
         None, v, x, cols_per_chunk=CPC, plan=p
     ))
-    _assert_kernel(fn.lower(
-        _shape(one_chip, (n_slices, n_chunks * CPC, H), jnp.float32),
-        _shape(one_chip, (n_slices * H,), jnp.float32),
+    return fn.lower(
+        _shape(sharding, (n_slices, n_chunks * CPC, H), jnp.float32),
+        _shape(sharding, (n_slices * H,), jnp.float32),
         plan,
-    ))
+    )
 
 
-def test_sell_spmm_compiles_for_v5e_at_k8(one_chip):
+def _lower_spmm(sharding):
     n_slices, n_chunks, max_warps = WEBBASE
-    plan = _plan(one_chip, *WEBBASE)
+    plan = _plan(sharding, *WEBBASE)
     fn = jax.jit(lambda v, x, p: sell_spmm_pallas(
         None, v, x, cols_per_chunk=CPC, k_tile=8, plan=p
     ))
-    _assert_kernel(fn.lower(
-        _shape(one_chip, (n_slices, n_chunks * CPC, H), jnp.float32),
-        _shape(one_chip, (n_slices * H, 8), jnp.float32),
+    return fn.lower(
+        _shape(sharding, (n_slices, n_chunks * CPC, H), jnp.float32),
+        _shape(sharding, (n_slices * H, 8), jnp.float32),
         plan,
-    ))
+    )
 
 
-def test_coalesced_gather_compiles_for_v5e_paged_kv(one_chip):
+def _lower_gather(sharding):
     """Paged-KV gather at TinyLlama-1.1B widths: 4 KV heads x 64 dims,
     16-token pages (one page per block row), bf16, 8 sequences x 2048
     tokens -> 1024 pages, one page-table window of 256 per 4096 tokens."""
     n_pages, page_width, n_windows = 1024, 16 * 4 * 64, 4
-    plan = _plan(one_chip, n_windows, 1, 256, cols_per_chunk=1,
+    plan = _plan(sharding, n_windows, 1, 256, cols_per_chunk=1,
                  slice_height=256, block_rows=1)
     fn = jax.jit(lambda t, p: coalesced_gather_pallas(
         t, None, window=256, block_rows=1, plan=p
     ))
-    _assert_kernel(fn.lower(
-        _shape(one_chip, (n_pages, page_width), jnp.bfloat16), plan,
-    ))
+    return fn.lower(
+        _shape(sharding, (n_pages, page_width), jnp.bfloat16), plan,
+    )
+
+
+@pytest.mark.parametrize("geometry", [HPCG, WEBBASE], ids=["hpcg", "webbase"])
+def test_sell_spmv_compiles_for_v5e(one_chip, geometry):
+    _assert_kernel(_lower_spmv(one_chip, geometry))
+
+
+def test_sell_spmm_compiles_for_v5e_at_k8(one_chip):
+    _assert_kernel(_lower_spmm(one_chip))
+
+
+def test_coalesced_gather_compiles_for_v5e_paged_kv(one_chip):
+    _assert_kernel(_lower_gather(one_chip))
+
+
+KERNELS = {
+    "sell_spmv_hpcg": ("sell_spmv", lambda sh: _lower_spmv(sh, HPCG)),
+    "sell_spmv_one_group": ("sell_spmv",
+                            lambda sh: _lower_spmv(sh, ONE_GROUP)),
+    "sell_spmm": ("sell_spmm", _lower_spmm),
+    "coalesced_gather": ("coalesced_gather", _lower_gather),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNELS))
+def test_kernel_is_named_after_its_scope(one_chip, case):
+    """A device trace names an operation after its HLO instruction, and the
+    kernel's innermost `named_scope` names that instruction: ``sell_spmv.N``
+    whether or not a slice-group loop surrounds the call."""
+    scope, lower = KERNELS[case]
+    text = lower(one_chip).compile().as_text()
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert names, "no kernel in the compiled program"
+    assert all(re.fullmatch(rf"{scope}\.\d+", n) for n in names), names
