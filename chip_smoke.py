@@ -2,7 +2,7 @@
 """Bring-up smoke test of the SpMV engine and CG on one TPU chip.
 
     python chip_smoke.py               # one chip: HPCG CG, webbase-1M SpMV/SpMM
-    python chip_smoke.py --four-chips  # four chips: sharded webbase-1M SpMM
+    python chip_smoke.py --four-chips  # four chips: sharded webbase-1M SpMM, SpMV
 
 Runs in one process through the library's own entry points, at the full size
 of two deployments built from the repository's generators:
@@ -10,11 +10,13 @@ of two deployments built from the repository's generators:
 * HPCG: unpreconditioned CG (`core.solvers.cg`, `lax.while_loop`) on the
   27-point stencil over the HPCG reference's 104^3 local domain, b = A @ 1.
   The true residual is recomputed in f64 on the host with scipy.
-* webbase-1M: `SpMVEngine.matvec` and the fused `matmat` at k = 8 on the
-  1M-row power-law matrix, each compared with scipy's f64 product.
-* ``--four-chips``: `ShardedSpMVEngine` matmat at k = 8 on webbase-1M over a
-  (data=4, model=1) mesh with the cost partition; each shard's result must
-  sit on its own chip and no shard may fall back to the reference executor.
+* webbase-1M: `SpMVEngine.matvec`, the fused `matmat` and `matmat_vmapped`
+  at k = 8 on the 1M-row power-law matrix, each compared with scipy's f64
+  product.
+* ``--four-chips``: `ShardedSpMVEngine` matmat at k = 8 and matvec on
+  webbase-1M over a (data=4, model=1) mesh with the cost partition; each
+  shard's result must sit on its own chip and no shard may fall back to the
+  reference executor.
 
 It fails (nonzero exit, no result line) unless JAX finds a TPU, the "auto"
 backend resolves to compiled Pallas kernels, and every check passes. The
@@ -82,20 +84,27 @@ def _matrix(name: str):
     return csr, time.perf_counter() - t0
 
 
-def _geometry(eng) -> str:
-    from repro.kernels.sell_spmv import slices_per_call
+def _lower(eng) -> dict:
+    """Plan `eng` for its matvec; the counts of its `planner.lower` span
+    (`grid_steps`, `x_resident`)."""
+    from repro.core import spans
 
+    with spans.recording() as record:
+        eng.device_matvec()
+    return next((s.counts for s in record if s.name == "planner.lower"), {})
+
+
+def _geometry(eng, counts: dict) -> str:
     sched = eng.schedule
     n_slices = eng.sell.n_slices
     n_chunks = sched.n_windows // n_slices
-    per_call = slices_per_call(n_slices, n_chunks, sched.max_warps)
     return (
         f"rows={eng.n_rows} nnz={int(np.count_nonzero(eng.sell.values))} "
         f"slices={n_slices} plan_width={n_chunks * eng.cols_per_chunk} "
         f"window={eng.window} windows={sched.n_windows} "
         f"max_warps={sched.max_warps} "
-        f"grid_steps_per_spmv={n_slices * n_chunks * sched.max_warps} "
-        f"slices_per_call={per_call}"
+        f"x_resident={counts.get('x_resident')} "
+        f"grid_steps_per_spmv={counts.get('grid_steps')}"
     )
 
 
@@ -125,9 +134,10 @@ def hpcg_phase(grid=(104, 104, 104), *, backend: str = "auto",
     b = (A @ np.ones(csr.n_cols)).astype(np.float32)
     builds0 = schedule_cache_stats()["built"]
     eng, t_plan, _ = _timed(lambda: get_engine(csr, backend=backend))
-    _, dt, _ = _timed(eng.device_matvec)  # schedule + device plan
+    counts, dt, _ = _timed(lambda: _lower(eng))  # schedule + device plan
     t_plan += dt
-    print(f"  backend={eng.backend_resolved} {_geometry(eng)}", flush=True)
+    print(f"  backend={eng.backend_resolved} {_geometry(eng, counts)}",
+          flush=True)
     res, t_solve, t_compile = _timed(
         lambda: cg(eng, b, tol=tol, maxiter=maxiter)
     )
@@ -168,20 +178,25 @@ def webbase_phase(csr=None, *, backend: str = "auto", k: int = K) -> bool:
     x = rng.standard_normal(csr.n_cols).astype(np.float32)
     X = rng.standard_normal((csr.n_cols, k)).astype(np.float32)
     eng, t_plan, _ = _timed(lambda: get_engine(csr, backend=backend))
-    _, dt, _ = _timed(eng.device_matvec)
+    counts, dt, _ = _timed(lambda: _lower(eng))
     t_plan += dt
     print(f"  backend={eng.backend_resolved} "
-          f"matmat={eng.matmat_mode_resolved} {_geometry(eng)}", flush=True)
+          f"matmat={eng.matmat_mode_resolved} {_geometry(eng, counts)}",
+          flush=True)
     y, t_mv, c_mv = _timed(lambda: np.asarray(eng.matvec(x)))
     ok = _parity("matvec", y, A @ x.astype(np.float64))
+    Y_ref = A @ X.astype(np.float64)
     Y, t_mm, c_mm = _timed(lambda: np.asarray(eng.matmat(X)))
-    ok &= _parity(f"matmat k={k}", Y, A @ X.astype(np.float64))
+    ok &= _parity(f"matmat k={k}", Y, Y_ref)
+    Y, t_mv8, c_mv8 = _timed(lambda: np.asarray(eng.matmat_vmapped(X)))
+    ok &= _parity(f"matmat_vmapped k={k}", Y, Y_ref)
     ok &= eng.matmat_mode_resolved == ("fused" if eng.backend_resolved ==
                                         "pallas" else "vmapped")
     print(
         f"  seconds: matrix={t_gen:.3f} plan={t_plan:.3f} "
-        f"compile={c_mv + c_mm:.3f} run_matvec={t_mv - c_mv:.3f} "
-        f"run_matmat={t_mm - c_mm:.3f} ok={ok}", flush=True,
+        f"compile={c_mv + c_mm + c_mv8:.3f} run_matvec={t_mv - c_mv:.3f} "
+        f"run_matmat={t_mm - c_mm:.3f} "
+        f"run_matmat_vmapped={t_mv8 - c_mv8:.3f} ok={ok}", flush=True,
     )
     return bool(ok)
 
@@ -195,23 +210,24 @@ def four_chip_phase(csr=None, *, backend: str = "auto", k: int = K) -> bool:
     t_gen = 0.0
     if csr is None:
         csr, t_gen = _matrix("webbase-1M")
-    print(f"[four-chips] sharded matmat k={k} on powerlaw n={csr.n_rows} "
-          f"nnz={csr.nnz}, partition=cost", flush=True)
+    print(f"[four-chips] sharded matvec and matmat k={k} on powerlaw "
+          f"n={csr.n_rows} nnz={csr.nnz}, partition=cost", flush=True)
     A = _scipy(csr)
-    X = np.random.default_rng(SEED + 2).standard_normal(
-        (csr.n_cols, k)
-    ).astype(np.float32)
+    rng = np.random.default_rng(SEED + 2)
+    X = rng.standard_normal((csr.n_cols, k)).astype(np.float32)
+    x = rng.standard_normal(csr.n_cols).astype(np.float32)
     mesh = make_host_mesh(model_axis=1)  # (data=4, model=1)
 
     def plan():
         eng = ShardedSpMVEngine(
             csr, mesh=mesh, partition="cost", backend=backend
         )
-        for shard in eng.engines:
-            shard.device_matvec()
-        return eng
+        return eng, [_lower(shard) for shard in eng.engines]
 
-    eng, t_plan, _ = _timed(plan)
+    (eng, counts), t_plan, _ = _timed(plan)
+    print(f"  shards x_resident="
+          f"{[c.get('x_resident') for c in counts]} grid_steps_per_spmv="
+          f"{[c.get('grid_steps') for c in counts]}", flush=True)
     def run():
         pending = eng.dispatch(eng.stage(X))
         jax.block_until_ready(pending.blocks)
@@ -235,9 +251,11 @@ def four_chip_phase(csr=None, *, backend: str = "auto", k: int = K) -> bool:
     print(f"  recovery={recovery}", flush=True)
     ok &= recovery["recovered"] == 0 and not recovery["events"]
     ok &= _parity(f"sharded matmat k={k}", Y, A @ X.astype(np.float64))
+    y, t_mv, c_mv = _timed(lambda: eng.matvec(x))
+    ok &= _parity("sharded matvec", y, A @ x.astype(np.float64))
     print(f"  seconds: matrix={t_gen:.3f} plan={t_plan:.3f} "
-          f"compile={t_compile:.3f} run={t_run - t_compile:.3f} ok={ok}",
-          flush=True)
+          f"compile={t_compile + c_mv:.3f} run={t_run - t_compile:.3f} "
+          f"run_matvec={t_mv - c_mv:.3f} ok={ok}", flush=True)
     return bool(ok)
 
 
@@ -245,7 +263,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument(
         "--four-chips", action="store_true",
-        help="run only the sharded webbase-1M matmat over four chips",
+        help="run only the sharded webbase-1M matmat and matvec over four "
+             "chips",
     )
     args = ap.parse_args(argv)
     if os.environ.get("REPRO_BACKEND"):

@@ -819,7 +819,8 @@ class SpMVEngine:
                     from repro.kernels.ops import resolve_interpret
                     from repro.kernels.sell_spmm import sell_spmm_pallas
                     from repro.kernels.sell_spmv import build_device_plan, \
-                        chunk_values, grid_steps, sell_spmv_pallas
+                        chunk_row_plan, device_operands, grid_steps, \
+                        sell_spmv_pallas
 
                     # Lower the schedule to the kernel-ready device plan
                     # exactly once; the matvec and the fused matmat kernels
@@ -832,14 +833,16 @@ class SpMVEngine:
                         sched, n_slices=n_slices, cols_per_chunk=cpc,
                         slice_height=H, packed=self.packed,
                     )
-                    self._device_plan = plan
-                    # Values in the kernels' per-chunk row layout, so the
-                    # reshape below and the kernel's own cancel and no call
-                    # relayouts.
-                    operands = jax.block_until_ready((
-                        chunk_values(jnp.asarray(va_plan, vdt), cpc), plan
+                    # The stream in the layout its matvec path reads
+                    # (lane-dense where x stays in VMEM, else chunk rows),
+                    # so the reshape below and the kernel's own cancel and
+                    # no call relayouts.
+                    operands = jax.block_until_ready(device_operands(
+                        plan, jnp.asarray(va_plan, vdt), sell.n_cols
                     ))
+                    plan = self._device_plan = operands[1]
                     counts["grid_steps"] = grid_steps(plan)
+                    counts["x_resident"] = int(plan.lane_dense)
                 interpret = resolve_interpret()
 
                 def _values(va, dtype):
@@ -864,6 +867,8 @@ class SpMVEngine:
                 if self.matmat_mode_resolved == "fused":
 
                     def engine_matmat(ops, X: jnp.ndarray) -> jnp.ndarray:
+                        # sell_spmm streams chunk rows: on a lane-dense plan
+                        # each call relays the stream out into them.
                         va, plan = ops
                         Y = sell_spmm_pallas(
                             None,
@@ -872,7 +877,7 @@ class SpMVEngine:
                             cols_per_chunk=cpc,
                             block_rows=block_rows,
                             k_tile=kt,
-                            plan=plan,
+                            plan=chunk_row_plan(plan),
                             buffer_depth=depth,
                             interpret=interpret,
                         )

@@ -1,10 +1,20 @@
 """SELL SpMV Pallas kernel, fused with the coalesced indirect x-access.
 
-Mirrors the paper's VPC pipeline (Sec. II-C) in a single kernel: the grid's
-inner `t` dimension performs the adapter's coalesced wide fetches of the dense
-vector x (one VMEM block per unique wide block per window), and the (s, c)
-dimensions perform the VPC's VMAC consumption of SELL slices — compute and the
-indirect stream overlap exactly as prefetching overlaps compute in the paper.
+Mirrors the paper's VPC pipeline (Sec. II-C) in a single kernel: each warp
+of a window is one coalesced wide fetch of the dense vector x (one block of
+`block_rows` entries per unique wide block per window), and the slices'
+chunks are the VPC's VMAC consumption of SELL slices.
+
+Two paths run it, picked by the layout the plan holds, which
+`device_operands` chooses by `x_resident`. Where x fits VMEM the plan is
+lane-dense: x is copied there once per product and one grid step covers a
+tile of `TILE_SLICES` whole slices:
+the chunks and warps of the tile are loops in the kernel body, and a warp's
+wide fetch is a row load from VMEM plus an in-register lane gather. Every
+other plan runs the per-warp grid (s, c, t), one step per (slice, chunk,
+warp), with the warp's x block fetched by a data-dependent BlockSpec —
+compute and the indirect stream overlap as prefetching overlaps compute in
+the paper.
 
 Layout: padded SELL (n_slices, W, H) with H = slice height (32), W padded to a
 multiple of `cols_per_chunk`. One *window* of the indirect stream = one
@@ -38,11 +48,12 @@ Two bandwidth levers live here (the ROADMAP "bandwidth roofline push"):
   `SpMVEngine.plan_report()["metadata"]`.
 
 * **Double-buffered chunk pipelining.** With ``buffer_depth >= 2`` the
-  kernels stream SELL values + metadata through a rotating VMEM scratch
-  with explicit async copies: while chunk g computes out of slot
-  ``g % depth``, the DMA for chunk ``g + depth - 1`` fills the next slot —
-  the in-kernel analog of the host-side `StreamingExecutor` pipeline
-  (and of the paper's prefetch-overlaps-compute VPC timing).
+  per-warp grids (the matvec's where x is not resident, and
+  `kernels.sell_spmm`'s) stream SELL values + metadata through a rotating
+  VMEM scratch with explicit async copies: while chunk g computes out of
+  slot ``g % depth``, the DMA for chunk ``g + depth - 1`` fills the next
+  slot — the in-kernel analog of the host-side `StreamingExecutor`
+  pipeline (and of the paper's prefetch-overlaps-compute VPC timing).
   ``buffer_depth=1`` keeps the classic BlockSpec-pipelined path.
 """
 from __future__ import annotations
@@ -95,6 +106,10 @@ class DevicePlan:
                                elem_warp, row 1 elem_offset (8 bytes/element,
                                the lossless fallback for geometries whose
                                warp ids or offsets overflow 16 bits).
+               The x-resident matvec streams the same words lane-dense,
+               as (n_windows * rows * window / 128, 128) (`lane_dense_plan`);
+               `chunk_row_plan` turns them back. The layout a plan holds
+               picks the matvec's path (`lane_dense`).
 
     `elem_warp` / `elem_offset` remain available as decoding properties, so
     schedule-level invariants can be asserted against either encoding.
@@ -119,18 +134,31 @@ class DevicePlan:
         return int(self.tags.shape[1])
 
     @property
+    def lane_dense(self) -> bool:
+        """Whether `elem_meta` is the x-resident path's lane-dense stream
+        (`lane_dense_plan`) rather than chunk rows."""
+        return self.elem_meta.ndim == 2
+
+    @property
+    def _chunk_meta(self) -> jnp.ndarray:
+        """`elem_meta` as chunk rows, whichever layout the plan holds."""
+        return self.elem_meta.reshape(
+            self.n_slices, self.n_chunks, _meta_rows(self.packed), self.window
+        )
+
+    @property
     def elem_warp(self) -> jnp.ndarray:
         """(n_slices, n_chunks, window) int32 warp ids, whatever the encoding."""
         if self.packed:
-            return jax.lax.shift_right_logical(self.elem_meta[:, :, 0, :], 16)
-        return self.elem_meta[:, :, 0, :]
+            return jax.lax.shift_right_logical(self._chunk_meta[:, :, 0, :], 16)
+        return self._chunk_meta[:, :, 0, :]
 
     @property
     def elem_offset(self) -> jnp.ndarray:
         """(n_slices, n_chunks, window) int32 offsets, whatever the encoding."""
         if self.packed:
-            return jnp.bitwise_and(self.elem_meta[:, :, 0, :], 0xFFFF)
-        return self.elem_meta[:, :, 1, :]
+            return jnp.bitwise_and(self._chunk_meta[:, :, 0, :], 0xFFFF)
+        return self._chunk_meta[:, :, 1, :]
 
     @property
     def meta_bytes_per_element(self) -> int:
@@ -362,8 +390,11 @@ def slices_per_call(n_slices: int, n_chunks: int, max_warps: int) -> int:
 
 def grid_steps(plan: DevicePlan) -> int:
     """Kernel grid steps one `sell_spmv_pallas` product over `plan` runs:
+    one per tile of slices on the x-resident path (a lane-dense plan), else
     every slice group's (group, n_chunks, max_warps) grid, the last group
     counted whole although it overlaps its predecessor."""
+    if plan.lane_dense:
+        return -(-plan.n_slices // _tile(plan.n_slices))
     group = slices_per_call(plan.n_slices, plan.n_chunks, plan.max_warps)
     n_groups = -(-plan.n_slices // group)
     return n_groups * group * plan.n_chunks * plan.max_warps
@@ -559,6 +590,271 @@ def chunk_values(values: jnp.ndarray, cols_per_chunk: int) -> jnp.ndarray:
     return values.reshape(n_slices, W // cols_per_chunk, 1, cols_per_chunk * H)
 
 
+# -- the x-resident matvec ---------------------------------------------------
+#
+# One grid step per tile of `TILE_SLICES` whole slices. x sits in VMEM for the
+# whole product, viewed as (ceil(n_cols / 128), 128): a coalesced block
+# ``tag`` of `block_rows` floats is x row ``(tag * block_rows) // 128`` from
+# lane ``(tag * block_rows) % 128`` on. Inside a step a loop runs over the
+# tile's windows, and the warps of a window are unrolled: each warp's x row
+# is loaded from VMEM and its elements picked out by an in-register lane
+# gather, several warps to a vector register.
+# The stream is lane-dense: values and metadata as (rows, 128) arrays holding
+# each window's ``window // 128`` lane rows in order (`stream_values`,
+# `lane_dense_plan`).
+
+#: Lanes of a TPU vector register: the width of x rows and stream rows.
+LANES = 128
+
+#: Bytes of padded x the resident path may hold in VMEM: half of a TPU v5e
+#: core's 128 MiB, so about 16 M float32 columns.
+X_RESIDENT_BUDGET = 64 * 1024 * 1024
+
+#: Slices per grid step of the resident path.
+TILE_SLICES = 8
+
+#: VMEM the resident kernel may use beyond x and its pipelined blocks.
+_VMEM_HEADROOM = 8 * 1024 * 1024
+
+
+def stream_values(values: jnp.ndarray) -> jnp.ndarray:
+    """SELL values, (n_slices, W, H), as the resident path's lane-dense
+    (n_slices * W * H / 128, 128) stream: window after window, each window's
+    elements in order."""
+    return values.reshape(-1, LANES)
+
+
+def lane_dense_plan(plan: DevicePlan) -> DevicePlan:
+    """`plan` with its metadata as the resident path's lane-dense
+    (n_windows * rows * window / 128, 128) stream (rows: `_meta_rows`)."""
+    return dataclasses.replace(plan, elem_meta=plan.elem_meta.reshape(-1, LANES))
+
+
+def chunk_row_plan(plan: DevicePlan) -> DevicePlan:
+    """`plan` with its metadata as the (n_slices, n_chunks, rows, window)
+    chunk rows the per-warp grids stream; the inverse of `lane_dense_plan`."""
+    return dataclasses.replace(plan, elem_meta=plan.elem_meta.reshape(
+        plan.n_slices, plan.n_chunks, _meta_rows(plan.packed), plan.window))
+
+
+def _x_rows(n_cols: int) -> int:
+    return -(-int(n_cols) // LANES)
+
+
+def _tile(n_slices: int) -> int:
+    return min(TILE_SLICES, int(n_slices))
+
+
+def _tag_block_bytes(plan: DevicePlan) -> int:
+    """SMEM of one step's tag rows, each padded to 128 lanes, double
+    buffered."""
+    row_bytes = 4 * LANES * -(-plan.max_warps // LANES)
+    return 2 * _tile(plan.n_slices) * plan.n_chunks * row_bytes
+
+
+def x_resident(plan: DevicePlan, n_cols: int, value_dtype) -> bool:
+    """Whether a product over `plan` with an x of `n_cols` entries and
+    values of `value_dtype` runs the x-resident path: x padded to whole
+    128-lane rows fits `X_RESIDENT_BUDGET`, values are 32-bit (a window's
+    lane rows then load from any row), a window is whole lane rows, a
+    slice's outputs and a coalesced block both divide a lane row, and a
+    step's tag rows fit `SMEM_TAG_BUDGET`. Any other plan runs the per-warp
+    grid."""
+    return (
+        _x_rows(n_cols) * LANES * 4 <= X_RESIDENT_BUDGET
+        and jnp.dtype(value_dtype).itemsize == 4
+        and plan.window % LANES == 0
+        and LANES % plan.slice_height == 0
+        and LANES % plan.block_rows == 0
+        and _tag_block_bytes(plan) <= SMEM_TAG_BUDGET
+    )
+
+
+def device_operands(plan: DevicePlan, values: jnp.ndarray, n_cols: int):
+    """``(values, plan)`` as a plan-owning caller holds them for repeated
+    products with an x of `n_cols` entries: lane-dense where `x_resident`
+    holds, else the per-warp grid's chunk rows. `sell_spmv_pallas` takes
+    its path from the plan's layout, so the stream is never relaid out by a
+    call. `values` is (n_slices, W, H)."""
+    if x_resident(plan, n_cols, values.dtype):
+        return stream_values(values), lane_dense_plan(plan)
+    return chunk_values(values, plan.cols_per_chunk), chunk_row_plan(plan)
+
+
+def _fold_lane_rows(prod, cols_per_chunk: int, slice_height: int):
+    """`_sum_chunk_columns` on a window held as (window / 128, 128) lane
+    rows: window element ``j * H + h`` folds into lane h, j in order."""
+    acc = None
+    for j in range(cols_per_chunk):
+        r, lane = divmod(j * slice_height, LANES)
+        part = prod[r:r + 1, lane:lane + slice_height]
+        acc = part if acc is None else acc + part
+    return acc
+
+
+def _resident_kernel(
+    tags_ref,  # SMEM (tile * n_chunks, max_warps): this step's tag rows
+    meta_ref,  # (tile * n_chunks * rows * window / 128, 128) int32
+    values_ref,  # (tile * n_chunks * window / 128, 128)
+    x_hbm,  # (ceil(n_cols / 128), 128), ANY memory space
+    out_ref,  # (tile, H)
+    x_vmem,  # VMEM scratch, x's shape: x for the whole product
+    sem,  # DMA semaphore of x's copy
+    *,
+    n_slices: int,
+    tile: int,
+    n_chunks: int,
+    max_warps: int,
+    block_rows: int,
+    cols_per_chunk: int,
+    slice_height: int,
+    packed: bool,
+):
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _load_x():
+        # The grid runs in order ("arbitrary"), so x stays for every step.
+        copy = pltpu.make_async_copy(x_hbm, x_vmem, sem)
+        copy.start()
+        copy.wait()
+
+    rows = (cols_per_chunk * slice_height) // LANES  # lane rows per window
+    meta_rows = _meta_rows(packed) * rows
+    cdt = x_vmem.dtype
+    out_dtype = out_ref.dtype
+    # block_rows divides 128, so both are powers of two: shifts and masks.
+    per_row = LANES // block_rows
+    row_shift = per_row.bit_length() - 1
+    lane_shift = block_rows.bit_length() - 1
+    # A window's rows fill `rows` of a vector register's 8 sublanes, so one
+    # register serves `n_warps` warps at once: copy k of the window serves
+    # warp t0 + k, and one lane gather picks all of them.
+    n_warps = max(1, 8 // rows)
+    copy_of = jax.lax.broadcasted_iota(
+        jnp.int32, (n_warps * rows, LANES), 0) // rows
+
+    def gather_x(w, ew, eo):
+        """(rows, 128) x values of window `w`'s elements: element i takes
+        lane ``eo[i]`` of its warp's coalesced block."""
+        eo_n = jnp.concatenate([eo] * n_warps, axis=0)
+        ew_n = jnp.concatenate([ew] * n_warps, axis=0) - copy_of
+        g = jnp.zeros(copy_of.shape, cdt)
+        for t0 in range(0, max_warps, n_warps):
+            for k in range(n_warps):
+                # Past max_warps no element matches; any tag row will do.
+                tag = tags_ref[w, min(t0 + k, max_warps - 1)]
+                row = x_vmem[pl.ds(
+                    jax.lax.shift_right_logical(tag, row_shift), 1), :]
+                if k == 0:
+                    x_n = jnp.broadcast_to(row, copy_of.shape)
+                    tag_n = jnp.full(copy_of.shape, tag)
+                else:
+                    x_n = jnp.where(copy_of == k, row, x_n)
+                    tag_n = jnp.where(copy_of == k, tag, tag_n)
+            # The lane bases on the vector side: the scalar unit is the
+            # busiest in this loop.
+            base_n = jax.lax.shift_left(
+                jnp.bitwise_and(tag_n, per_row - 1), lane_shift)
+            picked = jnp.take_along_axis(x_n, eo_n + base_n, axis=1,
+                                         mode="promise_in_bounds")
+            g = jnp.where(ew_n == t0, picked, g)
+        # Each element matched in exactly one copy; the others hold zeros.
+        out = g[:rows]
+        for k in range(1, n_warps):
+            out = out + g[k * rows:(k + 1) * rows]
+        return out
+
+    def window(w, acc):
+        vals = values_ref[pl.ds(w * rows, rows), :]
+        meta = meta_ref[pl.ds(w * meta_rows, meta_rows), :]
+        if packed:
+            ew = jax.lax.shift_right_logical(meta, 16)
+            eo = jnp.bitwise_and(meta, 0xFFFF)
+        else:
+            ew, eo = meta[:rows], meta[rows:]
+        return acc + vals.astype(cdt) * gather_x(w, ew, eo)
+
+    def one_slice(s, carry):
+        # The slice's chunks are summed lane by lane, then folded once.
+        acc = jax.lax.fori_loop(
+            s * n_chunks, (s + 1) * n_chunks, window,
+            jnp.zeros((rows, LANES), cdt),
+        )
+        out_ref[pl.ds(s, 1), :] = _fold_lane_rows(
+            acc, cols_per_chunk, slice_height).astype(out_dtype)
+        return carry
+
+    # The last tile may hold fewer slices; its other output rows are clipped.
+    jax.lax.fori_loop(0, jnp.minimum(tile, n_slices - i * tile), one_slice, 0)
+
+
+def _sell_spmv_resident(dplan: DevicePlan, values, x, *, interpret: bool):
+    """y = A @ x by the x-resident path (see `x_resident`): one kernel call,
+    `grid_steps` = ceil(n_slices / `TILE_SLICES`)."""
+    n_slices, n_chunks = dplan.n_slices, dplan.n_chunks
+    H = dplan.slice_height
+    rows = dplan.window // LANES
+    meta_rows = _meta_rows(dplan.packed) * rows
+    tile = _tile(n_slices)
+    out_dtype = jnp.promote_types(values.dtype, x.dtype)
+    cdt = jnp.promote_types(out_dtype, jnp.float32)
+    n_x = _x_rows(x.shape[0])
+    x_p = jnp.pad(x.astype(cdt), (0, n_x * LANES - x.shape[0])).reshape(
+        n_x, LANES)
+    vals = stream_values(values)
+    if vals.dtype.itemsize != 4:
+        # A lane-dense plan's values come narrower only when the caller
+        # casts them per call (to an x of another dtype); the kernel loads
+        # one lane row at a time, which takes 32-bit rows.
+        vals = vals.astype(cdt)
+    meta = dplan.elem_meta
+    tw = tile * n_chunks  # windows per step
+    block_bytes = 2 * (
+        tw * rows * LANES * vals.dtype.itemsize + tw * meta_rows * LANES * 4
+        + tile * LANES * 4
+    )
+    x_bytes = n_x * LANES * jnp.dtype(cdt).itemsize
+    kernel = functools.partial(
+        _resident_kernel, n_slices=n_slices, tile=tile, n_chunks=n_chunks,
+        max_warps=dplan.max_warps, block_rows=dplan.block_rows,
+        cols_per_chunk=dplan.cols_per_chunk, slice_height=H,
+        packed=dplan.packed,
+    )
+    call = pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(n_slices, tile),),
+        in_specs=[
+            pl.BlockSpec((tw, dplan.max_warps), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((tw * meta_rows, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((tw * rows, LANES), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((tile, H), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_slices, H), out_dtype),
+        scratch_shapes=[
+            pltpu.VMEM((n_x, LANES), cdt),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=x_bytes + block_bytes + _VMEM_HEADROOM,
+        ),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )
+    # The kernel copies x in from HBM itself, which a batched call cannot
+    # (memory space ANY): under vmap (`SpMVEngine.matmat_vmapped`) each
+    # right-hand side runs as a product of its own, in a loop.
+    @jax.custom_batching.sequential_vmap
+    def product(tags, meta, vals, x_p):
+        with jax.named_scope(KERNEL_NAME):
+            return call(tags, meta, vals, x_p)
+
+    return product(dplan.tags, meta, vals, x_p).reshape(-1)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -590,10 +886,14 @@ def sell_spmv_pallas(
 
     `packed` picks the metadata encoding when the plan is built here
     (None == "auto": one int32 word per element whenever lossless);
-    `buffer_depth >= 2` streams values + metadata through a rotating VMEM
-    scratch with async copies (see `_kernel_buffered`), `buffer_depth=1`
-    keeps the classic BlockSpec pipeline. Plans whose tags exceed
-    `SMEM_TAG_BUDGET` run as one kernel call per group of slices."""
+    The plan's layout picks the path. A lane-dense plan
+    (`device_operands`, or a plan built here where `x_resident` holds) runs
+    one call of the x-resident kernel (`_sell_spmv_resident`). A plan of
+    chunk rows runs the per-warp grid: `buffer_depth >= 2` streams values
+    + metadata through a rotating VMEM scratch with async copies (see
+    `_kernel_buffered`), `buffer_depth=1` keeps the classic BlockSpec
+    pipeline, and plans whose tags exceed `SMEM_TAG_BUDGET` run as one
+    kernel call per group of slices."""
     n_slices, W, H = values.shape
     if W % cols_per_chunk != 0:
         raise ValueError(
@@ -611,6 +911,10 @@ def sell_spmv_pallas(
         cols_per_chunk=cols_per_chunk, block_rows=block_rows,
         max_warps=max_warps, schedule=schedule, plan=plan, packed=packed,
     )
+    if plan is None and x_resident(dplan, x.shape[0], values.dtype):
+        dplan = lane_dense_plan(dplan)
+    if dplan.lane_dense:
+        return _sell_spmv_resident(dplan, values, x, interpret=interpret)
     vals = chunk_values(values, cols_per_chunk)
 
     R = x.shape[0]

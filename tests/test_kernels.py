@@ -210,3 +210,106 @@ def test_kernels_split_into_slice_groups(monkeypatch, kernel):
     assert sell_spmv.slices_per_call(n_slices, W // cpc, 1) == 3
     np.testing.assert_allclose(np.asarray(y), np.asarray(ye), rtol=1e-5,
                                atol=1e-5)
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every `pallas_call` in `jaxpr`, inner jaxprs included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                grids += _pallas_grids(inner)
+    return grids
+
+
+def _padded_sell(csr, H, cpc):
+    """(colidx, values), each (n_slices, W, H), of `csr` with every slice
+    padded to the widest, rounded up to whole chunks."""
+    from repro.core.formats import csr_to_sell
+
+    sell = csr_to_sell(csr, H)
+    W = -(-int(sell.slice_widths.max()) // cpc) * cpc
+    ci = np.zeros((sell.n_slices, W, H), np.int32)
+    va = np.zeros((sell.n_slices, W, H), np.float32)
+    for s in range(sell.n_slices):
+        w, lo, hi = (int(sell.slice_widths[s]), int(sell.slice_ptrs[s]),
+                     int(sell.slice_ptrs[s + 1]))
+        ci[s, :w] = sell.colidx[lo:hi].reshape(w, H)
+        va[s, :w] = sell.values[lo:hi].reshape(w, H)
+    return jnp.asarray(ci), jnp.asarray(va), csr.n_cols
+
+
+def _random_sell(n_slices, W, H, n_cols):
+    rng = np.random.default_rng(n_slices * 1000 + n_cols)
+    ci = rng.integers(0, n_cols, size=(n_slices, W, H)).astype(np.int32)
+    va = (rng.standard_normal((n_slices, W, H))
+          * (rng.random((n_slices, W, H)) < 0.7)).astype(np.float32)
+    return jnp.asarray(ci), jnp.asarray(va), n_cols
+
+
+def _stencil_sell(H, cpc):
+    from repro.core.matrices import hpcg_stencil
+
+    return _padded_sell(hpcg_stencil(5, 5, 5)(), H, cpc)
+
+
+def _skewed_sell(H, cpc):
+    from repro.core.matrices import powerlaw
+
+    return _padded_sell(powerlaw(400, 12, alpha=1.1)(), H, cpc)
+
+
+# (matrix, slice height, cols_per_chunk, block_rows, packed)
+RESIDENT_CASES = {
+    "block_rows4": (lambda H, c: _random_sell(8, 16, H, 1024), 32, 8, 4,
+                    "auto"),
+    "block_rows8_unpacked": (lambda H, c: _random_sell(16, 8, H, 512), 32, 8,
+                             8, False),
+    "block_rows16": (lambda H, c: _random_sell(8, 24, H, 2048), 32, 8, 16,
+                     "auto"),
+    "ragged_slices_and_cols": (lambda H, c: _random_sell(13, 16, H, 333), 32,
+                               8, 8, "auto"),
+    "stencil27": (_stencil_sell, 32, 8, 8, "auto"),
+    "skewed": (_skewed_sell, 32, 4, 8, "auto"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RESIDENT_CASES))
+def test_sell_spmv_resident_parity(case, monkeypatch):
+    """The x-resident path (one grid step per tile of slices) against the
+    oracle and against the per-warp grid on the same plan, whose x is put
+    over the budget."""
+    from repro.kernels import sell_spmv
+
+    build, H, cpc, block_rows, packed = RESIDENT_CASES[case]
+    colidx, values, n_cols = build(H, cpc)
+    n_slices = colidx.shape[0]
+    x = jnp.asarray(np.random.default_rng(5).standard_normal(n_cols),
+                    jnp.float32)
+
+    def product():
+        return ops.sell_spmv(colidx, values, x, cols_per_chunk=cpc,
+                             block_rows=block_rows, packed=packed)
+
+    # The budget is read while the kernel is traced.
+    jax.clear_caches()
+    try:
+        grids = _pallas_grids(jax.make_jaxpr(product)().jaxpr)
+        y = product()
+        monkeypatch.setattr(sell_spmv, "X_RESIDENT_BUDGET", 0)
+        jax.clear_caches()
+        fallback_grids = _pallas_grids(jax.make_jaxpr(product)().jaxpr)
+        y_grid = product()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert grids == [(-(-n_slices // sell_spmv.TILE_SLICES),)]
+    assert len(fallback_grids[0]) == 3
+    ye = ref.sell_spmv_ref(colidx, values, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ye), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_grid), rtol=1e-5,
+                               atol=1e-5)

@@ -23,6 +23,8 @@ from repro.kernels import ops, ref
 from repro.kernels.sell_spmv import (
     DevicePlan,
     build_device_plan,
+    chunk_row_plan,
+    lane_dense_plan,
     resolve_packing,
 )
 
@@ -91,6 +93,26 @@ def test_pack_roundtrip_bit_exact(
     )
     assert plan.meta_bytes_per_element == META_BYTES_PACKED
     assert unpacked.meta_bytes_per_element == META_BYTES_UNPACKED
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_lane_dense_plan_decodes_like_chunk_rows(packed):
+    """The x-resident path's lane-dense metadata holds the same words:
+    it decodes to the schedule's warps and offsets, and `chunk_row_plan`
+    gives the chunk rows back."""
+    n_slices, n_chunks, cpc, H = 3, 2, 8, 32
+    stream = RNG.integers(0, 5_000, size=n_slices * n_chunks * cpc * H)
+    sched = _schedule(stream, window=cpc * H, block_rows=8)
+    plan = build_device_plan(sched, n_slices=n_slices, cols_per_chunk=cpc,
+                             slice_height=H, packed=packed)
+    lane = lane_dense_plan(plan)
+    rows = 1 if packed else 2
+    assert lane.elem_meta.shape == (n_slices * n_chunks * rows * 2, 128)
+    for name in ("elem_warp", "elem_offset"):
+        np.testing.assert_array_equal(np.asarray(getattr(lane, name)),
+                                      np.asarray(getattr(plan, name)))
+    np.testing.assert_array_equal(np.asarray(chunk_row_plan(lane).elem_meta),
+                                  np.asarray(plan.elem_meta))
 
 
 def test_pack_decodes_high_warp_ids_with_logical_shift():

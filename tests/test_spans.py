@@ -107,7 +107,8 @@ def test_planner_stages_run_in_order_and_count_grid_steps():
                if s.name.startswith("planner."))
     lower = next(s for s in record if s.name == "planner.lower")
     assert lower.counts == {
-        "grid_steps": sell_spmv.grid_steps(engine._device_plan)}
+        "grid_steps": sell_spmv.grid_steps(engine._device_plan),
+        "x_resident": 1}
     # The stages do not overlap, and the planner spans hold them.
     planned = sum(s.seconds for s in record if s.name == "planner")
     assert sum(s.seconds for s in record
@@ -164,16 +165,18 @@ def test_solver_phases_inside_the_solver_span(solver):
 
 
 def _kernel_steps(jaxpr) -> int:
-    """Grid steps of every `pallas_call` in `jaxpr`, through `lax.map`'s scan
-    and nested jits."""
+    """Grid steps of every `pallas_call` in `jaxpr`, through `lax.map`'s scan,
+    nested jits and the resident call's `sequential_vmap` wrapper."""
     steps = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             steps += math.prod(eqn.params["grid_mapping"].grid)
-        elif "jaxpr" in eqn.params:
-            inner = eqn.params["jaxpr"]
-            steps += eqn.params.get("length", 1) * _kernel_steps(
-                getattr(inner, "jaxpr", inner))
+            continue
+        for key in ("jaxpr", "call"):
+            if key in eqn.params:
+                inner = eqn.params[key]
+                steps += eqn.params.get("length", 1) * _kernel_steps(
+                    getattr(inner, "jaxpr", inner))
     return steps
 
 
@@ -199,6 +202,45 @@ def test_grid_steps_match_the_kernel_grid(tag_budget, monkeypatch):
     assert (group < plan.n_slices) == (tag_budget == 4096)
     lower = next(s for s in record if s.name == "planner.lower")
     assert lower.counts["grid_steps"] == _kernel_steps(jaxpr.jaxpr) > 0
+
+
+@pytest.mark.parametrize("x_budget", [None, 0], ids=["x_fits", "x_over"])
+def test_x_resident_count_follows_the_engagement_rule(x_budget,
+                                                      monkeypatch):
+    """`x_resident` on `planner.lower` reads 1 where x fits the budget, and
+    `grid_steps` then counts one step per tile of slices; with x over the
+    budget it reads 0 and the per-warp grid runs. Both give A @ x."""
+    jax.clear_caches()
+    if x_budget is not None:
+        monkeypatch.setattr(sell_spmv, "X_RESIDENT_BUDGET", x_budget)
+    try:
+        A = hpcg_stencil(8, 8, 8)()  # 16 slices: two tiles
+        with spans.recording() as record:
+            engine = get_engine(A, backend="pallas")
+            apply, ops = engine.device_matvec()
+        x = jnp.asarray(np.random.default_rng(0).standard_normal(A.n_cols),
+                        jnp.float32)
+        steps = _kernel_steps(jax.make_jaxpr(apply)(ops, x).jaxpr)
+        y = engine.matvec(x)
+    finally:
+        monkeypatch.undo()
+        clear_engine_cache()
+        jax.clear_caches()
+    lower = next(s for s in record if s.name == "planner.lower")
+    plan = engine._device_plan
+    resident = int(x_budget is None)
+    assert lower.counts["x_resident"] == resident
+    assert lower.counts["grid_steps"] == steps
+    if resident:
+        assert steps == -(-plan.n_slices // sell_spmv.TILE_SLICES) == 2
+    else:
+        assert steps == plan.n_slices * plan.n_chunks * plan.max_warps
+    dense = np.zeros((A.n_rows, A.n_cols))
+    for r in range(A.n_rows):
+        lo, hi = A.indptr[r], A.indptr[r + 1]
+        dense[r, A.indices[lo:hi]] = A.data[lo:hi]
+    np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(x),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_executables_carry_their_names():

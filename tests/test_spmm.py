@@ -232,6 +232,27 @@ def test_device_plan_built_once_and_shared():
     assert plan.cols_per_chunk == 4
 
 
+def test_fused_matmat_on_a_resident_plan_reads_the_one_stream():
+    """A plan whose matvec runs the x-resident path holds its stream once,
+    lane-dense. The fused matmat reads those operands (relaid out into the
+    chunk rows it streams inside its own call) and the vmapped matmat runs
+    the resident kernel per column, both with the matvec's results."""
+    dense, sell = _sell_case(96, 160, 0.1, 32, seed=11)
+    eng = SpMVEngine(sell, backend="pallas")
+    x = jnp.asarray(RNG.standard_normal(sell.n_cols).astype(np.float32))
+    np.testing.assert_allclose(np.asarray(eng.matvec(x)), dense @ np.asarray(x),
+                               rtol=1e-5, atol=1e-5)
+    assert eng._device_plan.lane_dense
+    held = {key: ops[0] for key, ops in eng._operands.items()}
+    X = jnp.asarray(RNG.standard_normal((sell.n_cols, 3)).astype(np.float32))
+    for product in (eng.matmat, eng.matmat_vmapped):
+        np.testing.assert_allclose(np.asarray(product(X)),
+                                   dense @ np.asarray(X), rtol=1e-5,
+                                   atol=1e-5)
+    assert set(eng._operands) == set(held)
+    assert all(eng._operands[key][0] is va for key, va in held.items())
+
+
 def test_fused_matmat_k_edge_cases():
     _, sell = _sell_case(33, 80, 0.2, 8, seed=2, force_width=13)  # odd W
     eng = SpMVEngine(sell, backend="pallas", cols_per_chunk=4, k_tile=K_TILE)

@@ -6,6 +6,7 @@ layout limits, SMEM and VMEM budgets) at no chip time.
 The topology is described inside a fixture, never at import time: only one
 process at a time may load the TPU compiler library.
 """
+import math
 import os
 import re
 
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.coalesced_gather import coalesced_gather_pallas
 from repro.kernels.sell_spmm import sell_spmm_pallas
+from repro.kernels import sell_spmv
 from repro.kernels.sell_spmv import DevicePlan, sell_spmv_pallas
 
 H = 32  # SELL slice height
@@ -60,11 +62,13 @@ def _shape(sharding, shape, dtype):
 
 
 def _plan(sharding, n_slices, n_chunks, max_warps, *, cols_per_chunk=CPC,
-          slice_height=H, block_rows=8):
+          slice_height=H, block_rows=8, lane_dense=False):
     window = cols_per_chunk * slice_height
+    meta = ((n_slices * n_chunks * window // 128, 128) if lane_dense
+            else (n_slices, n_chunks, 1, window))
     return DevicePlan(
         tags=_shape(sharding, (n_slices * n_chunks, max_warps), jnp.int32),
-        elem_meta=_shape(sharding, (n_slices, n_chunks, 1, window), jnp.int32),
+        elem_meta=_shape(sharding, meta, jnp.int32),
         window=window, block_rows=block_rows, cols_per_chunk=cols_per_chunk,
         slice_height=slice_height, n_slices=n_slices, n_chunks=n_chunks,
         packed=True,
@@ -85,6 +89,26 @@ ONE_GROUP = (64, 4, 26)
 
 
 def _lower_spmv(sharding, geometry):
+    """The engine's matvec at `geometry`: the stream held lane-dense, as
+    `SpMVEngine` holds it for the x-resident path, reshaped to the kernel's
+    (n_slices, W, H) signature inside the call."""
+    n_slices, n_chunks, max_warps = geometry
+    plan = _plan(sharding, *geometry, lane_dense=True)
+    fn = jax.jit(lambda v, x, p: sell_spmv_pallas(
+        None, v.reshape(n_slices, n_chunks * CPC, H), x, cols_per_chunk=CPC,
+        plan=p
+    ))
+    return fn.lower(
+        _shape(sharding, (n_slices * n_chunks * WINDOW // 128, 128),
+               jnp.float32),
+        _shape(sharding, (n_slices * H,), jnp.float32),
+        plan,
+    )
+
+
+def _lower_spmv_per_warp(sharding, geometry):
+    """The per-warp grid at `geometry`: a plan of chunk rows, as `SpMVEngine`
+    holds it where x is over the resident budget."""
     n_slices, n_chunks, max_warps = geometry
     plan = _plan(sharding, *geometry)
     fn = jax.jit(lambda v, x, p: sell_spmv_pallas(
@@ -130,6 +154,64 @@ def test_sell_spmv_compiles_for_v5e(one_chip, geometry):
     _assert_kernel(_lower_spmv(one_chip, geometry))
 
 
+@pytest.mark.parametrize("geometry", [HPCG, WEBBASE], ids=["hpcg", "webbase"])
+def test_sell_spmv_per_warp_grid_compiles_for_v5e(one_chip, geometry):
+    """A plan of chunk rows: the per-warp grid, slice groups and all."""
+    _assert_kernel(_lower_spmv_per_warp(one_chip, geometry))
+
+
+def test_sell_spmv_resident_compiles_under_vmap_for_v5e(one_chip):
+    """`SpMVEngine.matmat_vmapped` on an x-resident plan: the resident
+    kernel batched over 8 right-hand sides, at the webbase geometry, whose
+    31,250 slices leave a partial last tile."""
+    n_slices, n_chunks, _ = WEBBASE
+    plan = _plan(one_chip, *WEBBASE, lane_dense=True)
+    fn = jax.jit(jax.vmap(lambda v, x, p: sell_spmv_pallas(
+        None, v.reshape(n_slices, n_chunks * CPC, H), x, cols_per_chunk=CPC,
+        plan=p
+    ), in_axes=(None, 1, None), out_axes=1))
+    _assert_kernel(fn.lower(
+        _shape(one_chip, (n_slices * n_chunks * WINDOW // 128, 128),
+               jnp.float32),
+        _shape(one_chip, (n_slices * H, 8), jnp.float32),
+        plan,
+    ))
+
+
+def _elements(shape: str) -> int:
+    dims = re.search(r"\[([\d,]*)\]", shape).group(1)
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+@pytest.mark.parametrize("geometry", [HPCG, WEBBASE], ids=["hpcg", "webbase"])
+def test_sell_spmv_is_one_resident_kernel_call(one_chip, geometry):
+    """At full size the product is one `sell_spmv` kernel over
+    ceil(n_slices / 8) grid steps: no loop over slice groups around it, no
+    relayout of the stream, and the kernel's VMEM (x and the pipelined
+    blocks) within the limit it sets."""
+    n_slices, n_chunks, _ = geometry
+    text = _lower_spmv(one_chip, geometry).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert " while(" not in text
+    # No instruction but the parameters holds as many elements as the
+    # lane-dense stream: the kernel reads values and metadata in place.
+    stream = n_slices * n_chunks * WINDOW
+    produced = re.findall(r"= (\w+\[[\d,]*\])\S* (?!parameter)(\w[\w-]*)\(",
+                          text)
+    assert produced
+    assert all(_elements(shape) < stream for shape, _ in produced), produced
+    limit = int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                          calls[0]).group(1))
+    used = int(re.search(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+        calls[0]).group(1))
+    x_bytes = -(-n_slices * H // 128) * 128 * 4
+    assert x_bytes <= used <= limit
+    assert limit <= sell_spmv.X_RESIDENT_BUDGET + 16 * 2 ** 20
+
+
 def test_sell_spmm_compiles_for_v5e_at_k8(one_chip):
     _assert_kernel(_lower_spmm(one_chip))
 
@@ -142,6 +224,10 @@ KERNELS = {
     "sell_spmv_hpcg": ("sell_spmv", lambda sh: _lower_spmv(sh, HPCG)),
     "sell_spmv_one_group": ("sell_spmv",
                             lambda sh: _lower_spmv(sh, ONE_GROUP)),
+    "sell_spmv_per_warp_hpcg": ("sell_spmv",
+                                lambda sh: _lower_spmv_per_warp(sh, HPCG)),
+    "sell_spmv_per_warp_one_group": (
+        "sell_spmv", lambda sh: _lower_spmv_per_warp(sh, ONE_GROUP)),
     "sell_spmm": ("sell_spmm", _lower_spmm),
     "coalesced_gather": ("coalesced_gather", _lower_gather),
 }
@@ -151,7 +237,8 @@ KERNELS = {
 def test_kernel_is_named_after_its_scope(one_chip, case):
     """A device trace names an operation after its HLO instruction, and the
     kernel's innermost `named_scope` names that instruction: ``sell_spmv.N``
-    whether or not a slice-group loop surrounds the call."""
+    on the x-resident path and on the per-warp grid, whether or not a
+    slice-group loop surrounds the per-warp call."""
     scope, lower = KERNELS[case]
     text = lower(one_chip).compile().as_text()
     names = re.findall(
