@@ -86,7 +86,8 @@ def _matrix(name: str):
 
 def _lower(eng) -> dict:
     """Plan `eng` for its matvec; the counts of its `planner.lower` span
-    (`grid_steps`, `x_resident`)."""
+    (`grid_steps`, `x_resident`, `block_rows`, `max_warps`,
+    `lane_gathers`)."""
     from repro.core import spans
 
     with spans.recording() as record:
@@ -102,9 +103,10 @@ def _geometry(eng, counts: dict) -> str:
         f"rows={eng.n_rows} nnz={int(np.count_nonzero(eng.sell.values))} "
         f"slices={n_slices} plan_width={n_chunks * eng.cols_per_chunk} "
         f"window={eng.window} windows={sched.n_windows} "
-        f"max_warps={sched.max_warps} "
+        f"block_rows={eng.block_rows} max_warps={sched.max_warps} "
         f"x_resident={counts.get('x_resident')} "
-        f"grid_steps_per_spmv={counts.get('grid_steps')}"
+        f"grid_steps_per_spmv={counts.get('grid_steps')} "
+        f"lane_gathers_per_spmv={counts.get('lane_gathers')}"
     )
 
 
@@ -225,9 +227,10 @@ def four_chip_phase(csr=None, *, backend: str = "auto", k: int = K) -> bool:
         return eng, [_lower(shard) for shard in eng.engines]
 
     (eng, counts), t_plan, _ = _timed(plan)
-    print(f"  shards x_resident="
+    print(f"  shards block_rows={eng.block_rows} x_resident="
           f"{[c.get('x_resident') for c in counts]} grid_steps_per_spmv="
-          f"{[c.get('grid_steps') for c in counts]}", flush=True)
+          f"{[c.get('grid_steps') for c in counts]} lane_gathers_per_spmv="
+          f"{[c.get('lane_gathers') for c in counts]}", flush=True)
     def run():
         pending = eng.dispatch(eng.stage(X))
         jax.block_until_ready(pending.blocks)

@@ -65,7 +65,7 @@ from .coalescer import META_BYTES_PACKED, META_BYTES_UNPACKED, \
     coalesce_stats, schedule_meta_bytes
 from .engine import DEFAULT_BUFFER_DEPTH, DEFAULT_COLS_PER_CHUNK, \
     DEFAULT_K_TILE, DEFAULT_WINDOW, get_engine, resolve_backend, \
-    resolve_packed
+    resolve_block_rows, resolve_packed, resolve_value_dtype, resolve_window
 from .formats import CSRMatrix, SELLMatrix
 from .partition import resolve_partition, shard_bounds
 from .perfmodel import matmat_spmv_perf, sharded_spmv_perf, \
@@ -218,7 +218,7 @@ class ShardedSpMVEngine:
         mesh: Optional[jax.sharding.Mesh] = None,
         n_shards: Optional[int] = None,
         window: Optional[int] = None,
-        block_rows: int = 8,
+        block_rows: Optional[int] = None,
         slice_height: Optional[int] = None,
         width_multiple: int = 1,
         backend: str = "auto",
@@ -242,7 +242,17 @@ class ShardedSpMVEngine:
 
         self.backend = backend
         self.backend_resolved = resolve_backend(backend)
-        self.block_rows = int(block_rows)
+        # Resolved once, on the whole matrix, so the partition below and
+        # every shard plan coalesce alike (a shard keeps all the columns).
+        self.block_rows = resolve_block_rows(
+            block_rows, sell, backend_resolved=self.backend_resolved,
+            window=resolve_window(
+                window, backend_resolved=self.backend_resolved,
+                cols_per_chunk=cols_per_chunk,
+                slice_height=sell.slice_height,
+            ),
+            value_dtype=resolve_value_dtype(value_dtype),
+        )
         self.window = window
         self.n_shards = (
             self.n_data if n_shards is None else int(n_shards)
@@ -272,7 +282,7 @@ class ShardedSpMVEngine:
             get_engine(
                 shard,
                 window=window,
-                block_rows=block_rows,
+                block_rows=self.block_rows,
                 backend=backend,
                 cols_per_chunk=cols_per_chunk,
                 k_tile=k_tile,

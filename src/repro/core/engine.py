@@ -83,6 +83,9 @@ BACKEND_ENV = "REPRO_BACKEND"
 DEFAULT_WINDOW = 256
 DEFAULT_COLS_PER_CHUNK = 8
 DEFAULT_K_TILE = 8
+# Coalescing granularity wherever x is not held in VMEM lane rows (see
+# `resolve_block_rows`).
+DEFAULT_BLOCK_ROWS = 8
 # Kernel-pipeline default; must match kernels.sell_spmv.DEFAULT_BUFFER_DEPTH
 # (core stays importable before the kernels package, so no import here).
 DEFAULT_BUFFER_DEPTH = 2
@@ -221,6 +224,42 @@ def resolve_window(
             )
         return kernel_window
     return DEFAULT_WINDOW if window is None else int(window)
+
+
+def _storage_dtype(value_dtype: Optional[str]):
+    """The jnp dtype a resolved value-storage knob stores values in, or
+    None to follow the input."""
+    return {"bf16": jnp.bfloat16, "f32": jnp.float32, None: None}[value_dtype]
+
+
+def resolve_block_rows(
+    block_rows: Optional[int],
+    sell: SELLMatrix,
+    *,
+    backend_resolved: str,
+    window: int,
+    value_dtype: Optional[str],
+) -> int:
+    """The engine's coalescing granularity, shared by `SpMVEngine.__init__`,
+    the `get_engine` cache key and `ShardedSpMVEngine`. An explicit
+    `block_rows` is honoured. ``None`` resolves to a whole 128-lane row
+    (`kernels.sell_spmv.LANES`) where the pallas plan will hold x in VMEM
+    (`kernels.sell_spmv.resident_geometry` on the matrix's columns, stored
+    value dtype, window and slice height): every warp there loads a whole
+    lane row, so coalescing at that width fetches no more and runs fewer
+    warps. Elsewhere it resolves to `DEFAULT_BLOCK_ROWS`."""
+    if block_rows is not None:
+        return int(block_rows)
+    if backend_resolved == "pallas":
+        # Local: core stays importable before the kernels package.
+        from repro.kernels.sell_spmv import LANES, resident_geometry
+
+        dtype = _storage_dtype(value_dtype) or jax.dtypes.canonicalize_dtype(
+            sell.values.dtype)
+        if resident_geometry(sell.n_cols, dtype, window=window,
+                             slice_height=sell.slice_height):
+            return LANES
+    return DEFAULT_BLOCK_ROWS
 
 
 def resolve_matmat_mode(mode: str, backend_resolved: str) -> str:
@@ -610,6 +649,10 @@ class SpMVEngine:
     to the kernel-derived window for pallas; an explicit window that fights
     the pallas geometry raises rather than being silently ignored.
 
+    ``block_rows=None`` (default) resolves to 128, a whole VMEM lane row,
+    where the pallas matvec will hold x in VMEM, and to 8 everywhere else
+    (`resolve_block_rows`); an explicit value is always honoured.
+
     ``cache_dir`` (default: ``$REPRO_SCHEDULE_CACHE``) enables persistent
     schedule caching — see `cached_block_schedule`.
     """
@@ -619,7 +662,7 @@ class SpMVEngine:
         matrix: Union[CSRMatrix, SELLMatrix],
         *,
         window: Optional[int] = None,
-        block_rows: int = 8,
+        block_rows: Optional[int] = None,
         slice_height: Optional[int] = None,
         width_multiple: int = 1,
         backend: str = "auto",
@@ -664,7 +707,6 @@ class SpMVEngine:
         self.matmat_mode_resolved = resolve_matmat_mode(
             matmat_mode, self.backend_resolved
         )
-        self.block_rows = int(block_rows)
         self.cache_dir = schedule_store.resolve_cache_dir(cache_dir)
 
         self.window = resolve_window(
@@ -672,6 +714,10 @@ class SpMVEngine:
             backend_resolved=self.backend_resolved,
             cols_per_chunk=self.cols_per_chunk,
             slice_height=sell.slice_height,
+        )
+        self.block_rows = resolve_block_rows(
+            block_rows, sell, backend_resolved=self.backend_resolved,
+            window=self.window, value_dtype=self.value_dtype,
         )
         if plan_width_multiple is None:
             plan_width_multiple = (
@@ -802,10 +848,7 @@ class SpMVEngine:
             # Narrow value storage: cast the hoisted value plan once per
             # trace; the multiply promotes back to the RHS dtype (f32
             # accumulation for bf16 values).
-            vdt = (
-                {"bf16": jnp.bfloat16, "f32": jnp.float32}[self.value_dtype]
-                if self.value_dtype is not None else None
-            )
+            vdt = _storage_dtype(self.value_dtype)
 
             if self.backend_resolved == "pallas":
                 cpc = self.cols_per_chunk
@@ -820,7 +863,7 @@ class SpMVEngine:
                     from repro.kernels.sell_spmm import sell_spmm_pallas
                     from repro.kernels.sell_spmv import build_device_plan, \
                         chunk_row_plan, device_operands, grid_steps, \
-                        sell_spmv_pallas
+                        lane_gathers, sell_spmv_pallas
 
                     # Lower the schedule to the kernel-ready device plan
                     # exactly once; the matvec and the fused matmat kernels
@@ -843,6 +886,9 @@ class SpMVEngine:
                     plan = self._device_plan = operands[1]
                     counts["grid_steps"] = grid_steps(plan)
                     counts["x_resident"] = int(plan.lane_dense)
+                    counts["block_rows"] = plan.block_rows
+                    counts["max_warps"] = plan.max_warps
+                    counts["lane_gathers"] = lane_gathers(plan)
                 interpret = resolve_interpret()
 
                 def _values(va, dtype):
@@ -1197,7 +1243,7 @@ def get_engine(
     matrix: Union[CSRMatrix, SELLMatrix],
     *,
     window: Optional[int] = None,
-    block_rows: int = 8,
+    block_rows: Optional[int] = None,
     slice_height: Optional[int] = None,
     width_multiple: int = 1,
     backend: str = "auto",
@@ -1212,11 +1258,13 @@ def get_engine(
     """Engine cache: same matrix content + plan params -> same engine (and
     therefore same compiled matvec/matmat). CSR inputs are keyed on the SELL
     they convert to, so CSR and its converted SELL share an engine. The key
-    includes the *resolved* backend, the *resolved* window, and the
-    *resolved* matmat mode — exactly the resolution `SpMVEngine.__init__`
-    performs, so ``window=None`` and its explicit spelling (256 for
-    reference, `cols_per_chunk * slice_height` for pallas) share one engine
-    instead of duplicating schedules and jit compiles — and, for pallas,
+    includes the *resolved* backend, the *resolved* window, the *resolved*
+    `block_rows` and the *resolved* matmat mode — exactly the resolution
+    `SpMVEngine.__init__` performs, so ``window=None`` and its explicit
+    spelling (256 for reference, `cols_per_chunk * slice_height` for
+    pallas), and ``block_rows=None`` and the value it resolves to
+    (`resolve_block_rows`), share one engine instead of duplicating
+    schedules and jit compiles — and, for pallas,
     `cols_per_chunk`, `k_tile`, `packed`, and `buffer_depth`, which shape its
     plan encoding and its executables (the reference backend ignores them
     all, so they stay out of its key). `packed` is keyed on the *requested*
@@ -1236,19 +1284,25 @@ def get_engine(
         raise ValueError(
             f"packed must be one of {PACKED_CHOICES}, got {packed!r}"
         )
+    window_resolved = resolve_window(
+        window,
+        backend_resolved=resolved,
+        cols_per_chunk=cols_per_chunk,
+        slice_height=matrix.slice_height,
+    )
+    storage = resolve_value_dtype(value_dtype)
+    block_rows = resolve_block_rows(
+        block_rows, matrix, backend_resolved=resolved, window=window_resolved,
+        value_dtype=storage,
+    )
     key = (
         _sell_content_digest(matrix),
-        resolve_window(
-            window,
-            backend_resolved=resolved,
-            cols_per_chunk=cols_per_chunk,
-            slice_height=matrix.slice_height,
-        ),
+        window_resolved,
         block_rows,
         resolved,
         # Value storage changes numerics on every backend, so it keys both
         # ("native" and None share the engine — same resolution as __init__).
-        resolve_value_dtype(value_dtype),
+        storage,
         # k_tile only shapes the *fused* executable; a vmapped pallas engine
         # ignores it, so resolved-identical configurations share one engine
         # (the same rule that keeps cols_per_chunk out of reference keys).

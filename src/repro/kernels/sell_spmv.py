@@ -400,6 +400,16 @@ def grid_steps(plan: DevicePlan) -> int:
     return n_groups * group * plan.n_chunks * plan.max_warps
 
 
+def lane_gathers(plan: DevicePlan) -> int:
+    """Lane-gather rounds one product over `plan` runs on the x-resident
+    path: every window loops over its tag row `warps_per_gather` warps at a
+    time. 0 on the per-warp grid, which gathers no lanes."""
+    if not plan.lane_dense:
+        return 0
+    rounds = -(-plan.max_warps // warps_per_gather(plan.window))
+    return plan.n_slices * plan.n_chunks * rounds
+
+
 def run_slice_groups(call, tags, *, n_slices: int, n_chunks: int,
                      group: int):
     """Run ``call(base, tags_group)`` over consecutive groups of `group`
@@ -652,19 +662,29 @@ def _tag_block_bytes(plan: DevicePlan) -> int:
     return 2 * _tile(plan.n_slices) * plan.n_chunks * row_bytes
 
 
-def x_resident(plan: DevicePlan, n_cols: int, value_dtype) -> bool:
-    """Whether a product over `plan` with an x of `n_cols` entries and
-    values of `value_dtype` runs the x-resident path: x padded to whole
-    128-lane rows fits `X_RESIDENT_BUDGET`, values are 32-bit (a window's
-    lane rows then load from any row), a window is whole lane rows, a
-    slice's outputs and a coalesced block both divide a lane row, and a
-    step's tag rows fit `SMEM_TAG_BUDGET`. Any other plan runs the per-warp
-    grid."""
+def resident_geometry(n_cols: int, value_dtype, *, window: int,
+                      slice_height: int) -> bool:
+    """The conditions of `x_resident` that a schedule does not decide: x of
+    `n_cols` entries padded to whole 128-lane rows fits `X_RESIDENT_BUDGET`,
+    values are 32-bit (a window's lane rows then load from any row), a
+    window is whole lane rows and a slice's outputs divide a lane row. A
+    planner that finds them true coalesces at `LANES` (`SpMVEngine`)."""
     return (
         _x_rows(n_cols) * LANES * 4 <= X_RESIDENT_BUDGET
         and jnp.dtype(value_dtype).itemsize == 4
-        and plan.window % LANES == 0
-        and LANES % plan.slice_height == 0
+        and window % LANES == 0
+        and LANES % slice_height == 0
+    )
+
+
+def x_resident(plan: DevicePlan, n_cols: int, value_dtype) -> bool:
+    """Whether a product over `plan` with an x of `n_cols` entries and
+    values of `value_dtype` runs the x-resident path: `resident_geometry`
+    holds, a coalesced block divides a lane row, and a step's tag rows fit
+    `SMEM_TAG_BUDGET`. Any other plan runs the per-warp grid."""
+    return (
+        resident_geometry(n_cols, value_dtype, window=plan.window,
+                          slice_height=plan.slice_height)
         and LANES % plan.block_rows == 0
         and _tag_block_bytes(plan) <= SMEM_TAG_BUDGET
     )
@@ -679,6 +699,13 @@ def device_operands(plan: DevicePlan, values: jnp.ndarray, n_cols: int):
     if x_resident(plan, n_cols, values.dtype):
         return stream_values(values), lane_dense_plan(plan)
     return chunk_values(values, plan.cols_per_chunk), chunk_row_plan(plan)
+
+
+def warps_per_gather(window: int) -> int:
+    """Warps one lane gather of the resident kernel serves: a window's
+    ``window // 128`` lane rows fill that many of a vector register's 8
+    sublanes, and each further copy of the window serves one more warp."""
+    return max(1, 8 // (window // LANES))
 
 
 def _fold_lane_rows(prod, cols_per_chunk: int, slice_height: int):
@@ -730,7 +757,7 @@ def _resident_kernel(
     # A window's rows fill `rows` of a vector register's 8 sublanes, so one
     # register serves `n_warps` warps at once: copy k of the window serves
     # warp t0 + k, and one lane gather picks all of them.
-    n_warps = max(1, 8 // rows)
+    n_warps = warps_per_gather(cols_per_chunk * slice_height)
     copy_of = jax.lax.broadcasted_iota(
         jnp.int32, (n_warps * rows, LANES), 0) // rows
 
@@ -746,17 +773,19 @@ def _resident_kernel(
                 tag = tags_ref[w, min(t0 + k, max_warps - 1)]
                 row = x_vmem[pl.ds(
                     jax.lax.shift_right_logical(tag, row_shift), 1), :]
-                if k == 0:
-                    x_n = jnp.broadcast_to(row, copy_of.shape)
-                    tag_n = jnp.full(copy_of.shape, tag)
-                else:
-                    x_n = jnp.where(copy_of == k, row, x_n)
-                    tag_n = jnp.where(copy_of == k, tag, tag_n)
-            # The lane bases on the vector side: the scalar unit is the
-            # busiest in this loop.
-            base_n = jax.lax.shift_left(
-                jnp.bitwise_and(tag_n, per_row - 1), lane_shift)
-            picked = jnp.take_along_axis(x_n, eo_n + base_n, axis=1,
+                x_n = (jnp.broadcast_to(row, copy_of.shape) if k == 0
+                       else jnp.where(copy_of == k, row, x_n))
+                if per_row > 1:
+                    tag_n = (jnp.full(copy_of.shape, tag) if k == 0
+                             else jnp.where(copy_of == k, tag, tag_n))
+            lanes = eo_n
+            if per_row > 1:
+                # The lane bases on the vector side: the scalar unit is the
+                # busiest in this loop. A block of a whole lane row starts
+                # at lane 0, so it needs none.
+                lanes = eo_n + jax.lax.shift_left(
+                    jnp.bitwise_and(tag_n, per_row - 1), lane_shift)
+            picked = jnp.take_along_axis(x_n, lanes, axis=1,
                                          mode="promise_in_bounds")
             g = jnp.where(ew_n == t0, picked, g)
         # Each element matched in exactly one copy; the others hold zeros.

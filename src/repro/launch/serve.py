@@ -600,7 +600,11 @@ def main() -> None:
         help="coalescer window (default: 256 for the reference backend, "
         "cols_per_chunk*slice_height for pallas)",
     )
-    ap.add_argument("--block-rows", type=int, default=8)
+    ap.add_argument(
+        "--block-rows", type=int, default=None,
+        help="coalesced block size (default: 128 where the pallas matvec "
+        "holds x in VMEM, else 8)",
+    )
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument(

@@ -281,3 +281,53 @@ def test_lazy_planning_perf_does_not_build_schedule():
     assert eng._schedule is None  # perf-model query never pays for planning
     eng.matvec(jnp.zeros((csr.n_cols,), jnp.float32))
     assert eng._schedule is not None
+
+
+def _resident_sell():
+    """float32 SELL whose pallas plan holds x in VMEM: slice height 32,
+    window 8 * 32 = 256, two lane rows."""
+    _, csr = _case(96, 140, seed=31)
+    return csr_to_sell(csr, slice_height=32)
+
+
+@pytest.mark.parametrize("case", ["f32", "bf16", "x_over_budget",
+                                  "reference", "explicit"])
+def test_block_rows_none_resolves_to_a_lane_row_on_the_resident_path(
+        case, monkeypatch):
+    """`block_rows=None` coalesces at a whole 128-lane row where the pallas
+    matvec will hold x in VMEM, and at 8 where it will not: 16-bit values,
+    x over the budget, the reference backend. An explicit value wins."""
+    from repro.kernels import sell_spmv
+
+    sell = _resident_sell()
+    kw = {"backend": "reference" if case == "reference" else "pallas"}
+    if case == "bf16":
+        kw["value_dtype"] = "bf16"
+    if case == "explicit":
+        kw["block_rows"] = 16
+    if case == "x_over_budget":
+        monkeypatch.setattr(sell_spmv, "X_RESIDENT_BUDGET", 64)
+    expected = {"f32": 128, "explicit": 16}.get(case, 8)
+    assert SpMVEngine(sell, **kw).block_rows == expected
+    assert get_engine(sell, **kw).block_rows == expected
+
+
+def test_default_and_lane_row_block_rows_share_one_engine():
+    sell = _resident_sell()
+    e_none = get_engine(sell, backend="pallas")
+    e_128 = get_engine(sell, backend="pallas", block_rows=128)
+    assert e_128 is e_none
+    assert e_none.schedule.block_rows == 128
+    assert schedule_cache_stats()["built"] == 1
+    assert get_engine(sell, backend="pallas", block_rows=8) is not e_none
+
+
+@pytest.mark.parametrize("value_dtype,expected", [(None, 128), ("bf16", 8)])
+def test_sharded_engine_resolves_block_rows_once_for_every_shard(
+        value_dtype, expected):
+    from repro.core.dist import ShardedSpMVEngine
+
+    sharded = ShardedSpMVEngine(_resident_sell(), backend="pallas",
+                                n_shards=2, value_dtype=value_dtype)
+    assert sharded.block_rows == expected
+    assert [e.block_rows for e in sharded.engines] == [expected] * 2

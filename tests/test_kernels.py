@@ -275,6 +275,12 @@ RESIDENT_CASES = {
     "stencil27": (_stencil_sell, 32, 8, 8, "auto"),
     "skewed": (_skewed_sell, 32, 4, 8, "auto"),
 }
+# Coalesced at a whole lane row (`SpMVEngine`'s default on this path).
+RESIDENT_CASES.update({
+    f"{name}_block_rows128{tag}": (*RESIDENT_CASES[name][:3], 128, packed)
+    for name in ("stencil27", "skewed", "ragged_slices_and_cols")
+    for packed, tag in (("auto", ""), (False, "_unpacked"))
+})
 
 
 @pytest.mark.parametrize("case", sorted(RESIDENT_CASES))
@@ -313,3 +319,26 @@ def test_sell_spmv_resident_parity(case, monkeypatch):
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_grid), rtol=1e-5,
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["stencil27", "skewed"])
+def test_resident_lane_row_plan_is_bitwise_the_8_plan(case):
+    """On the x-resident path a plan coalesced at a whole lane row gives the
+    8-row plan's y bit for bit: every element gathers the same x entry and
+    accumulates in the same order; the other copies add exact zeros."""
+    from repro.kernels import sell_spmv
+
+    build, H, cpc, _, _ = RESIDENT_CASES[case]
+    colidx, values, n_cols = build(H, cpc)
+    x = jnp.asarray(np.random.default_rng(9).standard_normal(n_cols),
+                    jnp.float32)
+    ys = {}
+    for block_rows in (8, 128):
+        def product():
+            return ops.sell_spmv(colidx, values, x, cols_per_chunk=cpc,
+                                 block_rows=block_rows)
+
+        grids = _pallas_grids(jax.make_jaxpr(product)().jaxpr)
+        assert grids == [(-(-colidx.shape[0] // sell_spmv.TILE_SLICES),)]
+        ys[block_rows] = np.asarray(product())
+    np.testing.assert_array_equal(ys[128], ys[8])

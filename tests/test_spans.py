@@ -106,9 +106,13 @@ def test_planner_stages_run_in_order_and_count_grid_steps():
     assert all(s.parent == "planner" for s in record
                if s.name.startswith("planner."))
     lower = next(s for s in record if s.name == "planner.lower")
+    plan = engine._device_plan
     assert lower.counts == {
-        "grid_steps": sell_spmv.grid_steps(engine._device_plan),
-        "x_resident": 1}
+        "grid_steps": sell_spmv.grid_steps(plan),
+        "x_resident": 1,
+        "block_rows": 128,
+        "max_warps": plan.max_warps,
+        "lane_gathers": sell_spmv.lane_gathers(plan)}
     # The stages do not overlap, and the planner spans hold them.
     planned = sum(s.seconds for s in record if s.name == "planner")
     assert sum(s.seconds for s in record
@@ -241,6 +245,29 @@ def test_x_resident_count_follows_the_engagement_rule(x_budget,
         dense[r, A.indices[lo:hi]] = A.data[lo:hi]
     np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(x),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("x_budget", [None, 0], ids=["resident", "per_warp"])
+def test_planner_lower_counts_the_lane_gathers(x_budget, monkeypatch):
+    """`planner.lower` records the plan's `block_rows` and `max_warps`, and
+    `lane_gathers`: windows x ceil(max_warps / warps per gather) on the
+    resident path, 0 on the per-warp grid."""
+    if x_budget is not None:
+        monkeypatch.setattr(sell_spmv, "X_RESIDENT_BUDGET", x_budget)
+    A = _tiny()
+    with spans.recording() as record:
+        engine = get_engine(A, backend="pallas")
+        engine.device_matvec()
+    plan = engine._device_plan
+    sched = engine.schedule
+    lower = next(s for s in record if s.name == "planner.lower")
+    assert lower.counts["block_rows"] == sched.block_rows == (
+        128 if x_budget is None else 8)
+    assert lower.counts["max_warps"] == sched.max_warps == plan.max_warps
+    # A window of 8 columns x 32 rows fills 2 of a register's 8 sublanes.
+    rounds = -(-sched.max_warps // (8 // (plan.window // 128)))
+    assert lower.counts["lane_gathers"] == (
+        sched.n_windows * rounds if x_budget is None else 0)
 
 
 def test_executables_carry_their_names():

@@ -84,19 +84,24 @@ def _assert_kernel(lowered):
 # HPCG 27-point 104^3 (1,124,864 rows) and webbase-1M (1,000,000 rows).
 HPCG = (35152, 4, 26)
 WEBBASE = (31250, 8, 120)
+# Coalesced at a whole lane row, as `SpMVEngine` plans both where x stays in
+# VMEM: HPCG's windows hold 7 warps instead of 26, webbase-1M's scattered
+# windows as many as at 8.
+HPCG_LANE_ROW = (35152, 4, 7)
+WEBBASE_LANE_ROW = WEBBASE
 # Few enough slices for one kernel call (no slice-group loop around it).
 ONE_GROUP = (64, 4, 26)
 
 
-def _lower_spmv(sharding, geometry):
+def _lower_spmv(sharding, geometry, block_rows=8):
     """The engine's matvec at `geometry`: the stream held lane-dense, as
     `SpMVEngine` holds it for the x-resident path, reshaped to the kernel's
     (n_slices, W, H) signature inside the call."""
     n_slices, n_chunks, max_warps = geometry
-    plan = _plan(sharding, *geometry, lane_dense=True)
+    plan = _plan(sharding, *geometry, block_rows=block_rows, lane_dense=True)
     fn = jax.jit(lambda v, x, p: sell_spmv_pallas(
         None, v.reshape(n_slices, n_chunks * CPC, H), x, cols_per_chunk=CPC,
-        plan=p
+        block_rows=block_rows, plan=p
     ))
     return fn.lower(
         _shape(sharding, (n_slices * n_chunks * WINDOW // 128, 128),
@@ -121,11 +126,12 @@ def _lower_spmv_per_warp(sharding, geometry):
     )
 
 
-def _lower_spmm(sharding):
-    n_slices, n_chunks, max_warps = WEBBASE
-    plan = _plan(sharding, *WEBBASE)
+def _lower_spmm(sharding, geometry=WEBBASE, block_rows=8):
+    n_slices, n_chunks, max_warps = geometry
+    plan = _plan(sharding, *geometry, block_rows=block_rows)
     fn = jax.jit(lambda v, x, p: sell_spmm_pallas(
-        None, v, x, cols_per_chunk=CPC, k_tile=8, plan=p
+        None, v, x, cols_per_chunk=CPC, block_rows=block_rows, k_tile=8,
+        plan=p
     ))
     return fn.lower(
         _shape(sharding, (n_slices, n_chunks * CPC, H), jnp.float32),
@@ -214,6 +220,16 @@ def test_sell_spmv_is_one_resident_kernel_call(one_chip, geometry):
 
 def test_sell_spmm_compiles_for_v5e_at_k8(one_chip):
     _assert_kernel(_lower_spmm(one_chip))
+
+
+@pytest.mark.parametrize("kernel", ["matvec", "fused_matmat_k8"])
+@pytest.mark.parametrize("geometry", [HPCG_LANE_ROW, WEBBASE_LANE_ROW],
+                         ids=["hpcg", "webbase"])
+def test_lane_row_plan_compiles_for_v5e(one_chip, geometry, kernel):
+    """A plan at `block_rows` 128: the resident matvec, and the fused
+    matmat on the same plan fetching (128, 8) X tiles."""
+    lower = {"matvec": _lower_spmv, "fused_matmat_k8": _lower_spmm}[kernel]
+    _assert_kernel(lower(one_chip, geometry, block_rows=128))
 
 
 def test_coalesced_gather_compiles_for_v5e_paged_kv(one_chip):
