@@ -34,9 +34,6 @@ CHAOS_RETRY_BUDGET = 2
 # absorbs residual CPU jitter — a real pipelining regression blows well
 # past 10%.
 STREAM_JITTER_TOL = 1.10
-# Same policy for the fused matmat kernel vs the vmapped per-column path at
-# k >= k_tile (where the matrix-stream amortization must win).
-MATMAT_JITTER_TOL = 1.10
 # Packed plans ship 4-byte metadata words instead of 8; the stream-level
 # reduction is below 2x only because the warp tags ship either way. 1.5x is
 # a conservative structural floor — it holds for any schedule whose tag
@@ -131,10 +128,7 @@ def _packed_plan_smoke() -> dict:
     metadata-stream reduction (packed plans carry the warp id and the
     16-bit element offset in one int32 word), the perf model's
     mem_util/traffic-ratio under each encoding, and packed-vs-unpacked
-    kernel parity for both the SpMV and the fused matmat path. The packed
-    engine runs the double-buffered (depth=2) kernel and the unpacked one
-    the classic depth=1 pipeline, so the parity gate also crosses the two
-    kernel data paths."""
+    kernel parity for both the SpMV and the matmat path."""
     import jax.numpy as jnp
 
     from repro.core.engine import SpMVEngine
@@ -156,10 +150,8 @@ def _packed_plan_smoke() -> dict:
         X = jnp.asarray(
             rng.standard_normal((sell.n_cols, 8)).astype(np.float32)
         )
-        packed_eng = SpMVEngine(sell, backend="pallas", packed=True,
-                                buffer_depth=2)
-        unpacked_eng = SpMVEngine(sell, backend="pallas", packed=False,
-                                  buffer_depth=1)
+        packed_eng = SpMVEngine(sell, backend="pallas", packed=True)
+        unpacked_eng = SpMVEngine(sell, backend="pallas", packed=False)
         meta = packed_eng.plan_report()["metadata"]
         err_mv = float(np.abs(
             np.asarray(packed_eng.matvec(x))
@@ -599,18 +591,10 @@ def _streaming_smoke() -> dict:
 
 
 def _matmat_smoke() -> dict:
-    """Fused-vs-vmapped matmat rows + the amortization gates.
-
-    The fused `kernels.sell_spmm` kernel must (a) agree with the vmapped
-    per-column path and the reference backend to PARITY_TOL at every tested
-    k — including k < k_tile (clamped tile) and k % k_tile != 0 (padded
-    tail tile) — (b) beat-or-tie vmapped throughput at k >= k_tile, where
-    one pass over the schedule and the SELL values serves k_tile columns
-    instead of one, and (c) track the perf model: `matmat_spmv_perf` must
-    predict the amortization trend (speedup growing from ~1 at k=1 to > 1
-    at k >> k_tile). Throughput uses interleaved paired trials gated on the
-    median per-trial ratio, like the streaming gate — absolute timings on
-    shared CI CPUs drift too much to compare across blocks."""
+    """Matmat parity rows + gate: the pallas engine's `matmat` (its matvec
+    batched over right-hand sides) must agree with the reference backend to
+    PARITY_TOL at every tested k, and the sharded engine's decomposition
+    with the single-device reference."""
     import jax
     import jax.numpy as jnp
 
@@ -618,96 +602,34 @@ def _matmat_smoke() -> dict:
     from repro.core.engine import SpMVEngine
     from repro.core.formats import csr_to_sell
     from repro.core.matrices import banded
-    from repro.core.perfmodel import matmat_spmv_perf
     from .common import emit
 
-    k_tile = 8
-    ks = (1, k_tile - 1, k_tile, 4 * k_tile)
-    k_gate = 4 * k_tile
-    trials = 7
+    ks = (1, 7, 8, 32)
     csr = banded(512, 16, 0.7)(np.random.default_rng(0))
     sell = csr_to_sell(csr)
     rng = np.random.default_rng(1)
-    eng = SpMVEngine(sell, backend="pallas", k_tile=k_tile)
+    eng = SpMVEngine(sell, backend="pallas")
     ref = SpMVEngine(sell, backend="reference")
-    assert eng.matmat_mode_resolved == "fused"
 
     parity: dict = {}
-    predicted: dict = {}
     for k in ks:
         X = jnp.asarray(
             rng.standard_normal((sell.n_cols, k)).astype(np.float32)
         )
-        y_fused = np.asarray(jax.block_until_ready(eng.matmat(X)))
-        y_vmapped = np.asarray(jax.block_until_ready(eng.matmat_vmapped(X)))
+        y = np.asarray(jax.block_until_ready(eng.matmat(X)))
         y_ref = np.asarray(jax.block_until_ready(ref.matmat(X)))
-        parity[str(k)] = {
-            "fused_vs_vmapped": float(np.abs(y_fused - y_vmapped).max()),
-            "fused_vs_reference": float(np.abs(y_fused - y_ref).max()),
-        }
-        predicted[str(k)] = round(
-            matmat_spmv_perf(sell, "pack256", k=k, k_tile=k_tile).speedup, 4
-        )
+        parity[str(k)] = {"pallas_vs_reference": float(np.abs(y - y_ref).max())}
         emit(
             f"matmat/parity/k{k}", 0.0,
-            f"n={sell.n_rows};k_tile={k_tile};"
-            f"fused_vs_vmapped={parity[str(k)]['fused_vs_vmapped']:.2e};"
-            f"fused_vs_reference={parity[str(k)]['fused_vs_reference']:.2e};"
-            f"predicted_speedup_pack256={predicted[str(k)]}",
+            f"n={sell.n_rows};"
+            f"pallas_vs_reference={parity[str(k)]['pallas_vs_reference']:.2e}",
         )
 
-    # Throughput: fused vs vmapped at k >= k_tile, paired interleaved trials
-    # (median per-trial ratio cancels machine-wide drift; order alternates so
-    # cache/thermal carryover cancels over the trial set too).
-    X = jnp.asarray(
-        rng.standard_normal((sell.n_cols, k_gate)).astype(np.float32)
-    )
-
-    def run_fused():
-        jax.block_until_ready(eng.matmat(X))
-
-    def run_vmapped():
-        jax.block_until_ready(eng.matmat_vmapped(X))
-
-    def timed(fn) -> float:
-        t0 = time.perf_counter()
-        fn()
-        return time.perf_counter() - t0
-
-    for fn in (run_fused, run_vmapped):
-        fn()  # warm both compiled paths
-    fused_times, vmapped_times = [], []
-    for i in range(trials):
-        first, second = (
-            (run_fused, run_vmapped) if i % 2 == 0
-            else (run_vmapped, run_fused)
-        )
-        a, b = timed(first), timed(second)
-        f, v = (a, b) if i % 2 == 0 else (b, a)
-        fused_times.append(f)
-        vmapped_times.append(v)
-    fused_us = min(fused_times) * 1e6
-    vmapped_us = min(vmapped_times) * 1e6
-    speedup = float(np.median(
-        [v / f for v, f in zip(vmapped_times, fused_times)]
-    ))
-    emit(
-        f"matmat/throughput/vmapped_k{k_gate}", vmapped_us,
-        f"n={sell.n_rows};k={k_gate}",
-    )
-    emit(
-        f"matmat/throughput/fused_k{k_gate}", fused_us,
-        f"n={sell.n_rows};k={k_gate};k_tile={k_tile};"
-        f"speedup={speedup:.2f};"
-        f"predicted_speedup_pack256={predicted[str(k_gate)]}",
-    )
-
-    # Sharded engine: every shard's matmat routes through the fused kernel
-    # on its own device; the decomposition must still match the
-    # single-device reference.
-    sharded = ShardedSpMVEngine(sell, backend="pallas", k_tile=k_tile)
+    # Sharded engine: every shard's matmat runs on its own device; the
+    # decomposition must still match the single-device reference.
+    sharded = ShardedSpMVEngine(sell, backend="pallas")
     X8 = jnp.asarray(
-        rng.standard_normal((sell.n_cols, k_tile)).astype(np.float32)
+        rng.standard_normal((sell.n_cols, 8)).astype(np.float32)
     )
     err_sharded = float(np.abs(
         np.asarray(sharded.matmat(X8)) - np.asarray(ref.matmat(X8))
@@ -715,29 +637,18 @@ def _matmat_smoke() -> dict:
     d, m = sharded.n_data, sharded.n_model
     emit(
         f"matmat/sharded_mesh_{d}x{m}", 0.0,
-        f"n={sell.n_rows};k={k_tile};shards={sharded.n_shards};"
+        f"n={sell.n_rows};k=8;shards={sharded.n_shards};"
         f"max_abs_err={err_sharded:.2e}",
     )
 
     return {
-        "k_tile": k_tile,
         "ks": list(ks),
-        "trials": trials,
         "parity": parity,
         "sharded": {
             "mesh": [d, m],
             "n_shards": sharded.n_shards,
             "max_abs_err": err_sharded,
         },
-        "throughput": {
-            "k": k_gate,
-            "fused_us": round(fused_us, 1),
-            "vmapped_us": round(vmapped_us, 1),
-            "speedup": round(speedup, 3),  # median paired per-trial ratio
-            "jitter_tol": MATMAT_JITTER_TOL,
-        },
-        # model side of the amortization story: speedup(k) per pack256
-        "predicted_speedup_pack256": predicted,
     }
 
 
@@ -1311,10 +1222,9 @@ def _solve_gate(solve: dict) -> dict:
 
 
 def _matmat_gate(matmat: dict) -> dict:
-    """Fused-matmat failures, empty when clean: parity within PARITY_TOL at
-    every k, fused >= vmapped throughput at k >= k_tile within the jitter
-    tolerance, and the perf model predicting the amortization trend (NaN
-    comparisons are written to fail, as in the other gates)."""
+    """Matmat failures, empty when clean: parity within PARITY_TOL at every
+    k and on the sharded engine (NaN comparisons are written to fail, as in
+    the other gates)."""
     bad = {}
     for k, errs in matmat["parity"].items():
         for name, err in errs.items():
@@ -1322,12 +1232,6 @@ def _matmat_gate(matmat: dict) -> dict:
                 bad[f"matmat-parity-k{k}-{name}"] = err
     if not (matmat["sharded"]["max_abs_err"] <= PARITY_TOL):
         bad["matmat-sharded-parity"] = matmat["sharded"]["max_abs_err"]
-    if not (matmat["throughput"]["speedup"] * MATMAT_JITTER_TOL >= 1.0):
-        bad["matmat-throughput"] = matmat["throughput"]["speedup"]
-    pred = matmat["predicted_speedup_pack256"]
-    k_hi = str(max(matmat["ks"]))
-    if not (pred[k_hi] > 1.0 and pred[k_hi] >= pred["1"]):
-        bad["matmat-model-trend"] = pred
     return bad
 
 
@@ -1362,10 +1266,9 @@ def main() -> None:
     )
     ap.add_argument(
         "--matmat", action="store_true",
-        help="fused-vs-vmapped matmat rows through the sell_spmm kernel; "
-        "writes BENCH_matmat.json and gates parity (1e-5 at every k) + "
-        "fused>=vmapped throughput at k>=k_tile + the perf-model "
-        "amortization trend (implies ci scale)",
+        help="matmat parity rows, pallas against the reference backend; "
+        "writes BENCH_matmat.json and gates parity (1e-5 at every k and on "
+        "the sharded engine; implies ci scale)",
     )
     ap.add_argument(
         "--solve", action="store_true",
@@ -1484,11 +1387,7 @@ def main() -> None:
             }
             with open(MATMAT_JSON, "w") as f:
                 json.dump(matmat_payload, f, indent=2)
-            print(
-                f"# wrote {MATMAT_JSON} (fused speedup "
-                f"{matmat['throughput']['speedup']:.2f} at "
-                f"k={matmat['throughput']['k']})"
-            )
+            print(f"# wrote {MATMAT_JSON}")
             bad.update(_matmat_gate(matmat))
         if solve is not None:
             solve_payload = {

@@ -10,9 +10,8 @@ of two deployments built from the repository's generators:
 * HPCG: unpreconditioned CG (`core.solvers.cg`, `lax.while_loop`) on the
   27-point stencil over the HPCG reference's 104^3 local domain, b = A @ 1.
   The true residual is recomputed in f64 on the host with scipy.
-* webbase-1M: `SpMVEngine.matvec`, the fused `matmat` and `matmat_vmapped`
-  at k = 8 on the 1M-row power-law matrix, each compared with scipy's f64
-  product.
+* webbase-1M: `SpMVEngine.matvec` and `matmat` at k = 8 on the 1M-row
+  power-law matrix, each compared with scipy's f64 product.
 * ``--four-chips``: `ShardedSpMVEngine` matmat at k = 8 and matvec on
   webbase-1M over a (data=4, model=1) mesh with the cost partition; each
   shard's result must sit on its own chip and no shard may fall back to the
@@ -174,7 +173,7 @@ def webbase_phase(csr=None, *, backend: str = "auto", k: int = K) -> bool:
     if csr is None:
         csr, t_gen = _matrix("webbase-1M")
     print(f"[webbase] powerlaw n={csr.n_rows} nnz={csr.nnz}, matvec and "
-          f"fused matmat k={k}", flush=True)
+          f"matmat k={k}", flush=True)
     A = _scipy(csr)
     rng = np.random.default_rng(SEED + 1)
     x = rng.standard_normal(csr.n_cols).astype(np.float32)
@@ -182,23 +181,17 @@ def webbase_phase(csr=None, *, backend: str = "auto", k: int = K) -> bool:
     eng, t_plan, _ = _timed(lambda: get_engine(csr, backend=backend))
     counts, dt, _ = _timed(lambda: _lower(eng))
     t_plan += dt
-    print(f"  backend={eng.backend_resolved} "
-          f"matmat={eng.matmat_mode_resolved} {_geometry(eng, counts)}",
+    print(f"  backend={eng.backend_resolved} {_geometry(eng, counts)}",
           flush=True)
     y, t_mv, c_mv = _timed(lambda: np.asarray(eng.matvec(x)))
     ok = _parity("matvec", y, A @ x.astype(np.float64))
     Y_ref = A @ X.astype(np.float64)
     Y, t_mm, c_mm = _timed(lambda: np.asarray(eng.matmat(X)))
     ok &= _parity(f"matmat k={k}", Y, Y_ref)
-    Y, t_mv8, c_mv8 = _timed(lambda: np.asarray(eng.matmat_vmapped(X)))
-    ok &= _parity(f"matmat_vmapped k={k}", Y, Y_ref)
-    ok &= eng.matmat_mode_resolved == ("fused" if eng.backend_resolved ==
-                                        "pallas" else "vmapped")
     print(
         f"  seconds: matrix={t_gen:.3f} plan={t_plan:.3f} "
-        f"compile={c_mv + c_mm + c_mv8:.3f} run_matvec={t_mv - c_mv:.3f} "
-        f"run_matmat={t_mm - c_mm:.3f} "
-        f"run_matmat_vmapped={t_mv8 - c_mv8:.3f} ok={ok}", flush=True,
+        f"compile={c_mv + c_mm:.3f} run_matvec={t_mv - c_mv:.3f} "
+        f"run_matmat={t_mm - c_mm:.3f} ok={ok}", flush=True,
     )
     return bool(ok)
 

@@ -27,7 +27,6 @@ from .engine import (  # noqa: F401
     engine_cache_stats,
     get_engine,
     resolve_backend,
-    resolve_matmat_mode,
     resolve_window,
     schedule_cache_stats,
     stream_digest,

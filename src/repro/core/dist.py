@@ -63,13 +63,12 @@ import numpy as np
 from . import faults
 from .coalescer import META_BYTES_PACKED, META_BYTES_UNPACKED, \
     coalesce_stats, schedule_meta_bytes
-from .engine import DEFAULT_BUFFER_DEPTH, DEFAULT_COLS_PER_CHUNK, \
-    DEFAULT_K_TILE, DEFAULT_WINDOW, get_engine, resolve_backend, \
-    resolve_block_rows, resolve_packed, resolve_value_dtype, resolve_window
+from .engine import DEFAULT_COLS_PER_CHUNK, DEFAULT_WINDOW, get_engine, \
+    resolve_backend, resolve_block_rows, resolve_packed, \
+    resolve_value_dtype, resolve_window
 from .formats import CSRMatrix, SELLMatrix
 from .partition import resolve_partition, shard_bounds
-from .perfmodel import matmat_spmv_perf, sharded_spmv_perf, \
-    streaming_spmv_perf
+from .perfmodel import sharded_spmv_perf, streaming_spmv_perf
 from .runtime import column_groups, data_model_grid, device_put_rhs, \
     normalize_to_sell, proper_slice
 
@@ -202,13 +201,11 @@ class ShardedSpMVEngine:
     estimate so no device straggles on skewed matrices).
 
     All plan parameters (``window``, ``block_rows``, ``backend``,
-    ``cols_per_chunk``, ``k_tile``, ``matmat_mode``, ``packed``,
-    ``buffer_depth``, ``value_dtype``, ``cache_dir``) are
+    ``cols_per_chunk``, ``packed``, ``value_dtype``, ``cache_dir``) are
     forwarded to every shard's `SpMVEngine`, so backends, window resolution,
-    the fused multi-column matmat routing, the content-addressed schedule
-    cache, and npz persistence all behave exactly as on the single-device
-    engine — per shard (a pallas-backed sharded matmat streams each shard's
-    schedule and values once per `k_tile` RHS columns on its own device).
+    the content-addressed schedule cache, and npz persistence all behave
+    exactly as on the single-device engine — per shard (a shard's matmat is
+    its engine's, on its own device).
     """
 
     def __init__(
@@ -223,10 +220,7 @@ class ShardedSpMVEngine:
         width_multiple: int = 1,
         backend: str = "auto",
         cols_per_chunk: int = DEFAULT_COLS_PER_CHUNK,
-        k_tile: int = DEFAULT_K_TILE,
-        matmat_mode: str = "auto",
         packed: Union[bool, str] = "auto",
-        buffer_depth: int = DEFAULT_BUFFER_DEPTH,
         value_dtype: Optional[str] = None,
         partition: str = "auto",
         cache_dir: Optional[str] = None,
@@ -285,10 +279,7 @@ class ShardedSpMVEngine:
                 block_rows=self.block_rows,
                 backend=backend,
                 cols_per_chunk=cols_per_chunk,
-                k_tile=k_tile,
-                matmat_mode=matmat_mode,
                 packed=packed,
-                buffer_depth=buffer_depth,
                 value_dtype=value_dtype,
                 cache_dir=cache_dir,
             )
@@ -554,7 +545,6 @@ class ShardedSpMVEngine:
 
     def plan_report(
         self, *, stream: Optional[Dict[str, int]] = None,
-        k: Optional[int] = None,
     ) -> Dict[str, object]:
         """Aggregate plan report plus per-shard coalesce stats.
 
@@ -564,8 +554,7 @@ class ShardedSpMVEngine:
         the per-memory-bank view of the paper's Sec. II-B statistics.
         ``stream={"k": ..., "microbatch": ..., "depth": ...}`` adds the perf
         model's streamed-throughput prediction for the whole matrix under
-        ``streaming``; ``k=`` adds the whole-matrix matmat amortization
-        prediction under ``matmat`` (see `SpMVEngine.plan_report`).
+        ``streaming``.
         """
         shard_reports: List[Dict[str, object]] = []
         total_wide = 0
@@ -613,21 +602,6 @@ class ShardedSpMVEngine:
                     for system in ("base", "pack256")
                 },
             }
-        matmat = None
-        if k is not None:
-            k_tile = self.engines[0].k_tile
-            matmat = {
-                "k": int(k),
-                "k_tile": k_tile,
-                "mode": self.engines[0].matmat_mode_resolved,
-                "perf": {
-                    system: dataclasses.asdict(
-                        matmat_spmv_perf(self.sell, system, k=int(k),
-                                         k_tile=k_tile)
-                    )
-                    for system in ("pack0", "pack256")
-                },
-            }
         # Straggler-bound sharded prediction over the *actual* shard
         # matrices (their own padded widths): max over per-shard cycles plus
         # the x broadcast — and the imbalance metric the partitioner
@@ -663,5 +637,4 @@ class ShardedSpMVEngine:
             "recovery": self.recovery_report(),
             "shards": shard_reports,
             **({"streaming": streaming} if streaming is not None else {}),
-            **({"matmat": matmat} if matmat is not None else {}),
         }

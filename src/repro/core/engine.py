@@ -13,12 +13,10 @@ module makes the plan a first-class, cached object:
   * `SpMVEngine` — owns one matrix (CSR is converted to SELL up front,
     validated), one schedule, and jit-compiled `matvec(x)` / batched
     `matmat(X)` closures that reuse the schedule across thousands of
-    right-hand sides. On the pallas backend `matmat` routes through the
-    fused multi-column kernel (`kernels.sell_spmm`): the schedule metadata
-    and SELL values stream once per `k_tile` RHS columns instead of once per
-    column. `matmat_vmapped` keeps the per-column baseline (`vmap` of
-    matvec) compiled alongside it — the reference the fused path is gated
-    against — and the reference backend always executes it.
+    right-hand sides. On the pallas backend `matmat` is the matvec batched
+    over RHS columns (`vmap`), one kernel product per column; the reference
+    backend runs a direct 2-D schedule gather, bit-identical per column to
+    its matvec.
   * Execution backends — ``backend="reference" | "pallas" | "auto"``. The
     reference backend executes the jnp schedule-gather oracle; the pallas
     backend runs the fused `kernels.sell_spmv` kernel (natively on TPU,
@@ -73,7 +71,7 @@ from .coalescer import BlockSchedule, META_BYTES_PACKED, \
     packable_schedule, schedule_gather_reference, schedule_meta_bytes, \
     trim_schedule_warps
 from .formats import CSRMatrix, SELLMatrix
-from .perfmodel import DEFAULT_HW, HWConfig, matmat_spmv_perf, spmv_perf, \
+from .perfmodel import DEFAULT_HW, HWConfig, spmv_perf, \
     streaming_spmv_perf
 from .runtime import device_put_rhs, normalize_to_sell, pad_width
 from .spans import span
@@ -82,14 +80,9 @@ BACKENDS = ("reference", "pallas", "auto")
 BACKEND_ENV = "REPRO_BACKEND"
 DEFAULT_WINDOW = 256
 DEFAULT_COLS_PER_CHUNK = 8
-DEFAULT_K_TILE = 8
 # Coalescing granularity wherever x is not held in VMEM lane rows (see
 # `resolve_block_rows`).
 DEFAULT_BLOCK_ROWS = 8
-# Kernel-pipeline default; must match kernels.sell_spmv.DEFAULT_BUFFER_DEPTH
-# (core stays importable before the kernels package, so no import here).
-DEFAULT_BUFFER_DEPTH = 2
-MATMAT_MODES = ("fused", "vmapped", "auto")
 PACKED_CHOICES = (True, False, "auto")
 # SELL value-storage dtypes. "native" (== None) streams values at the input
 # dtype; "bf16"/"f32" store the value stream narrower and accumulate at the
@@ -261,28 +254,6 @@ def resolve_block_rows(
             return LANES
     return DEFAULT_BLOCK_ROWS
 
-
-def resolve_matmat_mode(mode: str, backend_resolved: str) -> str:
-    """``"auto"`` routes `matmat` onto the fused multi-column kernel
-    (`kernels.sell_spmm`) on the pallas backend — one pass over the schedule
-    and the SELL values per `k_tile` RHS columns — and onto the vmapped
-    matvec elsewhere (the reference backend has no fused kernel to run; its
-    vmapped path *is* the per-column oracle). ``"vmapped"`` keeps the
-    per-column path on any backend (the fallback/baseline the fused kernel
-    is gated against); ``"fused"`` demands the fused kernel and raises off
-    the pallas backend rather than silently degrading."""
-    if mode not in MATMAT_MODES:
-        raise ValueError(
-            f"matmat_mode must be one of {MATMAT_MODES}, got {mode!r}"
-        )
-    if mode == "auto":
-        return "fused" if backend_resolved == "pallas" else "vmapped"
-    if mode == "fused" and backend_resolved != "pallas":
-        raise ValueError(
-            f"matmat_mode='fused' requires the pallas backend (the fused "
-            f"sell_spmm kernel); backend resolved to {backend_resolved!r}"
-        )
-    return mode
 
 # ---------------------------------------------------------------------------
 # Content-addressed schedule cache
@@ -631,13 +602,9 @@ class SpMVEngine:
     the `BlockSchedule` is built, so the content-addressed cache keys on the
     exact stream and geometry the kernel executes.
 
-    ``k_tile`` sets the fused matmat kernel's RHS tile width (pallas only):
-    one pass over the schedule and the SELL values serves `k_tile` columns.
-    ``matmat_mode`` routes `matmat` — ``"auto"`` (fused on pallas, vmapped
-    elsewhere), ``"vmapped"`` (per-column baseline everywhere), ``"fused"``
-    (demand the fused kernel; raises off pallas). `core.tune.autotune`
-    searches (`cols_per_chunk`, `block_rows`, `k_tile`) for a matrix and
-    feeds the winners back through `get_engine`.
+    `core.tune.autotune` searches (`cols_per_chunk`, `block_rows`,
+    `packed`, `value_dtype`) for a matrix and feeds the winners back
+    through `get_engine`.
 
     ``plan_width_multiple`` overrides the plan-level width padding (default:
     `cols_per_chunk` for the pallas backend, 1 for the reference backend).
@@ -667,10 +634,7 @@ class SpMVEngine:
         width_multiple: int = 1,
         backend: str = "auto",
         cols_per_chunk: int = DEFAULT_COLS_PER_CHUNK,
-        k_tile: int = DEFAULT_K_TILE,
-        matmat_mode: str = "auto",
         packed: Union[bool, str] = "auto",
-        buffer_depth: int = DEFAULT_BUFFER_DEPTH,
         value_dtype: Optional[str] = None,
         plan_width_multiple: Optional[int] = None,
         cache_dir: Optional[str] = None,
@@ -690,23 +654,11 @@ class SpMVEngine:
         self.cols_per_chunk = int(cols_per_chunk)
         if self.cols_per_chunk < 1:
             raise ValueError(f"cols_per_chunk must be >= 1, got {cols_per_chunk}")
-        self.k_tile = int(k_tile)
-        if self.k_tile < 1:
-            raise ValueError(f"k_tile must be >= 1, got {k_tile}")
         if packed not in PACKED_CHOICES:
             raise ValueError(
                 f"packed must be one of {PACKED_CHOICES}, got {packed!r}"
             )
         self.packed = packed  # as requested; resolved against the schedule
-        self.buffer_depth = int(buffer_depth)
-        if self.buffer_depth < 1:
-            raise ValueError(
-                f"buffer_depth must be >= 1, got {buffer_depth}"
-            )
-        self.matmat_mode = matmat_mode  # as requested
-        self.matmat_mode_resolved = resolve_matmat_mode(
-            matmat_mode, self.backend_resolved
-        )
         self.cache_dir = schedule_store.resolve_cache_dir(cache_dir)
 
         self.window = resolve_window(
@@ -743,7 +695,6 @@ class SpMVEngine:
         self._operands = {}
         self._matvec = None
         self._matmat = None
-        self._matmat_vmapped = None
 
     # -- planning ----------------------------------------------------------
 
@@ -843,8 +794,6 @@ class SpMVEngine:
             n_rows, n_out = sell.n_rows, stream.shape[0]
             # Named after what they run: a profile's module line reads
             # jit_engine_matvec, jit_engine_matmat and so on.
-            engine_matmat = None
-            engine_matmat_ref = None
             # Narrow value storage: cast the hoisted value plan once per
             # trace; the multiply promotes back to the RHS dtype (f32
             # accumulation for bf16 values).
@@ -853,21 +802,17 @@ class SpMVEngine:
             if self.backend_resolved == "pallas":
                 cpc = self.cols_per_chunk
                 block_rows = self.block_rows
-                kt = self.k_tile
-                depth = self.buffer_depth
                 with span("planner.lower") as counts:
                     # Locals to the kernels package are lazy: core must stay
                     # importable before kernels (which itself imports core).
                     # The first engine of a process pays the import here.
                     from repro.kernels.ops import resolve_interpret
-                    from repro.kernels.sell_spmm import sell_spmm_pallas
                     from repro.kernels.sell_spmv import build_device_plan, \
-                        chunk_row_plan, device_operands, grid_steps, \
-                        lane_gathers, sell_spmv_pallas
+                        device_operands, grid_steps, lane_gathers, \
+                        sell_spmv_pallas
 
                     # Lower the schedule to the kernel-ready device plan
-                    # exactly once; the matvec and the fused matmat kernels
-                    # share it. The schedule already encodes every gather,
+                    # exactly once; matvec and matmat share it. The schedule already encodes every gather,
                     # so the column-index array is never shipped into a
                     # kernel call (colidx=None). `packed` resolves here
                     # against the real schedule geometry (auto: one int32
@@ -905,29 +850,21 @@ class SpMVEngine:
                         cols_per_chunk=cpc,
                         block_rows=block_rows,
                         plan=plan,
-                        buffer_depth=depth,
                         interpret=interpret,
                     )
                     return y[:n_rows]
 
-                if self.matmat_mode_resolved == "fused":
+                def engine_matmat(ops, X: jnp.ndarray) -> jnp.ndarray:
+                    if X.shape[1] == 0:  # no columns, no kernel launch
+                        return jnp.zeros((n_rows, 0), jnp.promote_types(
+                            vdt if vdt is not None else X.dtype, X.dtype))
+                    # One kernel product per column: on a lane-dense plan
+                    # the resident kernel's `sequential_vmap` loops over
+                    # them.
+                    return jax.vmap(engine_matvec, in_axes=(None, 1),
+                                    out_axes=1)(ops, X)
 
-                    def engine_matmat(ops, X: jnp.ndarray) -> jnp.ndarray:
-                        # sell_spmm streams chunk rows: on a lane-dense plan
-                        # each call relays the stream out into them.
-                        va, plan = ops
-                        Y = sell_spmm_pallas(
-                            None,
-                            _values(va, X.dtype),
-                            X,
-                            cols_per_chunk=cpc,
-                            block_rows=block_rows,
-                            k_tile=kt,
-                            plan=chunk_row_plan(plan),
-                            buffer_depth=depth,
-                            interpret=interpret,
-                        )
-                        return Y[:n_rows]
+                matmat = engine_matmat
 
             else:
                 with span("planner.lower"):
@@ -965,19 +902,11 @@ class SpMVEngine:
                     y = _width_tree_sum(va[..., None] * g, _runtime_one(X))
                     return y.reshape(-1, k)[:n_rows]
 
+                matmat = engine_matmat_ref
+
             self._operands = {None: operands}
             self._matvec = jax.jit(engine_matvec)
-            self._matmat_vmapped = (
-                jax.jit(engine_matmat_ref) if engine_matmat is None
-                and engine_matmat_ref is not None
-                else jax.jit(
-                    jax.vmap(engine_matvec, in_axes=(None, 1), out_axes=1)
-                )
-            )
-            self._matmat = (
-                jax.jit(engine_matmat) if engine_matmat is not None
-                else self._matmat_vmapped
-            )
+            self._matmat = jax.jit(matmat)
         return self._matvec, self._matmat
 
     # -- execution ---------------------------------------------------------
@@ -1031,14 +960,9 @@ class SpMVEngine:
     def matmat(self, X: jnp.ndarray) -> jnp.ndarray:
         """Y = A @ X for X: (n_cols, k) — one schedule shared by all k.
 
-        On the pallas backend this routes through the fused multi-column
-        kernel (`kernels.sell_spmm`) by default: the schedule metadata and
-        the SELL values stream once per `k_tile` columns instead of once per
-        column, and each coalesced wide fetch grabs a ``(block_rows,
-        k_tile)`` tile of X (within 1e-5 per column of `matvec` — summation
-        order differs inside the MXU tile). The reference backend (and
-        ``matmat_mode="vmapped"``) runs `matmat_vmapped`, which is
-        bit-identical per column to `matvec`."""
+        Column j is bit-identical to ``matvec(X[:, j])`` on either backend:
+        pallas runs the matvec batched over the columns, the reference
+        backend one 2-D schedule gather with the matvec's folds."""
         X = jnp.asarray(X)
         if X.ndim != 2 or X.shape[0] != self.sell.n_cols:
             raise ValueError(
@@ -1047,20 +971,6 @@ class SpMVEngine:
         _, mm = self._ensure_compiled()
         with span("engine.matmat"):
             return mm(self._operands_for(X), X)
-
-    def matmat_vmapped(self, X: jnp.ndarray) -> jnp.ndarray:
-        """The per-column baseline: `matvec` vmapped over RHS columns (one
-        kernel pass per column, bit-identical per column to `matvec`). Kept
-        compiled alongside the fused path on every backend — it is the
-        reference the fused kernel is parity- and throughput-gated against
-        (`benchmarks/run.py --matmat`)."""
-        X = jnp.asarray(X)
-        if X.ndim != 2 or X.shape[0] != self.sell.n_cols:
-            raise ValueError(
-                f"matmat expects X of shape ({self.sell.n_cols}, k), got {X.shape}"
-            )
-        self._ensure_compiled()
-        return self._matmat_vmapped(self._operands_for(X), X)
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         return self.matvec(x) if jnp.asarray(x).ndim == 1 else self.matmat(x)
@@ -1102,7 +1012,6 @@ class SpMVEngine:
         hw: HWConfig = DEFAULT_HW,
         *,
         stream: Optional[Dict[str, int]] = None,
-        k: Optional[int] = None,
     ) -> Dict[str, object]:
         """The plan, inspectable: stream/coalescer stats + model predictions.
         Forces planning (this reports on the actual plan, not an estimate).
@@ -1110,11 +1019,7 @@ class SpMVEngine:
         model's streamed-throughput prediction (transfer/compute overlap —
         `perfmodel.streaming_spmv_perf`) under ``streaming``; wrapping the
         engine in `runtime.StreamingExecutor` and calling its `plan_report`
-        fills these in from the live pipeline shape. ``k=`` adds the matmat
-        amortization prediction under ``matmat`` — fused vs vmapped cycles
-        for a k-column RHS at this plan's `k_tile`
-        (`perfmodel.matmat_spmv_perf`), the model side of the measured
-        comparison `benchmarks/run.py --matmat` gates."""
+        fills these in from the live pipeline shape."""
         sched = self.schedule
         _, _, plan_stream, W, W_plan = self._ensure_plan()
         wide, rate = coalesce_stats(
@@ -1130,8 +1035,6 @@ class SpMVEngine:
             "backend": self.backend,
             "backend_resolved": self.backend_resolved,
             "cols_per_chunk": self.cols_per_chunk,
-            "k_tile": self.k_tile,
-            "matmat_mode": self.matmat_mode_resolved,
             "window": self.window,
             "block_rows": self.block_rows,
             "n_windows": sched.n_windows,
@@ -1172,7 +1075,6 @@ class SpMVEngine:
                 "requested": self.packed,
                 "packed": packed_resolved,
                 "packable": packable_schedule(sched),
-                "buffer_depth": self.buffer_depth,
                 "meta_bytes_per_element": (
                     META_BYTES_PACKED if packed_resolved
                     else META_BYTES_UNPACKED
@@ -1220,21 +1122,6 @@ class SpMVEngine:
                     for system in ("base", "pack256")
                 },
             }
-        if k is not None:
-            report["matmat"] = {
-                "k": int(k),
-                "k_tile": self.k_tile,
-                "mode": self.matmat_mode_resolved,
-                "perf": {
-                    system: dataclasses.asdict(
-                        matmat_spmv_perf(
-                            self.sell, system, k=int(k), k_tile=self.k_tile,
-                            hw=hw,
-                        )
-                    )
-                    for system in ("pack0", "pack256")
-                },
-            }
         return report
 
 
@@ -1248,10 +1135,7 @@ def get_engine(
     width_multiple: int = 1,
     backend: str = "auto",
     cols_per_chunk: int = DEFAULT_COLS_PER_CHUNK,
-    k_tile: int = DEFAULT_K_TILE,
-    matmat_mode: str = "auto",
     packed: Union[bool, str] = "auto",
-    buffer_depth: int = DEFAULT_BUFFER_DEPTH,
     value_dtype: Optional[str] = None,
     cache_dir: Optional[str] = None,
 ) -> SpMVEngine:
@@ -1259,15 +1143,15 @@ def get_engine(
     therefore same compiled matvec/matmat). CSR inputs are keyed on the SELL
     they convert to, so CSR and its converted SELL share an engine. The key
     includes the *resolved* backend, the *resolved* window, the *resolved*
-    `block_rows` and the *resolved* matmat mode — exactly the resolution
+    `block_rows` — exactly the resolution
     `SpMVEngine.__init__` performs, so ``window=None`` and its explicit
     spelling (256 for reference, `cols_per_chunk * slice_height` for
     pallas), and ``block_rows=None`` and the value it resolves to
     (`resolve_block_rows`), share one engine instead of duplicating
     schedules and jit compiles — and, for pallas,
-    `cols_per_chunk`, `k_tile`, `packed`, and `buffer_depth`, which shape its
-    plan encoding and its executables (the reference backend ignores them
-    all, so they stay out of its key). `packed` is keyed on the *requested*
+    `cols_per_chunk` and `packed`, which shape its plan encoding and its
+    executables (the reference backend ignores both, so they stay out of its
+    key). `packed` is keyed on the *requested*
     spelling: ``"auto"`` and an explicit ``True`` may lower to the same
     encoding, but resolving it needs the schedule — too expensive for a
     cache lookup. `cache_dir` is not part of the key — it changes where a
@@ -1279,7 +1163,6 @@ def get_engine(
             validate=False,  # O(nnz) scan deferred to construction on a miss
         )
     resolved = resolve_backend(backend)
-    mode_resolved = resolve_matmat_mode(matmat_mode, resolved)
     if packed not in PACKED_CHOICES:
         raise ValueError(
             f"packed must be one of {PACKED_CHOICES}, got {packed!r}"
@@ -1303,17 +1186,7 @@ def get_engine(
         # Value storage changes numerics on every backend, so it keys both
         # ("native" and None share the engine — same resolution as __init__).
         storage,
-        # k_tile only shapes the *fused* executable; a vmapped pallas engine
-        # ignores it, so resolved-identical configurations share one engine
-        # (the same rule that keeps cols_per_chunk out of reference keys).
-        (
-            cols_per_chunk,
-            k_tile if mode_resolved == "fused" else None,
-            mode_resolved,
-            packed,
-            int(buffer_depth),
-        )
-        if resolved == "pallas" else None,
+        (cols_per_chunk, packed) if resolved == "pallas" else None,
     )
     adopted = None
     with _engine_lock:
@@ -1325,10 +1198,7 @@ def get_engine(
                 block_rows=block_rows,
                 backend=backend,
                 cols_per_chunk=cols_per_chunk,
-                k_tile=k_tile,
-                matmat_mode=matmat_mode,
                 packed=packed,
-                buffer_depth=buffer_depth,
                 value_dtype=value_dtype,
                 cache_dir=cache_dir,
             )

@@ -752,14 +752,10 @@ class StreamingExecutor:
         """The wrapped executor's plan report with the perf model's overlap
         prediction for this pipeline shape filled in under ``streaming``
         (`perfmodel.streaming_spmv_perf` — the transfer/compute overlap
-        term). `k` defaults to one full in-flight window. The report's
-        ``matmat`` section is evaluated at the *micro-batch* width — every
-        dispatch through this pipeline is one `microbatch`-column matmat, so
-        that is the batch the fused kernel's amortization actually sees."""
+        term). `k` defaults to one full in-flight window."""
         stream = {
             "k": self.depth * self.microbatch if k is None else int(k),
             "microbatch": self.microbatch,
             "depth": self.depth,
         }
-        kwargs.setdefault("k", self.microbatch)
         return self.executor.plan_report(stream=stream, **kwargs)

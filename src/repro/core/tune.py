@@ -1,20 +1,19 @@
-"""Plan autotuner: search (cols_per_chunk, block_rows, k_tile, packed,
-buffer_depth, value_dtype) per matrix.
+"""Plan autotuner: search (cols_per_chunk, block_rows, packed, value_dtype)
+per matrix.
 
-The pallas plan has three coupled knobs and no hand-pickable sweet spot:
+The pallas plan has coupled knobs and no hand-pickable sweet spot:
 `cols_per_chunk` sets both the coalescing window (``cols_per_chunk *
-slice_height``) *and* the width padding the plan pays for, `block_rows` sets
-the wide-fetch granularity (wider blocks coalesce more but waste bytes on
-sparse hits), and the fused matmat kernel (`kernels.sell_spmm`) adds
-`k_tile` — the RHS tile width that trades matrix-stream amortization against
-padding compute at awkward k. This module searches the cross product per
-matrix and remembers the winner:
+slice_height``) *and* the width padding the plan pays for, and `block_rows`
+sets the wide-fetch granularity (wider blocks coalesce more but waste bytes
+on sparse hits). This module searches the cross product per matrix and
+remembers the winner:
 
   * ``mode="model"`` (default) scores every candidate with
-    `perfmodel.plan_matmat_cycles` — the fused-matmat cycle model evaluated
-    on the candidate's *own* plan geometry (its padded stream, its window,
-    its block granularity). Pure numpy on the index stream: no compilation,
-    no device, deterministic.
+    `perfmodel.plan_matmat_cycles` at a one-column tile — k single-column
+    passes, as `SpMVEngine.matmat` runs them — evaluated on the candidate's
+    *own* plan geometry (its padded stream, its window, its block
+    granularity). Pure numpy on the index stream: no compilation, no
+    device, deterministic.
   * ``mode="measure"`` builds each candidate engine through `get_engine`
     (so trial engines land in the engine cache warm) and times real
     ``matmat`` calls, interleaved round-robin across candidates so shared-
@@ -52,14 +51,13 @@ from .perfmodel import DEFAULT_HW, HWConfig, plan_matmat_cycles
 from .runtime import normalize_to_sell, pad_width
 
 TUNE_CACHE_ENV = "REPRO_TUNE_CACHE"
-TUNE_VERSION = 3  # v3: value_dtype joined the space (v2: packed +
-# buffer_depth); earlier winners answer a smaller question and are
-# deliberately re-searched
+TUNE_VERSION = 4  # v4: the RHS tile and the pipeline depth left the
+# space (v3: value_dtype joined it; v2: packed and the depth); winners of
+# another question are deliberately re-searched
 
 # The search space: every combination is a legal plan (cols_per_chunk widens
 # the window and the width padding together; block_rows is the wide-fetch
-# granularity; k_tile the fused RHS tile; packed toggles the 4-byte metadata
-# encoding; buffer_depth the manual VMEM pipeline depth; value_dtype the
+# granularity; packed toggles the 4-byte metadata encoding; value_dtype the
 # SELL value storage width — bf16 halves the value stream at a numerics
 # cost the caller owns). Deliberately small — the tuner is rerun per
 # matrix, and the persisted winner makes even the model-mode search a
@@ -67,9 +65,7 @@ TUNE_VERSION = 3  # v3: value_dtype joined the space (v2: packed +
 DEFAULT_SPACE: Dict[str, Tuple] = {
     "cols_per_chunk": (4, 8, 16),
     "block_rows": (4, 8, 16),
-    "k_tile": (4, 8, 16),
     "packed": (0, 1),
-    "buffer_depth": (1, 2),
     "value_dtype": ("native", "bf16"),
 }
 TUNE_MODES = ("model", "measure")
@@ -84,9 +80,7 @@ class TunedPlan:
 
     cols_per_chunk: int
     block_rows: int
-    k_tile: int
     packed: int  # 0 | 1 — int (not bool) so the space/JSON stay uniform
-    buffer_depth: int
     value_dtype: str  # 'native' | 'bf16' | 'f32' (engine.VALUE_DTYPES)
     k: int
     backend: str  # resolved
@@ -265,9 +259,7 @@ def _load(
         plan = TunedPlan(
             cols_per_chunk=int(w["cols_per_chunk"]),
             block_rows=int(w["block_rows"]),
-            k_tile=int(w["k_tile"]),
             packed=int(w["packed"]),
-            buffer_depth=int(w["buffer_depth"]),
             value_dtype=str(w["value_dtype"]),
             k=int(w["k"]),
             backend=str(w["backend"]),
@@ -279,9 +271,7 @@ def _load(
         if (
             plan.cols_per_chunk not in space["cols_per_chunk"]
             or plan.block_rows not in space["block_rows"]
-            or plan.k_tile not in space["k_tile"]
             or plan.packed not in space["packed"]
-            or plan.buffer_depth not in space["buffer_depth"]
             or plan.value_dtype not in space["value_dtype"]
             or plan.k != int(k)
             or plan.backend != backend
@@ -310,8 +300,8 @@ def _model_search(
     k: int,
     hw: HWConfig,
 ) -> Tuple[Dict[str, int], float, int]:
-    """Score every candidate with the fused-matmat cycle model on its own
-    plan geometry. The width-padded stream is shared across candidates with
+    """Score every candidate with the matmat cycle model on its own plan
+    geometry. The width-padded stream is shared across candidates with
     the same cols_per_chunk (padding is the only cpc-dependent part)."""
     from .spmv import _sell_padded  # local: spmv routes via engine
 
@@ -330,14 +320,16 @@ def _model_search(
             n_rows=sell.n_rows,
             n_slices=sell.n_slices,
             k=k,
-            k_tile=cand["k_tile"],
+            # One column per pass through the BlockSpec pipeline (depth 1),
+            # as the engine's matmat runs the per-warp grid.
+            k_tile=1,
             window=cpc * H,
             block_rows=cand["block_rows"],
             hw=hw,
             meta_bytes_per_elem=(
                 META_BYTES_PACKED if cand["packed"] else META_BYTES_UNPACKED
             ),
-            buffer_depth=cand["buffer_depth"],
+            buffer_depth=1,
             value_bytes_per_elem=value_bytes_per_elem(
                 cand["value_dtype"], hw=hw
             ),
@@ -375,9 +367,7 @@ def _measure_search(
             backend=backend,
             cols_per_chunk=cand["cols_per_chunk"],
             block_rows=cand["block_rows"],
-            k_tile=cand["k_tile"],
             packed=bool(cand["packed"]),
-            buffer_depth=cand["buffer_depth"],
             value_dtype=resolve_value_dtype(cand["value_dtype"]),
         ))
     for eng in engines:  # compile + first-touch outside the timed rounds
@@ -408,8 +398,8 @@ def autotune(
     cache_dir: Optional[str] = None,
     hw: HWConfig = DEFAULT_HW,
 ) -> TunedPlan:
-    """Find (cols_per_chunk, block_rows, k_tile, packed, buffer_depth,
-    value_dtype) for serving k-column matmats on this matrix. Returns the cached winner when one exists —
+    """Find (cols_per_chunk, block_rows, packed, value_dtype) for serving
+    k-column matmats on this matrix. Returns the cached winner when one exists —
     in-memory first, then the persistent store — running zero trials; only
     a genuinely new (matrix, k, backend, mode, space) combination searches.
     """
@@ -457,9 +447,7 @@ def autotune(
     plan = TunedPlan(
         cols_per_chunk=winner["cols_per_chunk"],
         block_rows=winner["block_rows"],
-        k_tile=winner["k_tile"],
         packed=winner["packed"],
-        buffer_depth=winner["buffer_depth"],
         value_dtype=winner["value_dtype"],
         k=int(k),
         backend=resolved,
@@ -504,9 +492,7 @@ def get_tuned_engine(
         backend=backend,
         cols_per_chunk=plan.cols_per_chunk,
         block_rows=plan.block_rows,
-        k_tile=plan.k_tile,
         packed=bool(plan.packed),
-        buffer_depth=plan.buffer_depth,
         value_dtype=resolve_value_dtype(plan.value_dtype),
         slice_height=slice_height,
         cache_dir=cache_dir,

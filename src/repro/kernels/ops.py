@@ -12,8 +12,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .coalesced_gather import coalesced_gather_pallas
-from .sell_spmm import sell_spmm_pallas
-from .sell_spmv import DEFAULT_BUFFER_DEPTH, sell_spmv_pallas
+from .sell_spmv import sell_spmv_pallas
 
 
 def resolve_interpret(interpret: bool | None = None) -> bool:
@@ -66,7 +65,6 @@ def sell_spmv(
     schedule=None,
     plan=None,
     packed: bool | str | None = None,
-    buffer_depth: int = DEFAULT_BUFFER_DEPTH,
     backend: str = "pallas",
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -82,42 +80,6 @@ def sell_spmv(
         schedule=schedule,
         plan=plan,
         packed=packed,
-        buffer_depth=buffer_depth,
         interpret=resolve_interpret(interpret),
     )
 
-
-def sell_spmm(
-    colidx: jnp.ndarray,
-    values: jnp.ndarray,
-    X: jnp.ndarray,
-    *,
-    cols_per_chunk: int = 8,
-    block_rows: int = 8,
-    k_tile: int = 8,
-    max_warps: int | None = None,
-    schedule=None,
-    plan=None,
-    packed: bool | str | None = None,
-    buffer_depth: int = DEFAULT_BUFFER_DEPTH,
-    backend: str = "pallas",
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Fused multi-column SELL SpMM: one pass over the schedule and the SELL
-    values per `k_tile` RHS columns (kernels.sell_spmm)."""
-    if backend == "jnp":
-        return ref.sell_spmm_ref(colidx, values, X)
-    return sell_spmm_pallas(
-        colidx,
-        values,
-        X,
-        cols_per_chunk=cols_per_chunk,
-        block_rows=block_rows,
-        k_tile=k_tile,
-        max_warps=max_warps,
-        schedule=schedule,
-        plan=plan,
-        packed=packed,
-        buffer_depth=buffer_depth,
-        interpret=resolve_interpret(interpret),
-    )

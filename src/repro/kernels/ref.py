@@ -23,14 +23,3 @@ def sell_spmv_ref(
     y = jnp.sum(values * x[colidx], axis=1)  # (n_slices, H)
     return y.reshape(-1)
 
-
-def sell_spmm_ref(
-    colidx: jnp.ndarray,  # (n_slices, W, H) int32
-    values: jnp.ndarray,  # (n_slices, W, H)
-    X: jnp.ndarray,  # (n_cols, k)
-) -> jnp.ndarray:
-    """Padded SELL SpMM: Y[s*H + h, j] = sum_w values[s, w, h] * X[colidx[
-    s, w, h], j]. Returns (n_slices * H, k) — column j equals
-    ``sell_spmv_ref(colidx, values, X[:, j])``."""
-    y = jnp.sum(values[..., None] * X[colidx], axis=1)  # (n_slices, H, k)
-    return y.reshape(-1, X.shape[1])

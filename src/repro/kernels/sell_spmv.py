@@ -28,33 +28,22 @@ array dims (the TPU's (8, 128) block-shape rule); a chunk's window element
 the SENTINEL-sanitized tag matrix plus the per-(slice, chunk) metadata words.
 Building it per call would re-trace that preprocessing into every jit (and
 re-upload it per trace), so plan-owning callers (`core.engine.SpMVEngine`)
-build it **once** and share it between the matvec kernel here and the fused
-matmat kernel (`kernels.sell_spmm`). With a prebuilt plan the column-index
+build it **once** and share it between their matvec and matmat (the matvec
+batched over right-hand sides). With a prebuilt plan the column-index
 array itself is dead weight — the schedule already encodes every gather — so
 `colidx` may be None and stays off the transfer path entirely.
 
-Two bandwidth levers live here (the ROADMAP "bandwidth roofline push"):
-
-* **Packed metadata.** The per-element (warp id, row offset) pair is the
-  kernel's indirect stream. Both values are small — `elem_warp <
-  max_warps` and `elem_offset < block_rows`, each comfortably under 2**16
-  for every practical geometry — so `build_device_plan(packed=...)` packs
-  them into a single int32 word ``(warp << 16) | offset`` per trace
-  element: 4 metadata bytes/element instead of 8, the AXI-Pack move of
-  narrowing the irregular stream to its information content. A lossless
-  unpacked fallback (two stacked int32 lanes) is selected automatically
-  when the geometry overflows the 16-bit halves; the choice is recorded
-  on the plan (`DevicePlan.packed`) and surfaced by
-  `SpMVEngine.plan_report()["metadata"]`.
-
-* **Double-buffered chunk pipelining.** With ``buffer_depth >= 2`` the
-  per-warp grids (the matvec's where x is not resident, and
-  `kernels.sell_spmm`'s) stream SELL values + metadata through a rotating
-  VMEM scratch with explicit async copies: while chunk g computes out of
-  slot ``g % depth``, the DMA for chunk ``g + depth - 1`` fills the next
-  slot — the in-kernel analog of the host-side `StreamingExecutor`
-  pipeline (and of the paper's prefetch-overlaps-compute VPC timing).
-  ``buffer_depth=1`` keeps the classic BlockSpec-pipelined path.
+**Packed metadata** is the bandwidth lever that lives here. The
+per-element (warp id, row offset) pair is the kernel's indirect stream.
+Both values are small — `elem_warp < max_warps` and `elem_offset <
+block_rows`, each comfortably under 2**16 for every practical geometry — so
+`build_device_plan(packed=...)` packs them into a single int32 word
+``(warp << 16) | offset`` per trace element: 4 metadata bytes/element
+instead of 8, the AXI-Pack move of narrowing the irregular stream to its
+information content. A lossless unpacked fallback (two stacked int32 lanes)
+is selected automatically when the geometry overflows the 16-bit halves;
+the choice is recorded on the plan (`DevicePlan.packed`) and surfaced by
+`SpMVEngine.plan_report()["metadata"]`.
 """
 from __future__ import annotations
 
@@ -80,16 +69,11 @@ from repro.core.coalescer import (
 #: `pallas_call` name and the innermost `jax.named_scope` around its call.
 KERNEL_NAME = "sell_spmv"
 
-#: Default VMEM pipeline depth for both SELL kernels: double buffering.
-DEFAULT_BUFFER_DEPTH = 2
-
-#: Upper bound on the manual VMEM pipeline depth (slots are real VMEM).
-MAX_BUFFER_DEPTH = 8
-
 
 @dataclasses.dataclass
 class DevicePlan:
-    """Kernel-ready coalescer plan: what both SELL kernels actually consume.
+    """Kernel-ready coalescer plan: what both paths of the SELL kernel
+    actually consume.
 
     tags:      (n_windows, max_warps) int32 — per-window wide-block ids with
                SENTINEL slots remapped to 0 (a SENTINEL tag is never hit by
@@ -107,9 +91,9 @@ class DevicePlan:
                                the lossless fallback for geometries whose
                                warp ids or offsets overflow 16 bits).
                The x-resident matvec streams the same words lane-dense,
-               as (n_windows * rows * window / 128, 128) (`lane_dense_plan`);
-               `chunk_row_plan` turns them back. The layout a plan holds
-               picks the matvec's path (`lane_dense`).
+               as (n_windows * rows * window / 128, 128) (`lane_dense_plan`).
+               The layout a plan holds picks the matvec's path
+               (`lane_dense`).
 
     `elem_warp` / `elem_offset` remain available as decoding properties, so
     schedule-level invariants can be asserted against either encoding.
@@ -203,8 +187,8 @@ def build_device_plan(
     slice_height: int,
     packed: bool | str = "auto",
 ) -> DevicePlan:
-    """Lower a `BlockSchedule` to the device-resident `DevicePlan` both SELL
-    kernels consume. Validates that the schedule was built for exactly this
+    """Lower a `BlockSchedule` to the device-resident `DevicePlan` the SELL
+    kernel consumes. Validates that the schedule was built for exactly this
     (slice, chunk) geometry — a plan for different geometry would silently
     gather the wrong elements.
 
@@ -264,7 +248,7 @@ def resolve_device_plan(
     plan: DevicePlan | None,
     packed: bool | str | None = None,
 ) -> DevicePlan:
-    """Shared plan resolution for both SELL kernels: a prebuilt `plan` wins
+    """Plan resolution for the SELL kernel: a prebuilt `plan` wins
     (validated against the call geometry), else a prebuilt `schedule` is
     lowered, else the plan is built from `colidx` (which is only then
     required). The geometry of record is the *values* array's — a `colidx`
@@ -361,17 +345,6 @@ def _meta_rows(packed: bool) -> int:
     """Rows per chunk of `DevicePlan.elem_meta`: one packed word, or the
     (warp, offset) pair."""
     return 1 if packed else 2
-
-
-def _validate_buffer_depth(buffer_depth: int) -> int:
-    depth = int(buffer_depth)
-    if not 1 <= depth <= MAX_BUFFER_DEPTH:
-        raise ValueError(
-            f"buffer_depth must be in [1, {MAX_BUFFER_DEPTH}] (1 = classic "
-            f"BlockSpec pipeline, >= 2 = manual double buffering), got "
-            f"{buffer_depth}"
-        )
-    return depth
 
 
 #: Bytes of scalar-prefetched tag rows one kernel call may hold. The tags sit
@@ -500,101 +473,18 @@ def _kernel(
     )
 
 
-def _kernel_buffered(
-    tags_ref,  # scalar-prefetch (group * n_chunks, max_warps)
-    base_ref,  # scalar-prefetch (1,): the group's first slice
-    elem_meta_hbm,  # full meta array, ANY memory space
-    values_hbm,  # full (n_slices, n_chunks, 1, window) values, ANY space
-    x_block_ref,  # (1, 1, block_rows) — coalesced wide fetch of x
-    out_ref,  # (1, 1, H)
-    meta_vmem,  # (depth, 1 | 2, window) scratch
-    vals_vmem,  # (depth, 1, window) scratch
-    sems,  # DMA semaphores (2, depth)
-    *,
-    block_rows: int,
-    cols_per_chunk: int,
-    slice_height: int,
-    packed: bool,
-    n_chunks: int,
-    total_chunks: int,
-    depth: int,
-):
-    """Double-buffered variant: SELL values + metadata stream through a
-    rotating `depth`-slot VMEM scratch with explicit async copies, so the DMA
-    for chunk ``g + depth - 1`` overlaps the compute of chunk ``g`` (the
-    kernel-level analog of the host-side StreamingExecutor pipeline). Scratch
-    persists across sequential grid steps; x keeps its scalar-prefetch
-    BlockSpec and is pipelined by pallas as before."""
-    s = pl.program_id(0)
-    c = pl.program_id(1)
-    t = pl.program_id(2)
-    g = s * n_chunks + c  # linearized chunk index across this call's slices
-
-    def chunk_dma(gg, slot):
-        s_g = base_ref[0] + gg // n_chunks
-        c_g = gg % n_chunks
-        return (
-            pltpu.make_async_copy(
-                elem_meta_hbm.at[s_g, c_g], meta_vmem.at[slot],
-                sems.at[0, slot],
-            ),
-            pltpu.make_async_copy(
-                values_hbm.at[s_g, c_g], vals_vmem.at[slot], sems.at[1, slot],
-            ),
-        )
-
-    @pl.when((s == 0) & (c == 0) & (t == 0))
-    def _warm_up():
-        # Fill the first depth-1 slots before any compute waits on them.
-        for j in range(min(depth - 1, total_chunks)):
-            for cp in chunk_dma(j, j):
-                cp.start()
-
-    @pl.when((c == 0) & (t == 0))
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    slot = jax.lax.rem(g, depth)
-
-    @pl.when(t == 0)
-    def _stage():
-        look_ahead = g + depth - 1
-
-        @pl.when(look_ahead < total_chunks)
-        def _prefetch():
-            # Slot (g - 1) % depth: its chunk finished computing last step.
-            for cp in chunk_dma(look_ahead, jax.lax.rem(look_ahead, depth)):
-                cp.start()
-
-        for cp in chunk_dma(g, slot):
-            cp.wait()
-
-    ew, eo = _decode_meta(meta_vmem[slot], packed=packed)
-    out_ref[0] += _chunk_contribution(
-        ew, eo, t, vals_vmem[slot], x_block_ref[0], out_ref.dtype,
-        block_rows=block_rows, cols_per_chunk=cols_per_chunk,
-        slice_height=slice_height,
-    )
-
-
-def _meta_block_spec(window: int, packed: bool, rank: int):
-    """BlockSpec for one chunk's metadata in the depth-1 path. `rank` is the
-    number of leading grid axes in the index map signature (2 for spmv's
-    (s, c, t), 3 for spmm's (s, q, c, t)); the map offsets the slice by the
-    call's group base."""
-    block = (1, 1, _meta_rows(packed), window)
-    if rank == 2:
-        return pl.BlockSpec(
-            block, lambda s, c, t, tags, base: (base[0] + s, c, 0, 0)
-        )
+def _meta_block_spec(window: int, packed: bool):
+    """BlockSpec for one chunk's metadata on the per-warp grid (s, c, t);
+    the map offsets the slice by the call's group base."""
     return pl.BlockSpec(
-        block, lambda s, q, c, t, tags, base: (base[0] + s, c, 0, 0)
+        (1, 1, _meta_rows(packed), window),
+        lambda s, c, t, tags, base: (base[0] + s, c, 0, 0),
     )
 
 
 def chunk_values(values: jnp.ndarray, cols_per_chunk: int) -> jnp.ndarray:
     """(n_slices, W, H) SELL values as one (1, window) row per (slice, chunk)
-    — the layout both SELL kernels stream. Plan-owning callers store values
+    — the layout the per-warp grid streams. Plan-owning callers store values
     in this shape so no call pays a relayout."""
     n_slices, W, H = values.shape
     return values.reshape(n_slices, W // cols_per_chunk, 1, cols_per_chunk * H)
@@ -638,13 +528,6 @@ def lane_dense_plan(plan: DevicePlan) -> DevicePlan:
     """`plan` with its metadata as the resident path's lane-dense
     (n_windows * rows * window / 128, 128) stream (rows: `_meta_rows`)."""
     return dataclasses.replace(plan, elem_meta=plan.elem_meta.reshape(-1, LANES))
-
-
-def chunk_row_plan(plan: DevicePlan) -> DevicePlan:
-    """`plan` with its metadata as the (n_slices, n_chunks, rows, window)
-    chunk rows the per-warp grids stream; the inverse of `lane_dense_plan`."""
-    return dataclasses.replace(plan, elem_meta=plan.elem_meta.reshape(
-        plan.n_slices, plan.n_chunks, _meta_rows(plan.packed), plan.window))
 
 
 def _x_rows(n_cols: int) -> int:
@@ -695,10 +578,11 @@ def device_operands(plan: DevicePlan, values: jnp.ndarray, n_cols: int):
     products with an x of `n_cols` entries: lane-dense where `x_resident`
     holds, else the per-warp grid's chunk rows. `sell_spmv_pallas` takes
     its path from the plan's layout, so the stream is never relaid out by a
-    call. `values` is (n_slices, W, H)."""
+    call. `values` is (n_slices, W, H); `plan` holds chunk rows, as
+    `build_device_plan` makes it."""
     if x_resident(plan, n_cols, values.dtype):
         return stream_values(values), lane_dense_plan(plan)
-    return chunk_values(values, plan.cols_per_chunk), chunk_row_plan(plan)
+    return chunk_values(values, plan.cols_per_chunk), plan
 
 
 def warps_per_gather(window: int) -> int:
@@ -874,7 +758,7 @@ def _sell_spmv_resident(dplan: DevicePlan, values, x, *, interpret: bool):
         name=KERNEL_NAME,
     )
     # The kernel copies x in from HBM itself, which a batched call cannot
-    # (memory space ANY): under vmap (`SpMVEngine.matmat_vmapped`) each
+    # (memory space ANY): under vmap (`SpMVEngine.matmat`) each
     # right-hand side runs as a product of its own, in a loop.
     @jax.custom_batching.sequential_vmap
     def product(tags, meta, vals, x_p):
@@ -887,8 +771,7 @@ def _sell_spmv_resident(dplan: DevicePlan, values, x, *, interpret: bool):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "cols_per_chunk", "block_rows", "max_warps", "packed",
-        "buffer_depth", "interpret",
+        "cols_per_chunk", "block_rows", "max_warps", "packed", "interpret",
     ),
 )
 def sell_spmv_pallas(
@@ -902,7 +785,6 @@ def sell_spmv_pallas(
     schedule: BlockSchedule | None = None,
     plan: DevicePlan | None = None,
     packed: bool | str | None = None,
-    buffer_depth: int = DEFAULT_BUFFER_DEPTH,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Returns y = A @ x, y: (n_slices * H,). Semantics: ref.sell_spmv_ref.
@@ -918,11 +800,9 @@ def sell_spmv_pallas(
     The plan's layout picks the path. A lane-dense plan
     (`device_operands`, or a plan built here where `x_resident` holds) runs
     one call of the x-resident kernel (`_sell_spmv_resident`). A plan of
-    chunk rows runs the per-warp grid: `buffer_depth >= 2` streams values
-    + metadata through a rotating VMEM scratch with async copies (see
-    `_kernel_buffered`), `buffer_depth=1` keeps the classic BlockSpec
-    pipeline, and plans whose tags exceed `SMEM_TAG_BUDGET` run as one
-    kernel call per group of slices."""
+    chunk rows runs the per-warp grid, whose BlockSpecs pipeline each
+    chunk's values and metadata and each warp's x block; plans whose tags
+    exceed `SMEM_TAG_BUDGET` run as one kernel call per group of slices."""
     n_slices, W, H = values.shape
     if W % cols_per_chunk != 0:
         raise ValueError(
@@ -931,7 +811,6 @@ def sell_spmv_pallas(
             f"multiple of cols_per_chunk (core.engine.SpMVEngine with "
             f"backend='pallas' does this at planning time)"
         )
-    depth = _validate_buffer_depth(buffer_depth)
     n_chunks = W // cols_per_chunk
     window = cols_per_chunk * H
     # The indirect stream in storage order: slice-by-slice, column-major.
@@ -961,48 +840,25 @@ def sell_spmv_pallas(
         # accumulation), matching ref.sell_spmv_ref's natural promotion.
         (group, 1, H), jnp.promote_types(values.dtype, x.dtype)
     )
-    out_spec = pl.BlockSpec((1, 1, H), lambda s, c, t, tags, base: (s, 0, 0))
-    x_spec = pl.BlockSpec((1, 1, block_rows), tag_of)
-    common = dict(
-        block_rows=block_rows, cols_per_chunk=cols_per_chunk,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(group, n_chunks, dplan.max_warps),
+        in_specs=[
+            _meta_block_spec(window, dplan.packed),
+            pl.BlockSpec(
+                (1, 1, 1, window),
+                lambda s, c, t, tags, base: (base[0] + s, c, 0, 0),
+            ),
+            pl.BlockSpec((1, 1, block_rows), tag_of),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, 1, H), lambda s, c, t, tags, base: (s, 0, 0)
+        ),
+    )
+    kernel = functools.partial(
+        _kernel, block_rows=block_rows, cols_per_chunk=cols_per_chunk,
         slice_height=H, packed=dplan.packed,
     )
-    if depth == 1:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(group, n_chunks, dplan.max_warps),
-            in_specs=[
-                _meta_block_spec(window, dplan.packed, rank=2),
-                pl.BlockSpec(
-                    (1, 1, 1, window),
-                    lambda s, c, t, tags, base: (base[0] + s, c, 0, 0),
-                ),
-                x_spec,
-            ],
-            out_specs=out_spec,
-        )
-        kernel = functools.partial(_kernel, **common)
-    else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(group, n_chunks, dplan.max_warps),
-            in_specs=[
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-                x_spec,
-            ],
-            out_specs=out_spec,
-            scratch_shapes=[
-                pltpu.VMEM((depth, _meta_rows(dplan.packed), window),
-                           jnp.int32),
-                pltpu.VMEM((depth, 1, window), values.dtype),
-                pltpu.SemaphoreType.DMA((2, depth)),
-            ],
-        )
-        kernel = functools.partial(
-            _kernel_buffered, **common,
-            n_chunks=n_chunks, total_chunks=group * n_chunks, depth=depth,
-        )
     call = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
         name=KERNEL_NAME,

@@ -330,8 +330,7 @@ def serve_spmv(args) -> None:
         )
         print(
             f"spmv-tune: cols_per_chunk={tuned.cols_per_chunk} "
-            f"block_rows={tuned.block_rows} k_tile={tuned.k_tile} "
-            f"packed={tuned.packed} buffer_depth={tuned.buffer_depth} "
+            f"block_rows={tuned.block_rows} packed={tuned.packed} "
             f"(mode={tuned.mode}, source={tuned.source}, "
             f"trials={tuned.trials}, cost={tuned.cost:.3g}, "
             f"{time.time() - t0:.3f}s)"
@@ -340,9 +339,7 @@ def serve_spmv(args) -> None:
             window=None,
             block_rows=tuned.block_rows,
             cols_per_chunk=tuned.cols_per_chunk,
-            k_tile=tuned.k_tile,
             packed=bool(tuned.packed),
-            buffer_depth=tuned.buffer_depth,
         )
     t0 = time.time()
     if args.mesh:
@@ -358,9 +355,8 @@ def serve_spmv(args) -> None:
             cache_dir=args.schedule_cache,
             **knobs,
         )
-        # Forces every shard's schedule build; k= folds the matmat
-        # amortization prediction into the same report pass.
-        rep = engine.plan_report(k=args.batch if args.batch > 1 else None)
+        # Forces every shard's schedule build.
+        rep = engine.plan_report()
         plan_s = time.time() - t0
         cached = [s["schedule_cached"] for s in rep["shards"]]
         print(
@@ -403,8 +399,8 @@ def serve_spmv(args) -> None:
             cache_dir=args.schedule_cache,
             **knobs,
         )
-        # Forces the (lazy) schedule build; k= folds the matmat prediction in.
-        rep = engine.plan_report(k=args.batch if args.batch > 1 else None)
+        # Forces the (lazy) schedule build.
+        rep = engine.plan_report()
         plan_s = time.time() - t0
         print(
             f"spmv-serve: {args.spmv} {rep['n_rows']}x{rep['n_cols']} "
@@ -414,26 +410,12 @@ def serve_spmv(args) -> None:
         print(
             f"  backend: {rep['backend']} -> {rep['backend_resolved']} "
             f"(cols_per_chunk={rep['cols_per_chunk']}, "
-            f"plan_width={rep['plan_width']}, "
-            f"matmat={rep['matmat_mode']}, k_tile={rep['k_tile']})"
+            f"plan_width={rep['plan_width']})"
         )
         print(
             f"  plan: window={rep['window']} block_rows={rep['block_rows']} "
             f"wide_accesses={rep['wide_accesses']} "
             f"coalesce_rate={rep['coalesce_rate']:.2f}"
-        )
-    if args.batch > 1:
-        # The fused-matmat amortization the model predicts for this batch
-        # width (measured fused-vs-vmapped lives in benchmarks/run.py
-        # --matmat; this is the serving-side prediction surface).
-        mm = rep["matmat"]
-        pred = mm["perf"]["pack256"]
-        print(
-            f"  matmat: k_tile={mm['k_tile']} mode={mm['mode']} — model "
-            f"predicts x{pred['speedup']:.3f} fused vs vmapped at "
-            f"k={args.batch} (matrix stream amortized "
-            f"x{pred['amortization']:.1f}, crossover k="
-            f"{pred['crossover_k']})"
         )
     stream_cfg = parse_stream_spec(args.stream) if args.stream else None
     streamer = None
@@ -638,9 +620,9 @@ def main() -> None:
     ap.add_argument(
         "--tune", nargs="?", const="model", choices=("model", "measure"),
         default=None,
-        help="autotune (cols_per_chunk, block_rows, k_tile) for this matrix "
+        help="autotune (cols_per_chunk, block_rows, packed) for this matrix "
         "and batch width before serving (core.tune.autotune): 'model' "
-        "scores candidates with the fused-matmat cycle model, 'measure' "
+        "scores candidates with the matmat cycle model, 'measure' "
         "times real matmats; winners persist content-addressed (see "
         "--tune-cache) so repeat serves run zero trials",
     )

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.engine import SpMVEngine
 from repro.core.formats import csr_to_sell, dense_to_csr
 from repro.core.spmv import _sell_padded
 from repro.kernels import ops, ref
@@ -57,16 +58,18 @@ def test_sell_spmv_bf16_values_f32_accum_vs_f64(seed):
     assert (err <= BF16_TOL * rowsum).all(), (err / rowsum).max()
 
 
-@pytest.mark.parametrize("k,k_tile", [(5, 4), (1, 8)])
-def test_sell_spmm_bf16_values_f32_accum_vs_f64(k, k_tile):
+def _bf16_engine(sell):
+    """The pallas engine with its SELL values stored in bf16."""
+    return SpMVEngine(sell, backend="pallas", cols_per_chunk=4,
+                      value_dtype="bf16")
+
+
+@pytest.mark.parametrize("k", [5, 1])
+def test_sell_spmm_bf16_values_f32_accum_vs_f64(k):
     dense, sell, ci, va = _case(7)
     rng = np.random.default_rng(99)
     X64 = rng.standard_normal((dense.shape[1], k))
-    va_bf = jnp.asarray(va).astype(jnp.bfloat16)
-    Y = ops.sell_spmm(
-        jnp.asarray(ci), va_bf, jnp.asarray(X64.astype(np.float32)),
-        cols_per_chunk=4, block_rows=8, k_tile=k_tile,
-    )
+    Y = _bf16_engine(sell).matmat(jnp.asarray(X64.astype(np.float32)))
     assert Y.dtype == jnp.float32
     Y64 = dense @ X64
     rowsum = np.abs(dense) @ np.abs(X64) + 1.0
@@ -91,9 +94,9 @@ def test_bf16_kernel_matches_promoting_oracle_at_1e5():
     X = jnp.asarray(
         rng.standard_normal((dense.shape[1], 6)).astype(np.float32)
     )
-    Y = ops.sell_spmm(jnp.asarray(ci), va_bf, X, cols_per_chunk=4,
-                      block_rows=8, k_tile=4)
-    Ye = ref.sell_spmm_ref(jnp.asarray(ci), va_bf, X)
+    Y = _bf16_engine(sell).matmat(X)
+    Ye = jnp.stack([ref.sell_spmv_ref(jnp.asarray(ci), va_bf, X[:, j])
+                    for j in range(X.shape[1])], axis=1)[: sell.n_rows]
     assert Y.dtype == Ye.dtype == jnp.float32
     np.testing.assert_allclose(
         np.asarray(Y), np.asarray(Ye), rtol=1e-5, atol=1e-5
@@ -101,9 +104,7 @@ def test_bf16_kernel_matches_promoting_oracle_at_1e5():
 
 
 def test_spmm_k0_keeps_promoted_dtype():
-    _, _, ci, va = _case(5)
-    va_bf = jnp.asarray(va).astype(jnp.bfloat16)
+    _, sell, _, _ = _case(5)
     X0 = jnp.zeros((120, 0), jnp.float32)
-    Y = ops.sell_spmm(jnp.asarray(ci), va_bf, X0, cols_per_chunk=4,
-                      block_rows=8)
+    Y = _bf16_engine(sell).matmat(X0)
     assert Y.shape[1] == 0 and Y.dtype == jnp.float32

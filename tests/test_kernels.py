@@ -176,7 +176,7 @@ def test_max_warps_reduction_still_correct():
                                   np.asarray(table)[np.asarray(idx)])
 
 
-@pytest.mark.parametrize("kernel", ["spmv-d1", "spmv-d2", "spmm", "gather"])
+@pytest.mark.parametrize("kernel", ["spmv", "matmat", "gather"])
 def test_kernels_split_into_slice_groups(monkeypatch, kernel):
     """A plan whose tags exceed the SMEM budget runs as one kernel call per
     group of slices (the last group shifted back to overlap its predecessor);
@@ -198,14 +198,17 @@ def test_kernels_split_into_slice_groups(monkeypatch, kernel):
     colidx = jnp.asarray(rng.integers(0, n_cols, size=(n_slices, W, H)),
                          jnp.int32)
     values = jnp.asarray(rng.standard_normal((n_slices, W, H)), jnp.float32)
-    if kernel == "spmm":
+    if kernel == "matmat":
         X = jnp.asarray(rng.standard_normal((n_cols, 5)), jnp.float32)
-        y = ops.sell_spmm(colidx, values, X, cols_per_chunk=cpc, k_tile=2)
-        ye = ref.sell_spmm_ref(colidx, values, X)
+        y = jax.vmap(
+            lambda x: ops.sell_spmv(colidx, values, x, cols_per_chunk=cpc),
+            in_axes=1, out_axes=1,
+        )(X)
+        ye = jnp.stack([ref.sell_spmv_ref(colidx, values, X[:, j])
+                        for j in range(X.shape[1])], axis=1)
     else:
         x = jnp.asarray(rng.standard_normal(n_cols), jnp.float32)
-        y = ops.sell_spmv(colidx, values, x, cols_per_chunk=cpc,
-                          buffer_depth=int(kernel[-1]))
+        y = ops.sell_spmv(colidx, values, x, cols_per_chunk=cpc)
         ye = ref.sell_spmv_ref(colidx, values, x)
     assert sell_spmv.slices_per_call(n_slices, W // cpc, 1) == 3
     np.testing.assert_allclose(np.asarray(y), np.asarray(ye), rtol=1e-5,

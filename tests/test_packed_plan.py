@@ -1,6 +1,6 @@
 """Packed-metadata DevicePlans: lossless 16/16-bit round-trips, the int32
-overflow fallback, packed-vs-unpacked kernel parity across pipeline depths,
-and the engine-cache identity of the new knobs."""
+overflow fallback, packed-vs-unpacked kernel parity on both plan layouts,
+and the engine-cache identity of the packing knob."""
 import dataclasses
 
 import jax.numpy as jnp
@@ -17,13 +17,12 @@ from repro.core.coalescer import (
     schedule_meta_bytes,
 )
 from repro.core.engine import clear_engine_cache, get_engine
-from repro.core.formats import csr_to_sell
+from repro.core.formats import csr_to_sell, dense_to_csr
 from repro.core.matrices import banded
 from repro.kernels import ops, ref
 from repro.kernels.sell_spmv import (
     DevicePlan,
     build_device_plan,
-    chunk_row_plan,
     lane_dense_plan,
     resolve_packing,
 )
@@ -97,9 +96,9 @@ def test_pack_roundtrip_bit_exact(
 
 @pytest.mark.parametrize("packed", [True, False])
 def test_lane_dense_plan_decodes_like_chunk_rows(packed):
-    """The x-resident path's lane-dense metadata holds the same words:
-    it decodes to the schedule's warps and offsets, and `chunk_row_plan`
-    gives the chunk rows back."""
+    """The x-resident path's lane-dense metadata holds the same words: it
+    decodes to the schedule's warps and offsets, and reshapes back to the
+    chunk rows."""
     n_slices, n_chunks, cpc, H = 3, 2, 8, 32
     stream = RNG.integers(0, 5_000, size=n_slices * n_chunks * cpc * H)
     sched = _schedule(stream, window=cpc * H, block_rows=8)
@@ -111,7 +110,7 @@ def test_lane_dense_plan_decodes_like_chunk_rows(packed):
     for name in ("elem_warp", "elem_offset"):
         np.testing.assert_array_equal(np.asarray(getattr(lane, name)),
                                       np.asarray(getattr(plan, name)))
-    np.testing.assert_array_equal(np.asarray(chunk_row_plan(lane).elem_meta),
+    np.testing.assert_array_equal(np.asarray(lane._chunk_meta),
                                   np.asarray(plan.elem_meta))
 
 
@@ -177,7 +176,7 @@ def test_schedule_meta_bytes_units():
 # -- kernel parity ----------------------------------------------------------
 
 
-def _sell_arrays(n_slices=3, W=8, H=16, n_cols=200):
+def _sell_arrays(n_slices=3, W=16, H=32, n_cols=200):
     colidx = jnp.asarray(
         RNG.integers(0, n_cols, size=(n_slices, W, H)).astype(np.int32)
     )
@@ -189,13 +188,20 @@ def _sell_arrays(n_slices=3, W=8, H=16, n_cols=200):
 
 
 @pytest.mark.parametrize("packed", [True, False])
-@pytest.mark.parametrize("buffer_depth", [1, 2, 3])
-def test_sell_spmv_packed_depth_parity(packed, buffer_depth):
+@pytest.mark.parametrize("layout", ["lane_dense", "chunk_rows"])
+def test_sell_spmv_packed_depth_parity(packed, layout):
+    """Both encodings on both plan layouts: lane-dense runs the x-resident
+    path, chunk rows the per-warp grid."""
     colidx, values, n_cols = _sell_arrays()
     x = jnp.asarray(RNG.standard_normal(n_cols).astype(np.float32))
+    sched = _schedule(np.asarray(colidx).reshape(-1), window=8 * 32,
+                      block_rows=8)
+    plan = build_device_plan(sched, n_slices=3, cols_per_chunk=8,
+                             slice_height=32, packed=packed)
+    if layout == "lane_dense":
+        plan = lane_dense_plan(plan)
     y = ops.sell_spmv(
-        colidx, values, x, cols_per_chunk=4, block_rows=8,
-        packed=packed, buffer_depth=buffer_depth,
+        None, values, x, cols_per_chunk=8, block_rows=8, plan=plan,
     )
     ye = ref.sell_spmv_ref(colidx, values, x)
     np.testing.assert_allclose(
@@ -204,29 +210,24 @@ def test_sell_spmv_packed_depth_parity(packed, buffer_depth):
 
 
 @pytest.mark.parametrize("packed", [True, False])
-@pytest.mark.parametrize("buffer_depth", [1, 2, 3])
-def test_sell_spmm_packed_depth_parity(packed, buffer_depth):
-    colidx, values, n_cols = _sell_arrays()
-    X = jnp.asarray(RNG.standard_normal((n_cols, 8)).astype(np.float32))
-    Y = ops.sell_spmm(
-        colidx, values, X, cols_per_chunk=4, block_rows=8, k_tile=4,
-        packed=packed, buffer_depth=buffer_depth,
-    )
-    Ye = ref.sell_spmm_ref(colidx, values, X)
-    np.testing.assert_allclose(
-        np.asarray(Y), np.asarray(Ye), rtol=1e-5, atol=1e-5
-    )
+@pytest.mark.parametrize("layout", ["lane_dense", "chunk_rows"])
+def test_sell_spmm_packed_depth_parity(packed, layout):
+    """`SpMVEngine.matmat` with either encoding, on a plan of either layout
+    (32-row slices in windows of 256 stay x-resident; 16-row slices in
+    windows of 64 do not)."""
+    from repro.core.engine import SpMVEngine
 
-
-def test_bad_buffer_depth_rejected():
-    colidx, values, n_cols = _sell_arrays()
-    x = jnp.asarray(RNG.standard_normal(n_cols).astype(np.float32))
-    for depth in (0, -1, 99):
-        with pytest.raises(ValueError, match="buffer_depth"):
-            ops.sell_spmv(
-                colidx, values, x, cols_per_chunk=4, block_rows=8,
-                buffer_depth=depth,
-            )
+    H, cpc = (32, 8) if layout == "lane_dense" else (16, 4)
+    dense = RNG.standard_normal((96, 200)) * (RNG.random((96, 200)) < 0.1)
+    sell = csr_to_sell(dense_to_csr(dense), slice_height=H)
+    eng = SpMVEngine(sell, backend="pallas", cols_per_chunk=cpc,
+                     packed=packed)
+    X = jnp.asarray(RNG.standard_normal((200, 8)).astype(np.float32))
+    Y = np.asarray(eng.matmat(X))
+    assert eng._device_plan.lane_dense == (layout == "lane_dense")
+    assert eng._device_plan.packed == packed
+    np.testing.assert_allclose(Y, dense @ np.asarray(X), rtol=1e-5,
+                               atol=1e-5)
 
 
 # -- engine integration -----------------------------------------------------
@@ -237,7 +238,6 @@ def test_engine_cache_keys_on_packing_and_depth():
     base = get_engine(sell, backend="pallas")
     assert get_engine(sell, backend="pallas") is base
     assert get_engine(sell, backend="pallas", packed=False) is not base
-    assert get_engine(sell, backend="pallas", buffer_depth=1) is not base
     # packed is keyed on the *requested* spelling (resolving would need the
     # schedule), so "auto" and True are distinct entries by design
     assert get_engine(sell, backend="pallas", packed=True) is not base
@@ -252,10 +252,8 @@ def test_engine_packed_parity_and_report():
     from repro.core.engine import SpMVEngine
 
     y_ref = np.asarray(SpMVEngine(sell, backend="reference").matvec(x))
-    for packed, depth in ((True, 2), (False, 1), ("auto", 3)):
-        eng = SpMVEngine(
-            sell, backend="pallas", packed=packed, buffer_depth=depth
-        )
+    for packed in (True, False, "auto"):
+        eng = SpMVEngine(sell, backend="pallas", packed=packed)
         y = np.asarray(eng.matvec(x))
         np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
     meta = SpMVEngine(sell, backend="pallas").plan_report()["metadata"]

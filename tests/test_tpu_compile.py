@@ -16,7 +16,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.coalesced_gather import coalesced_gather_pallas
-from repro.kernels.sell_spmm import sell_spmm_pallas
 from repro.kernels import sell_spmv
 from repro.kernels.sell_spmv import DevicePlan, sell_spmv_pallas
 
@@ -126,16 +125,23 @@ def _lower_spmv_per_warp(sharding, geometry):
     )
 
 
-def _lower_spmm(sharding, geometry=WEBBASE, block_rows=8):
+def _lower_matmat(sharding, geometry=WEBBASE, block_rows=8, *, k=8,
+                  lane_dense=True):
+    """`SpMVEngine.matmat` at `geometry`: the matvec batched over k
+    right-hand sides, on a lane-dense plan (the resident kernel under its
+    `sequential_vmap`) or on chunk rows (the per-warp grid)."""
     n_slices, n_chunks, max_warps = geometry
-    plan = _plan(sharding, *geometry, block_rows=block_rows)
-    fn = jax.jit(lambda v, x, p: sell_spmm_pallas(
-        None, v, x, cols_per_chunk=CPC, block_rows=block_rows, k_tile=8,
-        plan=p
-    ))
+    plan = _plan(sharding, *geometry, block_rows=block_rows,
+                 lane_dense=lane_dense)
+    values = ((n_slices * n_chunks * WINDOW // 128, 128) if lane_dense
+              else (n_slices, n_chunks * CPC, H))
+    fn = jax.jit(jax.vmap(lambda v, x, p: sell_spmv_pallas(
+        None, v.reshape(n_slices, n_chunks * CPC, H), x, cols_per_chunk=CPC,
+        block_rows=block_rows, plan=p
+    ), in_axes=(None, 1, None), out_axes=1))
     return fn.lower(
-        _shape(sharding, (n_slices, n_chunks * CPC, H), jnp.float32),
-        _shape(sharding, (n_slices * H, 8), jnp.float32),
+        _shape(sharding, values, jnp.float32),
+        _shape(sharding, (n_slices * H, k), jnp.float32),
         plan,
     )
 
@@ -167,21 +173,18 @@ def test_sell_spmv_per_warp_grid_compiles_for_v5e(one_chip, geometry):
 
 
 def test_sell_spmv_resident_compiles_under_vmap_for_v5e(one_chip):
-    """`SpMVEngine.matmat_vmapped` on an x-resident plan: the resident
-    kernel batched over 8 right-hand sides, at the webbase geometry, whose
-    31,250 slices leave a partial last tile."""
-    n_slices, n_chunks, _ = WEBBASE
-    plan = _plan(one_chip, *WEBBASE, lane_dense=True)
-    fn = jax.jit(jax.vmap(lambda v, x, p: sell_spmv_pallas(
-        None, v.reshape(n_slices, n_chunks * CPC, H), x, cols_per_chunk=CPC,
-        plan=p
-    ), in_axes=(None, 1, None), out_axes=1))
-    _assert_kernel(fn.lower(
-        _shape(one_chip, (n_slices * n_chunks * WINDOW // 128, 128),
-               jnp.float32),
-        _shape(one_chip, (n_slices * H, 8), jnp.float32),
-        plan,
-    ))
+    """`SpMVEngine.matmat` on an x-resident plan: the resident kernel
+    batched over 8 right-hand sides, at the webbase geometry, whose 31,250
+    slices leave a partial last tile."""
+    _assert_kernel(_lower_matmat(one_chip))
+
+
+@pytest.mark.parametrize("geometry", [HPCG, WEBBASE], ids=["hpcg", "webbase"])
+def test_sell_spmv_per_warp_grid_compiles_under_vmap_for_v5e(one_chip,
+                                                             geometry):
+    """`SpMVEngine.matmat` on a plan of chunk rows: the per-warp grid, slice
+    groups and all, batched over 8 right-hand sides."""
+    _assert_kernel(_lower_matmat(one_chip, geometry, lane_dense=False))
 
 
 def _elements(shape: str) -> int:
@@ -218,17 +221,13 @@ def test_sell_spmv_is_one_resident_kernel_call(one_chip, geometry):
     assert limit <= sell_spmv.X_RESIDENT_BUDGET + 16 * 2 ** 20
 
 
-def test_sell_spmm_compiles_for_v5e_at_k8(one_chip):
-    _assert_kernel(_lower_spmm(one_chip))
-
-
-@pytest.mark.parametrize("kernel", ["matvec", "fused_matmat_k8"])
+@pytest.mark.parametrize("kernel", ["matvec", "matmat_k8"])
 @pytest.mark.parametrize("geometry", [HPCG_LANE_ROW, WEBBASE_LANE_ROW],
                          ids=["hpcg", "webbase"])
 def test_lane_row_plan_compiles_for_v5e(one_chip, geometry, kernel):
-    """A plan at `block_rows` 128: the resident matvec, and the fused
-    matmat on the same plan fetching (128, 8) X tiles."""
-    lower = {"matvec": _lower_spmv, "fused_matmat_k8": _lower_spmm}[kernel]
+    """A plan at `block_rows` 128: the resident matvec, and the matmat on
+    the same plan, the resident kernel once per right-hand side."""
+    lower = {"matvec": _lower_spmv, "matmat_k8": _lower_matmat}[kernel]
     _assert_kernel(lower(one_chip, geometry, block_rows=128))
 
 
@@ -244,7 +243,10 @@ KERNELS = {
                                 lambda sh: _lower_spmv_per_warp(sh, HPCG)),
     "sell_spmv_per_warp_one_group": (
         "sell_spmv", lambda sh: _lower_spmv_per_warp(sh, ONE_GROUP)),
-    "sell_spmm": ("sell_spmm", _lower_spmm),
+    "sell_spmv_matmat": ("sell_spmv", _lower_matmat),
+    "sell_spmv_per_warp_matmat": (
+        "sell_spmv",
+        lambda sh: _lower_matmat(sh, HPCG, lane_dense=False)),
     "coalesced_gather": ("coalesced_gather", _lower_gather),
 }
 
@@ -254,7 +256,7 @@ def test_kernel_is_named_after_its_scope(one_chip, case):
     """A device trace names an operation after its HLO instruction, and the
     kernel's innermost `named_scope` names that instruction: ``sell_spmv.N``
     on the x-resident path and on the per-warp grid, whether or not a
-    slice-group loop surrounds the per-warp call."""
+    slice-group loop or a batch of right-hand sides surrounds the call."""
     scope, lower = KERNELS[case]
     text = lower(one_chip).compile().as_text()
     names = re.findall(

@@ -23,7 +23,7 @@ from repro.core.tune import (
 )
 
 SELL = csr_to_sell(banded(256, 12, 0.7)(np.random.default_rng(0)))
-N_CANDIDATES = 216  # |DEFAULT_SPACE| = 3 * 3 * 3 * 2 * 2 * 2
+N_CANDIDATES = 36  # |DEFAULT_SPACE| = 3 * 3 * 2 * 2
 
 
 @pytest.fixture(autouse=True)
@@ -38,13 +38,13 @@ def test_model_mode_search_is_deterministic_and_in_space():
     p1 = autotune(SELL, k=32, backend="reference", mode="model")
     assert p1.cols_per_chunk in DEFAULT_SPACE["cols_per_chunk"]
     assert p1.block_rows in DEFAULT_SPACE["block_rows"]
-    assert p1.k_tile in DEFAULT_SPACE["k_tile"]
+    assert p1.packed in DEFAULT_SPACE["packed"]
     assert p1.source == "search" and p1.trials == N_CANDIDATES
     assert p1.cost > 0
     clear_tune_cache()
     p2 = autotune(SELL, k=32, backend="reference", mode="model")
-    assert (p2.cols_per_chunk, p2.block_rows, p2.k_tile, p2.cost) == (
-        p1.cols_per_chunk, p1.block_rows, p1.k_tile, p1.cost
+    assert (p2.cols_per_chunk, p2.block_rows, p2.packed, p2.cost) == (
+        p1.cols_per_chunk, p1.block_rows, p1.packed, p1.cost
     )
 
 
@@ -53,8 +53,8 @@ def test_memory_cache_hit_runs_zero_trials():
     assert p1.trials == N_CANDIDATES
     p2 = autotune(SELL, k=16, backend="reference", mode="model")
     assert p2.source == "memory" and p2.trials == 0
-    assert (p2.cols_per_chunk, p2.block_rows, p2.k_tile) == (
-        p1.cols_per_chunk, p1.block_rows, p1.k_tile
+    assert (p2.cols_per_chunk, p2.block_rows, p2.packed) == (
+        p1.cols_per_chunk, p1.block_rows, p1.packed
     )
     stats = tune_stats()
     assert stats["searched"] == 1 and stats["memory_hits"] == 1
@@ -84,8 +84,8 @@ def test_tune_cache_roundtrip_cold_process_runs_zero_trials(
     p2 = autotune(SELL, k=32, backend="reference", mode="model",
                   cache_dir=cache_dir)
     assert p2.source == "disk" and p2.trials == 0
-    assert (p2.cols_per_chunk, p2.block_rows, p2.k_tile) == (
-        p1.cols_per_chunk, p1.block_rows, p1.k_tile
+    assert (p2.cols_per_chunk, p2.block_rows, p2.packed) == (
+        p1.cols_per_chunk, p1.block_rows, p1.packed
     )
     stats = tune_stats()
     assert stats["searched"] == 0 and stats["disk_hits"] == 1
@@ -128,14 +128,15 @@ def test_winner_body_outside_space_rejected(tmp_path):
              cache_dir=cache_dir)
     path = next(tmp_path.iterdir())
     payload = json.loads(path.read_text())
-    payload["winner"]["k_tile"] = 999  # not in DEFAULT_SPACE
+    payload["winner"]["block_rows"] = 999  # not in DEFAULT_SPACE
     path.write_text(json.dumps(payload))
     clear_tune_cache()
     p = autotune(SELL, k=32, backend="reference", mode="model",
                  cache_dir=cache_dir)
     stats = tune_stats()
     assert stats["disk_rejects"] == 1 and stats["searched"] == 1
-    assert p.source == "search" and p.k_tile in DEFAULT_SPACE["k_tile"]
+    assert p.source == "search"
+    assert p.block_rows in DEFAULT_SPACE["block_rows"]
 
 
 def test_tampered_winner_file_rejected_and_researched(tmp_path):
@@ -152,8 +153,8 @@ def test_tampered_winner_file_rejected_and_researched(tmp_path):
     stats = tune_stats()
     assert stats["disk_rejects"] == 1 and stats["searched"] == 1
     assert p2.source == "search" and p2.trials == N_CANDIDATES
-    assert (p2.cols_per_chunk, p2.block_rows, p2.k_tile) == (
-        p1.cols_per_chunk, p1.block_rows, p1.k_tile
+    assert (p2.cols_per_chunk, p2.block_rows, p2.packed) == (
+        p1.cols_per_chunk, p1.block_rows, p1.packed
     )
 
 
@@ -176,9 +177,8 @@ def test_cache_dir_env_var_and_schedule_store_fallback(tmp_path, monkeypatch):
 def test_measure_mode_reference_backend():
     plan = autotune(
         SELL, k=4, backend="reference", mode="measure",
-        space={"cols_per_chunk": (8,), "block_rows": (4, 8), "k_tile": (8,),
-               "packed": (1,), "buffer_depth": (2,),
-               "value_dtype": ("native",)},
+        space={"cols_per_chunk": (8,), "block_rows": (4, 8),
+               "packed": (1,), "value_dtype": ("native",)},
         rounds=2,
     )
     assert plan.source == "search" and plan.mode == "measure"
@@ -190,7 +190,7 @@ def test_space_validation():
     with pytest.raises(ValueError, match="unknown"):
         autotune(SELL, k=4, mode="model", space={"warp_size": (32,)})
     with pytest.raises(ValueError, match=">= 1"):
-        autotune(SELL, k=4, mode="model", space={"k_tile": (0,)})
+        autotune(SELL, k=4, mode="model", space={"block_rows": (0,)})
     with pytest.raises(ValueError, match="packed"):
         autotune(SELL, k=4, mode="model", space={"packed": (2,)})
     with pytest.raises(ValueError, match="mode"):
@@ -205,8 +205,6 @@ def test_get_tuned_engine_feeds_get_engine(tmp_path):
         tune_cache_dir=str(tmp_path),
     )
     assert engine.block_rows == plan.block_rows
-    assert engine.k_tile == plan.k_tile
-    assert engine.buffer_depth == plan.buffer_depth
     assert engine.packed == bool(plan.packed)
     # repeat call: warm tuner (disk/memory) + warm engine cache
     engine2, plan2 = get_tuned_engine(
@@ -220,11 +218,11 @@ def test_tuned_plan_key_stable_across_space_orderings():
     digest = "ab" * 32
     a = tune_key(digest, k=8, backend="pallas", mode="model",
                  space=tune_mod._normalize_space(
-                     {"k_tile": (8, 4), "cols_per_chunk": (4, 8),
+                     {"packed": (1, 0), "cols_per_chunk": (4, 8),
                       "block_rows": (8,)}))
     b = tune_key(digest, k=8, backend="pallas", mode="model",
                  space=tune_mod._normalize_space(
                      {"block_rows": (8,), "cols_per_chunk": (8, 4),
-                      "k_tile": (4, 8)}))
+                      "packed": (0, 1)}))
     assert a == b
     assert tune_path("/tmp/cache", a).endswith(f"tune-{a}.json")
