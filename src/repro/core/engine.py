@@ -808,8 +808,8 @@ class SpMVEngine:
                     # The first engine of a process pays the import here.
                     from repro.kernels.ops import resolve_interpret
                     from repro.kernels.sell_spmv import build_device_plan, \
-                        device_operands, grid_steps, lane_gathers, \
-                        sell_spmv_pallas
+                        chunk_unroll, device_operands, grid_steps, \
+                        lane_gathers, sell_spmv_pallas
 
                     # Lower the schedule to the kernel-ready device plan
                     # exactly once; matvec and matmat share it. The schedule already encodes every gather,
@@ -834,6 +834,7 @@ class SpMVEngine:
                     counts["block_rows"] = plan.block_rows
                     counts["max_warps"] = plan.max_warps
                     counts["lane_gathers"] = lane_gathers(plan)
+                    counts["chunk_unroll"] = chunk_unroll(plan)
                 interpret = resolve_interpret()
 
                 def _values(va, dtype):

@@ -8,9 +8,9 @@ chunks are the VPC's VMAC consumption of SELL slices.
 Two paths run it, picked by the layout the plan holds, which
 `device_operands` chooses by `x_resident`. Where x fits VMEM the plan is
 lane-dense: x is copied there once per product and one grid step covers a
-tile of `TILE_SLICES` whole slices:
-the chunks and warps of the tile are loops in the kernel body, and a warp's
-wide fetch is a row load from VMEM plus an in-register lane gather. Every
+tile of `TILE_SLICES` whole slices: the slices of the tile are a loop in
+the kernel body, their chunks and warps unrolled, and a warp's wide fetch
+is a row load from VMEM plus an in-register lane gather. Every
 other plan runs the per-warp grid (s, c, t), one step per (slice, chunk,
 warp), with the warp's x block fetched by a data-dependent BlockSpec —
 compute and the indirect stream overlap as prefetching overlaps compute in
@@ -383,6 +383,13 @@ def lane_gathers(plan: DevicePlan) -> int:
     return plan.n_slices * plan.n_chunks * rounds
 
 
+def chunk_unroll(plan: DevicePlan) -> int:
+    """Chunks one unrolled group of the x-resident kernel runs over `plan`:
+    ``min(n_chunks, CHUNK_UNROLL)``. 0 on the per-warp grid, one step per
+    chunk."""
+    return min(plan.n_chunks, CHUNK_UNROLL) if plan.lane_dense else 0
+
+
 def run_slice_groups(call, tags, *, n_slices: int, n_chunks: int,
                      group: int):
     """Run ``call(base, tags_group)`` over consecutive groups of `group`
@@ -496,9 +503,10 @@ def chunk_values(values: jnp.ndarray, cols_per_chunk: int) -> jnp.ndarray:
 # whole product, viewed as (ceil(n_cols / 128), 128): a coalesced block
 # ``tag`` of `block_rows` floats is x row ``(tag * block_rows) // 128`` from
 # lane ``(tag * block_rows) % 128`` on. Inside a step a loop runs over the
-# tile's windows, and the warps of a window are unrolled: each warp's x row
-# is loaded from VMEM and its elements picked out by an in-register lane
-# gather, several warps to a vector register.
+# tile's slices, a slice's chunks are unrolled (`CHUNK_UNROLL` at a time),
+# and so are the warps of a window: each warp's x row is loaded from VMEM
+# and its elements picked out by an in-register lane gather, several warps
+# to a vector register.
 # The stream is lane-dense: values and metadata as (rows, 128) arrays holding
 # each window's ``window // 128`` lane rows in order (`stream_values`,
 # `lane_dense_plan`).
@@ -512,6 +520,10 @@ X_RESIDENT_BUDGET = 64 * 1024 * 1024
 
 #: Slices per grid step of the resident path.
 TILE_SLICES = 8
+
+#: Chunks of a slice the resident kernel unrolls as one group: a slice of at
+#: most this many chunks runs them all unrolled, a wider one loops over groups.
+CHUNK_UNROLL = 8
 
 #: VMEM the resident kernel may use beyond x and its pipelined blocks.
 _VMEM_HEADROOM = 8 * 1024 * 1024
@@ -688,12 +700,30 @@ def _resident_kernel(
             ew, eo = meta[:rows], meta[rows:]
         return acc + vals.astype(cdt) * gather_x(w, ew, eo)
 
+    # A slice's chunks run over static bounds, unrolled `group` at a time,
+    # so a window's loads schedule across its neighbours' (Mosaic unrolls a
+    # loop only whole). Wider slices loop over whole groups, then unroll the
+    # rest: the code stays bounded at any `n_chunks`.
+    group = min(n_chunks, CHUNK_UNROLL)
+    n_groups, rest = divmod(n_chunks, group)
+
+    def chunks(first, count, acc):
+        for c in range(count):
+            acc = window(first + c, acc)
+        return acc
+
     def one_slice(s, carry):
-        # The slice's chunks are summed lane by lane, then folded once.
-        acc = jax.lax.fori_loop(
-            s * n_chunks, (s + 1) * n_chunks, window,
-            jnp.zeros((rows, LANES), cdt),
-        )
+        # The slice's chunks are summed lane by lane, chunk 0 first, then
+        # folded once.
+        first = s * n_chunks
+        acc = jnp.zeros((rows, LANES), cdt)
+        if n_groups == 1:
+            acc = chunks(first, group, acc)
+        else:
+            acc = jax.lax.fori_loop(
+                0, n_groups,
+                lambda g, a: chunks(first + g * group, group, a), acc)
+        acc = chunks(first + n_groups * group, rest, acc)
         out_ref[pl.ds(s, 1), :] = _fold_lane_rows(
             acc, cols_per_chunk, slice_height).astype(out_dtype)
         return carry

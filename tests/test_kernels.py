@@ -277,6 +277,9 @@ RESIDENT_CASES = {
                                8, 8, "auto"),
     "stencil27": (_stencil_sell, 32, 8, 8, "auto"),
     "skewed": (_skewed_sell, 32, 4, 8, "auto"),
+    # 16 chunks per slice: whole groups of `CHUNK_UNROLL` and no remainder.
+    "sixteen_chunks": (lambda H, c: _random_sell(3, 64, H, 64), 32, 4, 8,
+                       "auto"),
 }
 # Coalesced at a whole lane row (`SpMVEngine`'s default on this path).
 RESIDENT_CASES.update({
@@ -296,6 +299,8 @@ def test_sell_spmv_resident_parity(case, monkeypatch):
     build, H, cpc, block_rows, packed = RESIDENT_CASES[case]
     colidx, values, n_cols = build(H, cpc)
     n_slices = colidx.shape[0]
+    if case.startswith("skewed"):  # chunks in groups, then a remainder
+        assert colidx.shape[1] // cpc > sell_spmv.CHUNK_UNROLL
     x = jnp.asarray(np.random.default_rng(5).standard_normal(n_cols),
                     jnp.float32)
 
@@ -324,7 +329,7 @@ def test_sell_spmv_resident_parity(case, monkeypatch):
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("case", ["stencil27", "skewed"])
+@pytest.mark.parametrize("case", ["stencil27", "skewed", "sixteen_chunks"])
 def test_resident_lane_row_plan_is_bitwise_the_8_plan(case):
     """On the x-resident path a plan coalesced at a whole lane row gives the
     8-row plan's y bit for bit: every element gathers the same x entry and

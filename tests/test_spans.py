@@ -112,7 +112,8 @@ def test_planner_stages_run_in_order_and_count_grid_steps():
         "x_resident": 1,
         "block_rows": 128,
         "max_warps": plan.max_warps,
-        "lane_gathers": sell_spmv.lane_gathers(plan)}
+        "lane_gathers": sell_spmv.lane_gathers(plan),
+        "chunk_unroll": min(plan.n_chunks, sell_spmv.CHUNK_UNROLL)}
     # The stages do not overlap, and the planner spans hold them.
     planned = sum(s.seconds for s in record if s.name == "planner")
     assert sum(s.seconds for s in record
@@ -249,9 +250,10 @@ def test_x_resident_count_follows_the_engagement_rule(x_budget,
 
 @pytest.mark.parametrize("x_budget", [None, 0], ids=["resident", "per_warp"])
 def test_planner_lower_counts_the_lane_gathers(x_budget, monkeypatch):
-    """`planner.lower` records the plan's `block_rows` and `max_warps`, and
+    """`planner.lower` records the plan's `block_rows` and `max_warps`,
     `lane_gathers`: windows x ceil(max_warps / warps per gather) on the
-    resident path, 0 on the per-warp grid."""
+    resident path, 0 on the per-warp grid, and `chunk_unroll`:
+    min(n_chunks, CHUNK_UNROLL) on the resident path, 0 on the grid."""
     if x_budget is not None:
         monkeypatch.setattr(sell_spmv, "X_RESIDENT_BUDGET", x_budget)
     A = _tiny()
@@ -268,6 +270,10 @@ def test_planner_lower_counts_the_lane_gathers(x_budget, monkeypatch):
     rounds = -(-sched.max_warps // (8 // (plan.window // 128)))
     assert lower.counts["lane_gathers"] == (
         sched.n_windows * rounds if x_budget is None else 0)
+    # The chunks one unrolled group of the resident kernel runs.
+    assert lower.counts["chunk_unroll"] == (
+        min(plan.n_chunks, sell_spmv.CHUNK_UNROLL) if x_budget is None
+        else 0)
 
 
 def test_executables_carry_their_names():

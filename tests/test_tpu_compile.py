@@ -90,6 +90,9 @@ HPCG_LANE_ROW = (35152, 4, 7)
 WEBBASE_LANE_ROW = WEBBASE
 # Few enough slices for one kernel call (no slice-group loop around it).
 ONE_GROUP = (64, 4, 26)
+# Wide slices (W = 512): 64 chunks, more than `CHUNK_UNROLL`, so the resident
+# kernel loops over unrolled groups of chunks.
+WIDE = (4096, 64, 8)
 
 
 def _lower_spmv(sharding, geometry, block_rows=8):
@@ -187,6 +190,24 @@ def test_sell_spmv_per_warp_grid_compiles_under_vmap_for_v5e(one_chip,
     _assert_kernel(_lower_matmat(one_chip, geometry, lane_dense=False))
 
 
+def _kernel_line(text: str) -> str:
+    """The one kernel call of a compiled program."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    return calls[0]
+
+
+def _scoped_vmem(call: str) -> tuple[int, int]:
+    """(used, limit) bytes of VMEM of a compiled kernel call."""
+    limit = int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                          call).group(1))
+    used = int(re.search(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+        call).group(1))
+    return used, limit
+
+
 def _elements(shape: str) -> int:
     dims = re.search(r"\[([\d,]*)\]", shape).group(1)
     return math.prod(int(d) for d in dims.split(",") if d)
@@ -200,9 +221,7 @@ def test_sell_spmv_is_one_resident_kernel_call(one_chip, geometry):
     blocks) within the limit it sets."""
     n_slices, n_chunks, _ = geometry
     text = _lower_spmv(one_chip, geometry).compile().as_text()
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 1
+    call = _kernel_line(text)
     assert " while(" not in text
     # No instruction but the parameters holds as many elements as the
     # lane-dense stream: the kernel reads values and metadata in place.
@@ -211,11 +230,7 @@ def test_sell_spmv_is_one_resident_kernel_call(one_chip, geometry):
                           text)
     assert produced
     assert all(_elements(shape) < stream for shape, _ in produced), produced
-    limit = int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
-                          calls[0]).group(1))
-    used = int(re.search(
-        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
-        calls[0]).group(1))
+    used, limit = _scoped_vmem(call)
     x_bytes = -(-n_slices * H // 128) * 128 * 4
     assert x_bytes <= used <= limit
     assert limit <= sell_spmv.X_RESIDENT_BUDGET + 16 * 2 ** 20
@@ -229,6 +244,22 @@ def test_lane_row_plan_compiles_for_v5e(one_chip, geometry, kernel):
     the same plan, the resident kernel once per right-hand side."""
     lower = {"matvec": _lower_spmv, "matmat_k8": _lower_matmat}[kernel]
     _assert_kernel(lower(one_chip, geometry, block_rows=128))
+
+
+def test_wide_slices_compile_in_unrolled_groups_for_v5e(one_chip):
+    """The resident matvec at 64 chunks per slice compiles within the VMEM
+    limit it sets, and its kernel body (the serialized module in the
+    custom call) is about the size of a plan of `CHUNK_UNROLL` chunks: the
+    groups loop, so code does not grow with the chunks."""
+    n_slices, n_chunks, max_warps = WIDE
+    assert n_chunks > sell_spmv.CHUNK_UNROLL
+    wide, narrow = (
+        _kernel_line(_lower_spmv(one_chip, geometry, block_rows=128)
+                     .compile().as_text())
+        for geometry in (WIDE, (n_slices, sell_spmv.CHUNK_UNROLL, max_warps)))
+    used, limit = _scoped_vmem(wide)
+    assert used <= limit
+    assert len(wide) < 1.25 * len(narrow)
 
 
 def test_coalesced_gather_compiles_for_v5e_paged_kv(one_chip):
